@@ -2,9 +2,11 @@
 # builds static Go binaries; here the runtime is Python/JAX so the deploy
 # image is a slim Python base with the package installed).
 #
-# The default install runs the CPU backend of XLA — correct everywhere and
-# right for development clusters. On TPU hosts, build with
-#   --build-arg JAX_EXTRA="jax[tpu]"
+# The default install runs the CPU backend of XLA — right for development
+# clusters — and says so by name (GUBER_TPU_PLATFORM=cpu below): a daemon
+# that finds no TPU refuses to fall back to the CPU silently. On TPU
+# hosts, build with
+#   --build-arg JAX_EXTRA="jax[tpu]" --build-arg GUBER_TPU_PLATFORM=
 # (pulls libtpu; the daemon finds the chips automatically).
 FROM python:3.12-slim AS build
 
@@ -18,10 +20,17 @@ WORKDIR /src
 COPY pyproject.toml README.md ./
 COPY gubernator_tpu ./gubernator_tpu
 
+# The runtime image has no compiler, and the loader rebuilds a library
+# that is older than its source: installed files get arbitrary mtimes,
+# so stamp the libraries last.
 RUN make -C gubernator_tpu/native \
-    && pip install --no-cache-dir --prefix=/install . ${JAX_EXTRA}
+    && pip install --no-cache-dir --prefix=/install . ${JAX_EXTRA} \
+    && find /install -name 'libguber_*.so' -exec touch {} +
 
 FROM python:3.12-slim
+
+ARG GUBER_TPU_PLATFORM=cpu
+ENV GUBER_TPU_PLATFORM=${GUBER_TPU_PLATFORM}
 
 COPY --from=build /install /usr/local
 

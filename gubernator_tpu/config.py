@@ -69,7 +69,7 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_BREAKER_WINDOW": "sliding failure window length",
     "GUBER_CACHE_SIZE": "device bucket-table capacity (slots)",
     "GUBER_COLD_CACHE_SIZE": "host-side cold-tier entry budget (0 = off)",
-    "GUBER_COMPILE_CACHE_DIR": "persistent XLA compile cache dir / 'off'",
+    "GUBER_COMPILE_CACHE_DIR": "XLA compile cache dir / 'off' (JAX_COMPILATION_CACHE_DIR wins)",
     "GUBER_DATA_CENTER": "datacenter name for region-aware picking",
     "GUBER_DEBUG_ENDPOINTS": "serve /debug/* introspection endpoints (0/1)",
     "GUBER_DISABLE_BATCHING": "disable peer-forwarding batches",
@@ -105,7 +105,7 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_GRPC_MAX_CONN_AGE_SEC": "max gRPC client connection age (0 = inf)",
     "GUBER_HTTP_ADDRESS": "HTTP/JSON gateway listen address",
     "GUBER_INGEST_ARENA_SLABS": "preallocated wire-decode column slabs (0 = off)",
-    "GUBER_INGEST_FALLBACK_LIMIT": "arena-miss plain allocations per window before shed",
+    "GUBER_INGEST_FALLBACK_LIMIT": "arena-miss plain-allocation budget per window, in 1000-row batches",
     "GUBER_INSTANCE_ID": "unique instance id for logs/tracing",
     "GUBER_K8S_ENDPOINTS_SELECTOR": "k8s discovery: endpoints selector",
     "GUBER_K8S_NAMESPACE": "k8s discovery: namespace",
@@ -172,7 +172,7 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_TPU_GLOBAL_MESH_NODES": "GLOBAL mesh size (0 = gRPC loops only)",
     "GUBER_TPU_MAX_BATCH": "request columns per device tick",
     "GUBER_TPU_MESH_SHARDS": "table shards on the device mesh",
-    "GUBER_TPU_PLATFORM": "force jax platform (e.g. cpu)",
+    "GUBER_TPU_PLATFORM": "jax platform; the CPU must be named, never a fallback",
     "GUBER_TPU_SORTED32": "0 = x64 oracle tick for duplicate batches",
     "GUBER_TPU_TABLE_LAYOUT": "bucket-table layout: auto/columns/row",
 }

@@ -130,7 +130,7 @@ class _WorkerArena:
     def fits(self, n: int, blob_cap: int) -> bool:
         return n <= self.max_batch and blob_cap <= self.blob_cap
 
-    def try_fallback(self) -> bool:
+    def try_fallback(self, n: int) -> bool:
         return False  # busy ring = backpressure, never heap growth
 
 

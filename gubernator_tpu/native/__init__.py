@@ -2,9 +2,9 @@
 
 The compute path is JAX/XLA; the host runtime around it — here, the
 key→slot table that front-ends every device tick — is C++ (built by the
-Makefile in this directory).  Import degrades gracefully: when the shared
-library is absent and can't be built, callers fall back to the pure-Python
-SlotMap.
+Makefile in this directory).  When a shared library is absent or stale
+and can't be built, callers fall back to the pure-Python path and a
+WARNING says so.
 """
 
 from __future__ import annotations
@@ -20,9 +20,24 @@ import numpy as np
 log = logging.getLogger("gubernator.native")
 
 _DIR = os.path.dirname(__file__)
-_SO = os.path.join(_DIR, "libguber_slotmap.so")
+# library -> its one source file (the Makefile's rules, mirrored here so
+# a stale library is detected without needing make to be installed).
+_SOURCES = {
+    "libguber_slotmap.so": "slotmap.cc",
+    "libguber_wire.so": "wirecodec.cc",
+}
 _lib: Optional[ctypes.CDLL] = None
 _build_attempted = False
+_paths: dict = {}   # library name -> resolved path / None, once per process
+
+
+def _stale(name: str) -> bool:
+    """True when library ``name`` is absent or older than its source."""
+    so = os.path.join(_DIR, name)
+    src = os.path.join(_DIR, _SOURCES[name])
+    if not os.path.exists(so):
+        return True
+    return os.path.exists(src) and os.path.getmtime(src) > os.path.getmtime(so)
 
 
 def _try_build() -> None:
@@ -38,20 +53,45 @@ def _try_build() -> None:
             capture_output=True,
             timeout=120,
         )
-    except Exception as e:  # no toolchain / read-only install: fall back
-        log.debug("native slotmap build failed: %s", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        # no toolchain / read-only install: library_path warns per library
+        log.warning(
+            "native build failed: %s %s", e,
+            (getattr(e, "stderr", b"") or b"").decode(errors="replace")[-400:],
+        )
+
+
+def library_path(name: str) -> Optional[str]:
+    """Path of native library ``name``, (re)built first when it is absent
+    or older than its source — ``*.so`` is git-ignored, so what is on
+    disk is whatever an earlier checkout built.  None, with a WARNING,
+    when it cannot be brought up to date: callers then fall back to
+    their pure-Python path, which is correct but several times slower,
+    so the fallback is never silent."""
+    if name not in _paths:
+        if _stale(name):
+            _try_build()
+        if _stale(name):
+            log.warning(
+                "native library %s is missing or older than %s and could "
+                "not be built; using the slower pure-Python fallback",
+                name, _SOURCES[name],
+            )
+            _paths[name] = None
+        else:
+            _paths[name] = os.path.join(_DIR, name)
+    return _paths[name]
 
 
 def load_library() -> Optional[ctypes.CDLL]:
-    """The slotmap shared library, building it on first use if needed."""
+    """The slotmap shared library, built on first use if needed."""
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_SO):
-        _try_build()
-    if not os.path.exists(_SO):
+    so = library_path("libguber_slotmap.so")
+    if so is None:
         return None
-    lib = ctypes.CDLL(_SO)
+    lib = ctypes.CDLL(so)
     lib.guber_slotmap_new.restype = ctypes.c_void_p
     lib.guber_slotmap_new.argtypes = [ctypes.c_int64]
     lib.guber_slotmap_free.argtypes = [ctypes.c_void_p]
@@ -99,15 +139,12 @@ def load_library() -> Optional[ctypes.CDLL]:
         ctypes.c_int64,
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
     ]
-    try:  # a stale prebuilt library may predate this symbol
-        lib.guber_crc32_batch.argtypes = [
-            ctypes.c_char_p,
-            np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
-            ctypes.c_int64,
-            np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS"),
-        ]
-    except AttributeError:
-        log.debug("native library lacks guber_crc32_batch; rebuild to get it")
+    lib.guber_crc32_batch.argtypes = [
+        ctypes.c_char_p,
+        np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
+        ctypes.c_int64,
+        np.ctypeslib.ndpointer(np.uint32, flags="C_CONTIGUOUS"),
+    ]
     _lib = lib
     return lib
 
@@ -134,7 +171,7 @@ def crc32_batch(blob, offsets: np.ndarray) -> np.ndarray:
     a zlib loop when the native library is unavailable."""
     n = len(offsets) - 1
     lib = load_library()
-    if lib is None or not hasattr(lib, "guber_crc32_batch"):
+    if lib is None:
         import zlib
 
         mv = memoryview(blob)

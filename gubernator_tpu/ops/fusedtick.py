@@ -47,7 +47,6 @@ from gubernator_tpu.ops.engine import REQ32_INDEX, REQ32_ROWS
 from gubernator_tpu.ops.i64pair import I64
 from gubernator_tpu.ops.rowtable import ROW_W, _interpret
 from gubernator_tpu.ops.tfloat import T3
-from gubernator_tpu.utils import jaxcompat
 from gubernator_tpu.ops.transition32 import (
     PReq,
     PState,
@@ -61,7 +60,7 @@ F32 = jnp.float32
 # plus the zoo's tat/prev_count pairs), already a multiple of 8
 # sublanes.  The transposed block is (TW, C).
 TW = 24
-_VMEM = jaxcompat.pallas_tpu_compiler_params(
+_VMEM = pltpu.CompilerParams(
     vmem_limit_bytes=100 * 1024 * 1024)
 
 
@@ -224,7 +223,7 @@ def make_fused_tick_fn(capacity: int, chunk: int | None = None):
                 pltpu.SemaphoreType.DMA((2,)),   # write sems (per buffer)
             ],
         )
-        with jaxcompat.enable_x64(False):
+        with jax.enable_x64(False):
             table, resp = pl.pallas_call(
                 kernel,
                 grid_spec=grid_spec,
@@ -432,7 +431,7 @@ def make_fused_merged_tick_fn(capacity: int, chunk: int | None = None):
                 pltpu.SemaphoreType.DMA((2,)),
             ],
         )
-        with jaxcompat.enable_x64(False):
+        with jax.enable_x64(False):
             table, resp = pl.pallas_call(
                 kernel,
                 grid_spec=grid_spec,

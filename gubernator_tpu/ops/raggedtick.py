@@ -52,6 +52,7 @@ Reference semantics bar: algorithms.go:37-493 (via transition32).
 from __future__ import annotations
 
 import functools
+import math
 
 import gubernator_tpu.jaxinit  # noqa: F401  (x64 + compile cache before jax use)
 import jax
@@ -73,7 +74,6 @@ from gubernator_tpu.ops.fusedtick import (
 from gubernator_tpu.ops.i64pair import I64
 from gubernator_tpu.ops.rowtable import ROW_W, _interpret
 from gubernator_tpu.ops.transition32 import transition32
-from gubernator_tpu.utils import jaxcompat
 
 I32 = jnp.int32
 
@@ -197,7 +197,7 @@ def make_fused_ragged_tick_fn(capacity: int, chunk: int | None = None):
                 pltpu.SemaphoreType.DMA((2,)),   # write sems (per buffer)
             ],
         )
-        with jaxcompat.enable_x64(False):
+        with jax.enable_x64(False):
             table, resp = pl.pallas_call(
                 kernel,
                 grid_spec=grid_spec,
@@ -220,28 +220,41 @@ def _ragged_kernel(slots_ref, now_ref, ext_ref, m32_ref, table_ref,
     start = ext_ref[0]
     count = ext_ref[1]
     lo = ext_ref[2]
+    end = start + count
     cap_i = jnp.int32(capacity)
+    # Mosaic only loads/stores the (rows, B) request and response blocks
+    # at lane offsets it can PROVE are multiples of the 128-lane tile,
+    # and ``start`` is an arbitrary runtime lane.  So chunks are based
+    # at ``start`` rounded DOWN to the alignment (the extra head lanes
+    # are masked like any other off-extent lane), and the clamp bound
+    # B - C is a multiple of it too.  The serving shapes (B, C = 2048
+    # multiples) give 128; the small interpret-mode test chunks get
+    # their own gcd so the same arithmetic is exercised there.
+    A = math.gcd(128, C, B)
+    base0 = (start // jnp.int32(A)) * jnp.int32(A)
     # Runtime chunk count, rounded UP to even so the double-buffered
     # pair loop keeps its static buffer parity (fusedtick's read/write
     # interleave); an odd extent pays one phantom chunk whose lanes are
     # all masked (guard-row DMAs, merged-out responses).  count == 0
     # (warmup / idle shard) skips the pipeline entirely.
-    nc_live = (count + jnp.int32(C - 1)) // jnp.int32(C)
+    nc_live = jnp.where(
+        count > 0, (end - base0 + jnp.int32(C - 1)) // jnp.int32(C), 0)
     nc = nc_live + lax.rem(nc_live, jnp.int32(2))
     U = 8 if C % 8 == 0 else 1
 
     def chunk_base(c):
-        """(intended base, clamped base) of chunk ``c``: tail chunks
-        slide back into the batch and mask the re-read lanes."""
-        a = start + jnp.int32(c) * C
-        return a, jnp.clip(a, 0, jnp.int32(B - C))
+        """(first live lane, clamped aligned base) of chunk ``c``: tail
+        chunks slide back into the batch and mask the re-read lanes."""
+        a = base0 + jnp.int32(c) * C
+        actual = pl.multiple_of(jnp.clip(a, 0, jnp.int32(B - C)), A)
+        return jnp.maximum(a, start), actual
 
     def lslot(c, j):
         # Rebasing is clipped defensively: a host extent bug must never
         # aim a DMA outside the (capacity + 1)-row table.
         a, actual = chunk_base(c)
         idx = actual + j
-        live = (idx >= a) & (idx < start + count)
+        live = (idx >= a) & (idx < end)
         return jnp.where(
             live, jnp.clip(slots_ref[idx] - lo, 0, cap_i), cap_i)
 
@@ -289,7 +302,7 @@ def _ragged_kernel(slots_ref, now_ref, ext_ref, m32_ref, table_ref,
         T = _transpose_fwd(rbuf[buf, :, :TW])
         s = _pstate_from_T(T)
         lane = actual + lax.broadcasted_iota(I32, (1, C), 1)
-        live = (lane >= a) & (lane < start + count)
+        live = (lane >= a) & (lane < end)
         mr = m32_ref[:REQ32_ROWS, pl.ds(actual, C)]
         r = _preq_from_rows(mr)
         # Masked lanes ride the pipeline as guard rows: valid = 0 keeps

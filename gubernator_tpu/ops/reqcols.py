@@ -301,8 +301,13 @@ class ColumnArena:
         # counter resets whenever a slab recycles (a window completed),
         # so sustained exhaustion — not a transient burst — is what
         # exhausts the budget and triggers shed (docs/overload.md).
-        self.fallback_limit = max(0, int(fallback_limit))
-        self._window_fallbacks = 0
+        # The budget counts ROWS — ``fallback_limit`` full batches'
+        # worth — because rows are what the heap grows by: counted in
+        # frames, 41 concurrent one-item callers (8 slabs + 32 frames)
+        # were shed as "overload" with 41 rows in flight on a
+        # 4,096-row window.
+        self._fallback_rows = max(0, int(fallback_limit)) * self.max_batch
+        self._window_fallback_rows = 0
         # Telemetry: misses (all slabs busy / batch too big) say whether
         # the bound is sized to the deployment's concurrency;
         # fallbacks count the budgeted plain allocations taken while
@@ -349,21 +354,21 @@ class ColumnArena:
         return n <= self.max_batch and blob_cap <= self.blob_cap
 
     @hot_path
-    def try_fallback(self) -> bool:
-        """Spend one unit of the per-window plain-allocation budget.
-        False means the budget is gone: the caller sheds with
+    def try_fallback(self, n: int) -> bool:
+        """Spend ``n`` rows of the per-window plain-allocation budget.
+        False means the budget cannot cover them: the caller sheds with
         :class:`IngestOverloadError` semantics instead of allocating."""
         with self._lock:
-            if self._window_fallbacks >= self.fallback_limit:
+            if self._window_fallback_rows + n > self._fallback_rows:
                 return False
-            self._window_fallbacks += 1
+            self._window_fallback_rows += n
             self.metric_fallbacks += 1
             return True
 
     def _release(self, index: int) -> None:
         with self._lock:
             self._busy[index] = False
-            self._window_fallbacks = 0
+            self._window_fallback_rows = 0
 
     def in_use(self) -> int:
         with self._lock:

@@ -55,7 +55,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gubernator_tpu.utils import jaxcompat
 from gubernator_tpu.ops.buckets import (
     STATE_DTYPES,
     BucketState,
@@ -122,8 +121,7 @@ refresh_dma_tuning()
 # The kernels stage the whole (B, ROW_W) batch block in VMEM; Mosaic's
 # default scoped-vmem budget rejects a 64k-row tick (gather out-block +
 # scatter in-block, 32 MB each), so raise it — v5e has 128 MB of VMEM.
-# (CompilerParams is TPUCompilerParams on jax < 0.5-era pallas builds.)
-_COMPILER_PARAMS = jaxcompat.pallas_tpu_compiler_params(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=100 * 1024 * 1024)
 
 
@@ -243,7 +241,7 @@ def scatter_rows(table: jnp.ndarray, slots: jnp.ndarray,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA((DMA_RING,))],
     )
-    with jaxcompat.enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             _scatter_kernel,
             grid_spec=grid_spec,
@@ -267,7 +265,7 @@ def gather_rows(table: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
         out_specs=pl.BlockSpec((b, w), lambda t, *_: (0, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA((DMA_RING,))],
     )
-    with jaxcompat.enable_x64(False):
+    with jax.enable_x64(False):
         return pl.pallas_call(
             _gather_kernel,
             grid_spec=grid_spec,
@@ -275,33 +273,6 @@ def gather_rows(table: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
             compiler_params=_COMPILER_PARAMS,
             interpret=_interpret(),
         )(slots, table)
-
-
-_INTERPRET_OK = None
-
-
-def interpret_supported() -> bool:
-    """True when this toolchain can run the row kernels here: always on
-    real TPU (Mosaic), and on other backends only when the Pallas
-    interpreter of the installed jax can lower them (some versions choke
-    on the DMA-ring loops, e.g. mixed-dtype index adds on the 0.4.x
-    line).  Serving engines on non-TPU backends prefer the column layout
-    anyway (engine.make_layout_choice); row-layout tests skip when this
-    is False instead of failing on an emulation gap."""
-    global _INTERPRET_OK
-    if _INTERPRET_OK is None:
-        if not _interpret():
-            _INTERPRET_OK = True
-        else:
-            try:
-                st = RowState.zeros(8)
-                jax.jit(row_gather_state).lower(
-                    st, jnp.zeros(4, jnp.int32)
-                ).compile()
-                _INTERPRET_OK = True
-            except Exception:
-                _INTERPRET_OK = False
-    return _INTERPRET_OK
 
 
 # ----------------------------------------------------------------------

@@ -61,10 +61,8 @@ import gubernator_tpu.jaxinit  # noqa: F401  (x64 + compile cache before jax use
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from gubernator_tpu.utils.jaxcompat import shard_map
 
 from gubernator_tpu.ops import rowtable
 from gubernator_tpu.ops.buckets import BucketState, slice_field
@@ -76,6 +74,7 @@ from gubernator_tpu.ops.engine import (
     REQ32_ROWS,
     RESTORE_CHUNK,
     StagingRing,
+    describe_engine,
     device_dead_mask,
     items_from_columns,
     make_evict_fn,
@@ -515,7 +514,15 @@ class MeshTickEngine:
         self.metric_misses = 0
         self.metric_over_limit = 0
         self.metric_unexpired_evictions = 0
+        t0 = time.perf_counter()
         self._warmup()
+        self.warmup_seconds = time.perf_counter() - t0
+
+    def describe(self) -> dict:
+        return describe_engine(
+            self.mesh.devices.flat[0], self.n_shards, self.layout,
+            self.ops._fused32, self.warmup_seconds,
+        )
 
     def _warmup(self) -> None:
         """Compile the serving-path programs at startup (see

@@ -210,6 +210,15 @@ def _make_engine(conf: InstanceConfig):
         # GUBER_TPU_PLATFORM: pin the jax platform before any device use
         # (e.g. "cpu" for tests/CI hosts without a TPU).
         jax.config.update("jax_platforms", conf.tpu_platform)
+    devices = jax.devices()
+    if not jax.config.jax_platforms and devices[0].platform != "tpu":
+        # jax itself carries on on the CPU when it finds no chip; a
+        # daemon that nobody asked to run there must not.
+        raise RuntimeError(
+            f"no TPU found (jax picked {devices[0].platform!r}) and no "
+            "platform was named: set GUBER_TPU_PLATFORM=cpu (or "
+            "JAX_PLATFORMS=cpu) to serve from the CPU backend on purpose"
+        )
     if conf.tpu_mesh_shards > 1:
         from gubernator_tpu.parallel.mesh_engine import MeshTickEngine, make_mesh
 
@@ -228,7 +237,13 @@ def _make_engine(conf: InstanceConfig):
                 "engine (GUBER_TPU_MESH_SHARDS > 1): the SSD tier "
                 "hangs off the single-chip cold store; unset one"
             )
-        devices = jax.devices()[: conf.tpu_mesh_shards]
+        if len(devices) < conf.tpu_mesh_shards:
+            raise ValueError(
+                f"GUBER_TPU_MESH_SHARDS={conf.tpu_mesh_shards} but only "
+                f"{len(devices)} {devices[0].platform} device(s) are "
+                "visible to this process"
+            )
+        devices = devices[: conf.tpu_mesh_shards]
         local_cap = max(1, conf.cache_size // len(devices))
         return MeshTickEngine(
             mesh=make_mesh(devices),

@@ -526,6 +526,11 @@ class Daemon:
             self.conf.grpc_listen_address,
             self.conf.http_listen_address,
         )
+        log.info(
+            "engine: %s",
+            " ".join(f"{k}={v}" for k, v in
+                     self.instance.engine.describe().items()),
+        )
 
     def _start_edge_plane(self) -> None:
         """GUBER_EDGE_WORKERS > 0: bring up the shared-memory ingest

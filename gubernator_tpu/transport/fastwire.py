@@ -62,12 +62,8 @@ def load() -> Optional[ctypes.CDLL]:
     if _lib is not None or _load_attempted:
         return _lib
     _load_attempted = True
-    import os
-
-    so = os.path.join(os.path.dirname(native_mod.__file__), "libguber_wire.so")
-    if not os.path.exists(so):
-        native_mod._try_build()
-    if not os.path.exists(so):
+    so = native_mod.library_path("libguber_wire.so")
+    if so is None:
         return None
     lib = ctypes.CDLL(so)
     lib.guber_wire_count.restype = ctypes.c_int64
@@ -140,7 +136,7 @@ def parse_req(
         # exhaustion spends the arena's per-window fallback budget —
         # past it, the edge sheds instead of growing the heap.
         if (arena is not None and arena.fits(n, blob_cap)
-                and not arena.try_fallback()):
+                and not arena.try_fallback(n)):
             raise IngestOverloadError(
                 "ingest arena exhausted and fallback budget spent")
         blob = np.empty(blob_cap, np.uint8)

@@ -6,7 +6,7 @@ benchmark-action (/root/reference/.github/workflows/on-pull-request.yml,
 alert-threshold "200%"); this is the same gate over the BENCH_r*.json
 ladder:
 
-    python scripts/check_bench_regression.py BENCH_r01.json BENCH_r02.json
+    python scripts/check_bench_regression.py BENCH_r02.json BENCH_r03.json
 
 Exits 1 if the headline metric or any shared throughput rung regressed
 past the threshold (default 2.0x, override with --threshold).  Rungs

@@ -515,7 +515,11 @@ def test_edge_deadline_precedence():
     assert d is None  # default 0 = no deadline
 
 
-def test_arena_fallback_budget_is_per_window():
+@pytest.mark.parametrize("rows", [8, 1])
+def test_arena_fallback_budget_is_per_window(rows):
+    """The budget is fallback_limit full batches' worth of ROWS: two
+    8-row frames spend it, and so do sixteen one-row frames — a crowd
+    of small callers is not overload just for being many frames."""
     from gubernator_tpu.ops.reqcols import ColumnArena
 
     arena = ColumnArena(max_batch=8, slabs=1, fallback_limit=2)
@@ -524,11 +528,12 @@ def test_arena_fallback_budget_is_per_window():
     # Slab busy: fits-but-unleasable → budgeted fallbacks, then shed.
     assert arena.fits(4, 64)
     assert arena.lease(4, 64) is None
-    assert arena.try_fallback()
-    assert arena.try_fallback()
-    assert not arena.try_fallback()  # budget spent
-    assert arena.metric_fallbacks == 2
+    frames = 16 // rows
+    for _ in range(frames):
+        assert arena.try_fallback(rows)
+    assert not arena.try_fallback(rows)  # budget spent
+    assert arena.metric_fallbacks == frames
     lease.release()  # window completed: budget resets
     lease2 = arena.lease(4, 64)
-    assert arena.try_fallback()
+    assert arena.try_fallback(rows)
     lease2.release()

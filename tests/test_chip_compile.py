@@ -1,0 +1,213 @@
+"""Compile the served path's device programs for a TPU v5e that is
+described, not attached (on-chip-measurement guide, section 2): the
+installed Mosaic / XLA:TPU compiler refuses here exactly what it would
+refuse on the chip — unaligned lane slices, VMEM overruns, programs that
+do not fit HBM — at no chip time.  Nothing runs, so this says nothing
+about results; parity lives in the interpret-mode suites.
+
+Everything about the chip is built inside fixtures (never at import:
+only one process may load the TPU library, and every xdist worker
+imports every test file), the compiles run in the test's own process,
+and the persistent compile cache is switched off around them (an entry
+written for a described chip cannot be read back without one).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from gubernator_tpu.ops import fusedtick, raggedtick, rowtable
+from gubernator_tpu.ops.engine import REQ32_ROWS, group_upad
+
+CAP = 10_000_000        # BASELINE.json config 3: 5.12 GB of rows in HBM
+SHARDS = 4
+LOCAL_CAP = CAP // SHARDS
+B = 4096                # default GUBER_TPU_MAX_BATCH
+I32 = jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return list(topo.devices[:SHARDS])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Steer the kernels to the real Mosaic lowering (the backend here
+    is the CPU, so they would pick interpret mode) and keep the compiles
+    out of the persistent cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for mod in (rowtable, fusedtick, raggedtick):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setenv("GUBER_TPU_FUSED_TICK", "1")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+def row_state(cap, sharding):
+    return rowtable.RowState(
+        table=sds((cap + 1, rowtable.ROW_W), I32, sharding))
+
+
+def test_row_gather_and_scatter(mosaic, one_chip):
+    table = sds((CAP + 1, rowtable.ROW_W), I32, one_chip)
+    slots = sds((B,), I32, one_chip)
+    rows = sds((B, rowtable.ROW_W), I32, one_chip)
+    g = jax.jit(rowtable.gather_rows).lower(table, slots).compile()
+    s = jax.jit(rowtable.scatter_rows, donate_argnums=(0,)).lower(
+        table, slots, rows).compile()
+    assert has_kernel(g) and has_kernel(s)
+
+
+def test_fused_tick(mosaic, one_chip):
+    fn = jax.jit(fusedtick.make_fused_tick_fn(CAP), donate_argnums=(0,))
+    c = fn.lower(
+        row_state(CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
+        sds((), jnp.int64, one_chip),
+    ).compile()
+    assert has_kernel(c)
+
+
+def test_fused_merged_tick(mosaic, one_chip):
+    fn = jax.jit(
+        fusedtick.make_fused_merged_tick_fn(CAP), donate_argnums=(0,))
+    c = fn.lower(
+        row_state(CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
+        sds((B,), I32, one_chip), sds((), jnp.int64, one_chip),
+    ).compile()
+    assert has_kernel(c)
+
+
+def test_sorted_tick32_on_rows(mosaic, one_chip):
+    from gubernator_tpu.ops.tick32 import make_sorted_tick32_rows_fn
+
+    fn = jax.jit(make_sorted_tick32_rows_fn(CAP, "row"), donate_argnums=(0,))
+    c = fn.lower(
+        row_state(CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
+        sds((), jnp.int64, one_chip),
+    ).compile()
+    assert has_kernel(c)
+
+
+def test_layered_pipeline_at_warmup_shape(mosaic, one_chip):
+    """The one layered shape TickEngine._warmup compiles eagerly on a
+    TPU: w0 at the narrow width's floor, 2 layers of 512."""
+    from gubernator_tpu.ops.tick32 import jitted_layered_pipeline
+
+    w = max(1024, B // 4)
+    w0 = group_upad(w)
+    fn = jitted_layered_pipeline(CAP, "row", w0, 2, fused=True)
+    c = fn.lower(
+        row_state(CAP, one_chip),
+        sds((REQ32_ROWS, w0), I32, one_chip), sds((w0,), I32, one_chip),
+        sds((1, REQ32_ROWS, 512), I32, one_chip),
+        sds((1, 512), I32, one_chip),
+        sds((REQ32_ROWS, w), I32, one_chip),
+        sds((w,), I32, one_chip), sds((w,), I32, one_chip),
+        sds((), jnp.int64, one_chip),
+    ).compile()
+    assert has_kernel(c)
+
+
+def test_fused_ragged_tick(mosaic, one_chip):
+    """One shard's program of the sharded path: a runtime (start, count)
+    extent of the flat batch, at an arbitrary lane offset."""
+    fn = jax.jit(
+        raggedtick.make_fused_ragged_tick_fn(LOCAL_CAP), donate_argnums=(0,))
+    scalar = sds((), I32, one_chip)
+    c = fn.lower(
+        row_state(LOCAL_CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
+        scalar, scalar, scalar, sds((), jnp.int64, one_chip),
+    ).compile()
+    assert has_kernel(c)
+
+
+def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
+    """Both programs MeshTickEngine._warmup compiles when the daemon
+    starts with GUBER_TPU_MESH_SHARDS=4: the merge-capable x64 extent
+    walker and the fused ragged kernel, on a four-device mesh with the
+    table split 2.5M rows a shard."""
+    from gubernator_tpu.parallel.mesh_engine import ShardedOps
+
+    mesh = Mesh(np.array(four_chips), ("shard",))
+    ops = ShardedOps(mesh, LOCAL_CAP, "row")
+    assert ops._fused32
+    rep = NamedSharding(mesh, P())
+    state = rowtable.RowState(table=sds(
+        (SHARDS * (LOCAL_CAP + 1), rowtable.ROW_W), I32,
+        ops.state_shardings.table))
+    args = (
+        state, sds((REQ32_ROWS, B), I32, rep),
+        sds((SHARDS + 1,), I32, rep), sds((), jnp.int64, rep),
+    )
+    walker = ops.tick_ragged.lower(*args).compile()
+    fused = ops.tick_unique_ragged.lower(*args).compile()
+    assert has_kernel(walker) and has_kernel(fused)
+    for c in (walker, fused):
+        assert "all-reduce" in c.as_text()      # the one response psum
+        # each device holds its quarter of the table, not all of it
+        per_dev = c.memory_analysis().argument_size_in_bytes
+        assert per_dev < 2 * (LOCAL_CAP + 1) * rowtable.ROW_W * 4
+
+
+def test_global_sparse_reconcile_on_four_chips(mosaic, four_chips):
+    """The GLOBAL plane's fused sparse reconcile (x64 XLA, psum
+    collectives only) at the engine's sparse defaults: 1M replicated
+    slots, 4,096-row envelopes, one node per chip."""
+    from gubernator_tpu.ops.buckets import BucketState
+    from gubernator_tpu.parallel.global_mesh import (
+        ACC_ROWS, AUX_ROWS, make_global_sparse_step_fn)
+    from gubernator_tpu.parallel.partition import NodeLayout
+
+    cap = 1 << 20
+    mesh = Mesh(np.array(four_chips), ("node",))
+    lay = NodeLayout()
+    row = lay.shardings(mesh, P("node", None))
+    mat = lay.shardings(mesh, lay.mat3())
+    state = jax.tree.map(
+        lambda a: sds((SHARDS,) + a.shape, a.dtype, row),
+        jax.eval_shape(lambda: BucketState.zeros(cap)),
+    )
+    fn = jax.jit(
+        make_global_sparse_step_fn(mesh, cap, SHARDS, 4096),
+        donate_argnums=(0, 2),
+    )
+    c = fn.lower(
+        state,
+        sds((SHARDS, len(AUX_ROWS), cap), jnp.int64, mat),
+        sds((SHARDS, ACC_ROWS, cap), jnp.int64, mat),
+        sds((), jnp.int64, NamedSharding(mesh, P())),
+    ).compile()
+    assert "all-reduce" in c.as_text()

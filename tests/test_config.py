@@ -292,3 +292,87 @@ def test_reshard_knobs_defaults_and_overrides():
     assert c.config.reshard_verify is False
     with pytest.raises(ValueError, match="GUBER_RESHARD_FREEZE_TIMEOUT"):
         conf_from({"GUBER_RESHARD_FREEZE_TIMEOUT": "0"})
+
+
+# ----------------------------------------------------------------------
+# Device selection and the compile cache (no fallback that hides the
+# device; a cache placed from outside)
+# ----------------------------------------------------------------------
+def test_engine_refuses_silent_cpu_when_no_platform_named():
+    """jax carries on on the CPU when it finds no chip; a daemon nobody
+    asked to run there must not.  conftest names ``cpu``, so un-name it
+    for the one call."""
+    import jax
+
+    from gubernator_tpu.service.instance import InstanceConfig, _make_engine
+
+    named = jax.config.jax_platforms
+    jax.config.update("jax_platforms", None)
+    try:
+        with pytest.raises(RuntimeError, match="GUBER_TPU_PLATFORM=cpu"):
+            _make_engine(InstanceConfig(cache_size=64, tpu_max_batch=16))
+    finally:
+        jax.config.update("jax_platforms", named)
+    # Naming the platform through the knob is an explicit request.
+    eng = _make_engine(InstanceConfig(
+        cache_size=64, tpu_max_batch=16, tpu_platform="cpu"))
+    assert eng.describe()["platform"] == "cpu"
+    eng.close()
+
+
+def test_engine_refuses_fewer_devices_than_mesh_shards():
+    import jax
+
+    from gubernator_tpu.service.instance import InstanceConfig, _make_engine
+
+    want = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match=f"GUBER_TPU_MESH_SHARDS={want}"):
+        _make_engine(InstanceConfig(
+            cache_size=64, tpu_max_batch=16, tpu_mesh_shards=want))
+
+
+@pytest.fixture
+def cache_config():
+    """configure_compile_cache against a given environment, the process's
+    real setting restored afterwards."""
+    import jax
+
+    from gubernator_tpu import jaxinit
+
+    was = jax.config.jax_compilation_cache_dir
+
+    def run(env):
+        jaxinit.configure_compile_cache(env)
+        return jax.config.jax_compilation_cache_dir
+
+    yield run
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_var_wins_over_every_other_knob(
+        cache_config, tmp_path):
+    outside = str(tmp_path / "placed-from-outside")
+    other = tmp_path / "other"
+    for knob in ("off", str(other)):
+        assert cache_config({
+            "JAX_COMPILATION_CACHE_DIR": outside,
+            "GUBER_COMPILE_CACHE_DIR": knob,
+        }) == outside
+    # ...and no other directory was created on its behalf.
+    assert not other.exists()
+
+
+def test_compile_cache_default_is_inside_the_checkout_and_stable(
+        cache_config, tmp_path):
+    import os
+
+    import gubernator_tpu
+    from gubernator_tpu import jaxinit
+
+    repo = os.path.dirname(os.path.dirname(
+        os.path.abspath(gubernator_tpu.__file__)))
+    first = cache_config({"HOME": str(tmp_path / "a"), "TMPDIR": "/x"})
+    second = cache_config({"HOME": str(tmp_path / "b"), "TMPDIR": "/y"})
+    assert first == second == jaxinit.DEFAULT_COMPILE_CACHE_DIR
+    assert os.path.dirname(first) == repo and os.path.isdir(first)
+    assert cache_config({"GUBER_COMPILE_CACHE_DIR": "off"}) is None

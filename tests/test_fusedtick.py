@@ -78,17 +78,6 @@ def populate(rng, tick, state, b, rounds=3):
 # Python-stepped DMA loop stays seconds, not minutes.
 SMALL_CHUNK = 128 if jax.default_backend() == "tpu" else 32
 
-# The fused kernels share the row table's DMA-ring machinery; on jax
-# builds whose Pallas interpreter can't lower it these tests would fail
-# on the emulator, not the kernels (see rowtable.interpret_supported).
-from gubernator_tpu.ops import rowtable  # noqa: E402
-
-pytestmark = pytest.mark.skipif(
-    not rowtable.interpret_supported(),
-    reason="Pallas interpret mode cannot lower the row kernels on this "
-           "jax build",
-)
-
 
 @pytest.mark.parametrize("seed,mult", [(1, 4), (2, 8)])
 def test_fused_matches_unfused(seed, mult):

@@ -11,18 +11,8 @@ step.  Deterministic seeds keep failures reproducible.
 import numpy as np
 import pytest
 
-from gubernator_tpu.ops import rowtable
 from gubernator_tpu.ops.engine import TickEngine
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest
-
-# The row half of the parity pair runs the Pallas DMA-ring kernels; on
-# jax builds whose interpreter can't lower them this would fail on the
-# emulator, not the engine (see rowtable.interpret_supported).
-pytestmark = pytest.mark.skipif(
-    not rowtable.interpret_supported(),
-    reason="Pallas interpret mode cannot lower the row kernels on this "
-           "jax build",
-)
 
 BEHAVIOR_POOL = [
     Behavior.BATCHING,

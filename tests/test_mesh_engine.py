@@ -4,7 +4,6 @@ import jax
 import numpy as np
 import pytest
 
-from gubernator_tpu.ops import rowtable
 from gubernator_tpu.parallel.mesh_engine import MeshTickEngine, make_mesh
 from gubernator_tpu.types import Algorithm, RateLimitRequest, Status
 
@@ -146,11 +145,6 @@ def test_matches_single_device_engine():
     assert m_eng.metric_routed_overflows == 0
 
 
-@pytest.mark.skipif(
-    not rowtable.interpret_supported(),
-    reason="Pallas interpret mode cannot lower the row kernels on this "
-           "jax build",
-)
 def test_mesh_row_layout_matches_columns():
     """The Pallas row layout on the sharded mesh (interpret mode on CPU)
     must agree with the column layout decision for decision."""
@@ -170,11 +164,6 @@ def test_mesh_row_layout_matches_columns():
                [(r.status, r.remaining, r.reset_time) for r in b]
 
 
-@pytest.mark.skipif(
-    not rowtable.interpret_supported(),
-    reason="Pallas interpret mode cannot lower the row kernels on this "
-           "jax build",
-)
 def test_mesh_row_layout_snapshot_roundtrip():
     eng = MeshTickEngine(
         mesh=make_mesh(), local_capacity=32, max_batch=16, table_layout="row"

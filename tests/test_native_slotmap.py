@@ -82,3 +82,48 @@ def test_native_tombstone_rehash_stays_correct():
             assert s is not None and sm.key_of(s) == k
             sm.release(s)
         assert len(sm) == 0
+
+
+# ----------------------------------------------------------------------
+# The loader: ``*.so`` is git-ignored, so the library on disk is whatever
+# an earlier checkout built — it must be rebuilt when its source is
+# newer, and a fallback to pure Python must never be silent.
+# ----------------------------------------------------------------------
+@pytest.fixture
+def native_copy(tmp_path, monkeypatch):
+    """A private copy of the native sources, with the loader's
+    per-process memos cleared."""
+    import os
+    import shutil
+
+    for f in ("Makefile", "slotmap.cc", "wirecodec.cc"):
+        shutil.copy(os.path.join(native._DIR, f), tmp_path / f)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_paths", {})
+    monkeypatch.setattr(native, "_build_attempted", False)
+    return tmp_path
+
+
+def test_library_is_rebuilt_when_source_is_newer(native_copy, monkeypatch):
+    import os
+
+    so = native.library_path("libguber_wire.so")
+    assert so == str(native_copy / "libguber_wire.so") and os.path.exists(so)
+    # Age the library below its source: the next process must rebuild it.
+    src_mtime = os.path.getmtime(native_copy / "wirecodec.cc")
+    os.utime(so, (src_mtime - 100, src_mtime - 100))
+    monkeypatch.setattr(native, "_paths", {})
+    monkeypatch.setattr(native, "_build_attempted", False)
+    assert native._stale("libguber_wire.so")
+    assert native.library_path("libguber_wire.so") == so
+    assert os.path.getmtime(so) >= src_mtime
+
+
+def test_fallback_without_a_toolchain_warns(native_copy, monkeypatch, caplog):
+    import logging
+
+    monkeypatch.setenv("PATH", str(native_copy / "no-such-bin"))
+    with caplog.at_level(logging.WARNING, logger="gubernator.native"):
+        assert native.library_path("libguber_slotmap.so") is None
+    assert "pure-Python fallback" in caplog.text
+    assert "libguber_slotmap.so" in caplog.text

@@ -23,16 +23,6 @@ from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest, Status
 
 import jax.numpy as jnp
 
-# Interpret-mode emulation of the DMA-ring kernels is version-sensitive
-# (see rowtable.interpret_supported); on jax builds whose interpreter
-# can't lower them these tests would fail on the emulator, not the
-# kernels — real-TPU runs (GUBER_TEST_TPU=1) always execute them.
-pytestmark = pytest.mark.skipif(
-    not rowtable.interpret_supported(),
-    reason="Pallas interpret mode cannot lower the row kernels on this "
-           "jax build",
-)
-
 
 def req(key="k", hits=1, limit=10, duration=60_000, **kw):
     return RateLimitRequest(
@@ -89,6 +79,8 @@ def test_logical_matrix_round_trip():
         status=jnp.ones(b, jnp.int32),
         expire_at=jnp.full(b, 1_700_000_060_000, jnp.int64),
         in_use=jnp.asarray(np.arange(b) % 2 == 0),
+        tat=jnp.asarray(1_700_000_000_789 + np.arange(b) * (1 << 33), jnp.int64),
+        prev_count=jnp.asarray(np.arange(b) * (1 << 35) + 11, jnp.int64),
     )
     m = rowtable.logical_to_matrix(rows)
     back = rowtable.matrix_to_logical(m)

@@ -7,7 +7,6 @@ and write-through on every mutation.
 
 import pytest
 
-from gubernator_tpu.ops import rowtable
 from gubernator_tpu.ops.engine import TickEngine
 from gubernator_tpu.store import FileLoader, MockLoader, MockStore
 from gubernator_tpu.types import Algorithm, RateLimitRequest, Status
@@ -199,13 +198,7 @@ def test_load_columns_drops_expired_and_dedups(tmp_path):
     assert out.remaining == 3  # the LAST duplicate's remaining
 
 
-@pytest.mark.parametrize("layout", [
-    "columns",
-    pytest.param("row", marks=pytest.mark.skipif(
-        not rowtable.interpret_supported(),
-        reason="Pallas interpret mode cannot lower the row kernels on "
-               "this jax build")),
-])
+@pytest.mark.parametrize("layout", ["columns", "row"])
 def test_slim_export_probe_regimes(monkeypatch, layout):
     """The schema-specialized export (engine.export_columns) drops hi
     words a device probe proves redundant; this exercises all three
