@@ -1,0 +1,9 @@
+"""Device: 1 - (union of the device operations' intervals / traced span),
+from the profiler's trace."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
