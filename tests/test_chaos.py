@@ -19,13 +19,8 @@ from gubernator_tpu.config import BehaviorConfig, Config, DaemonConfig
 from gubernator_tpu.resilience import FaultInjector, ResilienceConfig
 from gubernator_tpu.transport.daemon import Daemon
 from gubernator_tpu.types import Behavior, RateLimitRequest, Status
-
-
-def req(name, key, hits=1, limit=1_000_000, duration=3_600_000, **kw):
-    return RateLimitRequest(
-        name=name, unique_key=key, hits=hits, limit=limit,
-        duration=duration, behavior=Behavior.GLOBAL, **kw
-    )
+from tests.helpers import global_req as req
+from tests.helpers import poll_consumed, warm_global_path
 
 
 def fast_chaos_conf():
@@ -53,29 +48,6 @@ def assert_no_loop_dead(cluster):
                 )
 
 
-async def poll_consumed(daemon, name, key, want, limit=1_000_000,
-                        timeout=10.0):
-    """Poll a daemon's local GLOBAL state until ``want`` hits landed."""
-    client = daemon.client()
-
-    async def poll():
-        while True:
-            # Per-RPC deadline above the poll budget: a first-compile
-            # stall on a loaded single-core host must surface as a slow
-            # poll, not a DEADLINE_EXCEEDED crash out of the helper.
-            r = (await client.get_rate_limits(
-                [req(name, key, hits=0, limit=limit)], timeout=30.0
-            ))[0]
-            if limit - r.remaining == want:
-                return r
-            await asyncio.sleep(0.02)
-
-    try:
-        return await asyncio.wait_for(poll(), timeout=timeout)
-    finally:
-        await client.close()
-
-
 async def test_chaos_100pct_failure_degrades_then_redelivers():
     """The ISSUE's acceptance run: one peer at 100% injected RPC failure.
     (a) the breaker opens within the configured threshold and GLOBAL
@@ -91,6 +63,7 @@ async def test_chaos_100pct_failure_degrades_then_redelivers():
         non_owner = c.list_non_owning_daemons(name, key)[0]
         ni = c.daemons.index(non_owner)
         owner_addr = owner.conf.grpc_listen_address
+        await warm_global_path(c, name, owner, non_owner)
         inj.set_fault(owner_addr, partition=True)
 
         client = non_owner.client()
@@ -144,6 +117,7 @@ async def test_chaos_drain_over_limit_preserved_in_degraded_mode():
         name, key = "chaos-drain", "dk"
         owner = c.find_owning_daemon(name, key)
         non_owner = c.list_non_owning_daemons(name, key)[0]
+        await warm_global_path(c, name, owner, non_owner)
         inj.set_fault(owner.conf.grpc_listen_address, partition=True)
 
         client = non_owner.client()
@@ -175,7 +149,7 @@ async def test_chaos_drain_over_limit_preserved_in_degraded_mode():
                     return r
                 await asyncio.sleep(0.02)
 
-        await asyncio.wait_for(owner_drained(), timeout=10)
+        await asyncio.wait_for(owner_drained(), timeout=60)
         # One more hit against the drained bucket is OVER_LIMIT (a zero-hit
         # query reports UNDER — nothing was requested).
         oc2 = owner.client()
@@ -205,6 +179,7 @@ async def test_chaos_kill_peer_mid_flush_redelivers_after_restart():
 
         # Kill the owner BEFORE any flush can land, then drive traffic:
         # every flush of these hits happens against a dead peer.
+        await warm_global_path(c, name, owner, non_owner)
         await owner.close()
         client = non_owner.client()
         sent = 0
@@ -241,6 +216,7 @@ async def test_chaos_intermittent_errors_recover_without_loss():
         name, key = "chaos-flap", "fk"
         owner = c.find_owning_daemon(name, key)
         non_owner = c.list_non_owning_daemons(name, key)[0]
+        await warm_global_path(c, name, owner, non_owner)
         inj.set_fault(owner.conf.grpc_listen_address,
                       from_peer=non_owner.advertise_address,
                       error_rate=0.5)
@@ -290,6 +266,7 @@ async def test_chaos_peer_death_mid_reshard_defined_state():
         non_owner = c.list_non_owning_daemons(name, key)[0]
         ni = c.daemons.index(non_owner)
         owner_addr = owner.conf.grpc_listen_address
+        await warm_global_path(c, name, owner, non_owner)
         inj.set_fault(owner_addr, partition=True)
 
         # Drive GLOBAL traffic into the dead owner until the breaker

@@ -11,12 +11,25 @@ import jax.numpy as jnp
 
 from gubernator_tpu.ops.engine import (
     REQ32_INDEX, REQ32_ROWS, _jitted_tick, pack_request_matrix32)
+from gubernator_tpu.ops.fusedtick import (
+    make_fused_merged_tick_fn, make_fused_tick_fn)
+from gubernator_tpu.ops.raggedtick import make_fused_ragged_tick_fn
 from gubernator_tpu.ops.rowtable import RowState
-from gubernator_tpu.ops.tick32 import make_tick32_fn, make_tick32_rows_fn
+from gubernator_tpu.ops.tick32 import (
+    make_merged_tick32_rows_fn, make_tick32_fn, make_tick32_rows_fn)
 from gubernator_tpu.types import Algorithm, Behavior, RateLimitRequest
 
 NOW = 1_700_000_000_000
-CAP = 2048
+
+# Small chunks force the double-buffered pipelined path (nc >= 2)
+# without production-width batches.  Mosaic (real TPU) requires the
+# chunk to be lane-aligned (128); interpret mode takes the least that
+# keeps the 8-wide issue loops and the ragged extents below apart.
+SMALL_CHUNK = 128 if jax.default_backend() == "tpu" else 16
+W = 4 * SMALL_CHUNK     # every case's batch width but the nc = 8 one
+# Room for the widest batch's unique slots (8 chunks), small enough
+# that most rows of a batch land on a slot ``state0`` populated.
+CAP = 16 * SMALL_CHUNK
 
 
 def make_plain(cap):
@@ -64,39 +77,38 @@ def build_batch(rng, b, n, with_behaviors=True):
     return m
 
 
-def populate(rng, tick, state, b, rounds=3):
-    """Run a few prior ticks so gathered states are non-trivial."""
-    for k in range(rounds):
-        m = build_batch(rng, b, b // 2, with_behaviors=False)
-        state, _ = tick(state, jnp.asarray(m), jnp.int64(NOW - 10_000 + k))
+# One jit object per program for the whole module: a new ``jax.jit(...)``
+# is a new trace and compile whatever the shape, and every case used to
+# build its own.  None of these donates, so the cases share ``state0``.
+PLAIN = make_plain(CAP)
+FUSED = jax.jit(make_fused_tick_fn(CAP, chunk=SMALL_CHUNK))
+RAGGED = jax.jit(make_fused_ragged_tick_fn(CAP, chunk=SMALL_CHUNK))
+
+
+@pytest.fixture(scope="module")
+def state0():
+    """A row table a few prior ticks have written, so gathered states
+    are non-trivial: three full-width batches over CAP = 4 W slots."""
+    rng = np.random.default_rng(0)
+    state = jax.tree.map(jnp.asarray, RowState.zeros(CAP))
+    for k in range(3):
+        m = build_batch(rng, W, W, with_behaviors=False)
+        state, _ = PLAIN(state, jnp.asarray(m), jnp.int64(NOW - 10_000 + k))
     return state
 
 
-# Small chunks force the double-buffered pipelined path (nc >= 2)
-# without production-width batches.  Mosaic (real TPU) requires the
-# chunk to be lane-aligned (128); interpret mode keeps 32 so the
-# Python-stepped DMA loop stays seconds, not minutes.
-SMALL_CHUNK = 128 if jax.default_backend() == "tpu" else 32
-
-
 @pytest.mark.parametrize("seed,mult", [(1, 4), (2, 8)])
-def test_fused_matches_unfused(seed, mult):
+def test_fused_matches_unfused(seed, mult, state0):
     """nc = 4/8 chunks exercises the double-buffered pipelined path."""
-    from gubernator_tpu.ops.fusedtick import make_fused_tick_fn
-
     b = SMALL_CHUNK * mult
     rng = np.random.default_rng(seed)
-    fused = jax.jit(make_fused_tick_fn(CAP, chunk=SMALL_CHUNK))
-    plain = make_plain(CAP)
 
-    state0 = jax.tree.map(jnp.asarray, RowState.zeros(CAP))
-    state0 = populate(rng, plain, state0, b)
-
-    m = build_batch(rng, b, int(rng.integers(1, b)))
+    # live rows reach into the last chunk, padding lanes close it
+    m = build_batch(rng, b, int(rng.integers(b - SMALL_CHUNK + 1, b)))
     now = jnp.int64(NOW)
 
-    s_f, r_f = fused(state0, jnp.asarray(m), now)
-    s_p, r_p = plain(state0, jnp.asarray(m), now)
+    s_f, r_f = FUSED(state0, jnp.asarray(m), now)
+    s_p, r_p = PLAIN(state0, jnp.asarray(m), now)
 
     n = int((np.asarray(m[REQ32_INDEX["slot"]]) < CAP).sum())
     np.testing.assert_array_equal(
@@ -105,28 +117,22 @@ def test_fused_matches_unfused(seed, mult):
         np.asarray(s_f.table), np.asarray(s_p.table))
 
 
-def test_fused_matches_merge_program_on_unique():
+def test_fused_matches_merge_program_on_unique(state0):
     """The x64 merge-capable program and the fused kernel agree on a
     unique-slot batch (the dispatch boundary in engine.submit_columns)."""
-    from gubernator_tpu.ops.fusedtick import make_fused_tick_fn
-
     rng = np.random.default_rng(7)
-    b = 4 * SMALL_CHUNK
-    fused = jax.jit(make_fused_tick_fn(CAP, chunk=SMALL_CHUNK))
     legacy = _jitted_tick(CAP, "row", sorted_input=True,
                           compact_resp=True, compact_req=True)
 
-    state0 = jax.tree.map(jnp.asarray, RowState.zeros(CAP))
-    plain = make_plain(CAP)
-    state0 = populate(rng, plain, state0, b)
-
-    m = build_batch(rng, b, 100)
+    n = W - 3   # the last chunk holds live rows and padding lanes
+    m = build_batch(rng, W, n)
     now = jnp.int64(NOW)
-    s_f, r_f = fused(state0, jnp.asarray(m), now)
-    s_l, r_l = legacy(state0, jnp.asarray(m), now)
+    s_f, r_f = FUSED(state0, jnp.asarray(m), now)
+    # the engine's program donates its state: hand it a copy
+    s_l, r_l = legacy(jax.tree.map(jnp.copy, state0), jnp.asarray(m), now)
 
     np.testing.assert_array_equal(
-        np.asarray(r_f)[:, :100], np.asarray(r_l)[:, :100])
+        np.asarray(r_f)[:, :n], np.asarray(r_l)[:, :n])
     mat_f = np.asarray(s_f.table)
     mat_l = np.asarray(s_l.table)
     # the merge program's padding lanes scatter to the guard row too;
@@ -134,25 +140,23 @@ def test_fused_matches_merge_program_on_unique():
     np.testing.assert_array_equal(mat_f[:CAP], mat_l[:CAP])
 
 
-def test_fused_single_chunk_width():
+def test_fused_single_chunk_width(state0):
     """b < chunk size exercises the nc == 1 path."""
     rng = np.random.default_rng(9)
-    b = 128
     fused = jax.jit(make_tick32_fn(CAP, "row", fused=True))
-    plain = make_plain(CAP)
-    state0 = jax.tree.map(jnp.asarray, RowState.zeros(CAP))
-    m = build_batch(rng, b, 100)
+    n = W - 3   # the last chunk holds live rows and padding lanes
+    m = build_batch(rng, W, n)
     now = jnp.int64(NOW)
     s_f, r_f = fused(state0, jnp.asarray(m), now)
-    s_p, r_p = plain(state0, jnp.asarray(m), now)
+    s_p, r_p = PLAIN(state0, jnp.asarray(m), now)
     np.testing.assert_array_equal(
-        np.asarray(r_f)[:, :100], np.asarray(r_p)[:, :100])
+        np.asarray(r_f)[:, :n], np.asarray(r_p)[:, :n])
     np.testing.assert_array_equal(
         np.asarray(s_f.table), np.asarray(s_p.table))
 
 
 @pytest.mark.parametrize("case", ["full", "odd", "tiny", "empty"])
-def test_ragged_fused_matches_plain_on_extent(case):
+def test_ragged_fused_matches_plain_on_extent(case, state0):
     """The ragged Pallas kernel, walking only ``[start, start + count)``
     of a flat global-slot batch, matches the plain program run on the
     localized extent alone — and leaves every off-extent response lane
@@ -162,23 +166,16 @@ def test_ragged_fused_matches_plain_on_extent(case):
     phantom-chunk even-rounding path); ``tiny`` is a sub-chunk extent
     (nc_live == 1 rounds to 2); ``empty`` skips the pipeline entirely.
     """
-    from gubernator_tpu.ops.raggedtick import make_fused_ragged_tick_fn
-
-    b = 4 * SMALL_CHUNK
+    b = W
     start, count = {
         "full": (0, b),
-        "odd": (37, 3 * SMALL_CHUNK - 5),
+        "odd": (SMALL_CHUNK + 5, 3 * SMALL_CHUNK - 5),
         "tiny": (5, 7),
         "empty": (50, 0),
     }[case]
     lo = CAP  # this shard's slot base in a 3-shard global slot space
 
     rng = np.random.default_rng(31)
-    ragged = jax.jit(make_fused_ragged_tick_fn(CAP, chunk=SMALL_CHUNK))
-    plain = make_plain(CAP)
-
-    state0 = jax.tree.map(jnp.asarray, RowState.zeros(CAP))
-    state0 = populate(rng, plain, state0, b)
 
     # Local batch (live rows at columns [0, count)), rolled so the live
     # block sits at [start, start + count): the oracle input.  The
@@ -201,9 +198,9 @@ def test_ragged_fused_matches_plain_on_extent(case):
         m_glob[REQ32_INDEX["hits"], start + count:] = 999
 
     now = jnp.int64(NOW)
-    s_f, r_f = ragged(state0, jnp.asarray(m_glob),
+    s_f, r_f = RAGGED(state0, jnp.asarray(m_glob),
                       np.int32(start), np.int32(count), np.int32(lo), now)
-    s_p, r_p = plain(state0, jnp.asarray(m_oracle), now)
+    s_p, r_p = PLAIN(state0, jnp.asarray(m_oracle), now)
 
     r_f = np.asarray(r_f)
     np.testing.assert_array_equal(
@@ -218,14 +215,11 @@ def test_ragged_fused_matches_plain_on_extent(case):
         np.asarray(s_f.table)[:CAP], np.asarray(s_p.table)[:CAP])
 
 
-def test_fused_merged_matches_xla_merged():
+def test_fused_merged_matches_xla_merged(state0):
     """The fused merged kernel (count fold in-register, 15-row resp) and
     the XLA merged rows program agree on state and every output row."""
-    from gubernator_tpu.ops.fusedtick import make_fused_merged_tick_fn
-    from gubernator_tpu.ops.tick32 import make_merged_tick32_rows_fn
-
     rng = np.random.default_rng(21)
-    b = 4 * SMALL_CHUNK
+    b = W
     fused = jax.jit(make_fused_merged_tick_fn(CAP, chunk=SMALL_CHUNK))
     inner = jax.jit(make_merged_tick32_rows_fn(CAP, "row"))
 
@@ -233,10 +227,7 @@ def test_fused_merged_matches_xla_merged():
         s, rows = inner(state, mhead, count, now)
         return s, jnp.stack(rows)
 
-    state0 = jax.tree.map(jnp.asarray, RowState.zeros(CAP))
-    state0 = populate(rng, make_plain(CAP), state0, b)
-
-    m = build_batch(rng, b, 100)
+    m = build_batch(rng, b, b - 3)
     count = np.ones(b, np.int32)
     live = np.asarray(m[REQ32_INDEX["slot"]]) < CAP
     count[live] = rng.integers(1, 9, int(live.sum()))

@@ -165,6 +165,7 @@ async def test_parity_with_grpc_reconciliation():
     gRPC protocol (sendHits → owner apply → broadcast) for the same hits."""
     from gubernator_tpu.cluster import Cluster
     from gubernator_tpu.config import BehaviorConfig
+    from tests.helpers import warm_global_path
 
     name, key = "parity", "pk"
     hits_a, hits_b, limit = 10, 20, 100
@@ -175,6 +176,9 @@ async def test_parity_with_grpc_reconciliation():
     try:
         owner = c.find_owning_daemon(name, key)
         non = c.list_non_owning_daemons(name, key)
+        # Both non-owners' flush paths meet their first compile with no
+        # deadline (helpers.warm_global_path) before the hits that count.
+        await warm_global_path(c, name, owner, *non)
         ca, cb = non[0].client(), non[1].client()
         g = lambda h: RateLimitRequest(
             name=name, unique_key=key, hits=h, limit=limit,
@@ -192,7 +196,7 @@ async def test_parity_with_grpc_reconciliation():
                     return resp[0]
                 await asyncio.sleep(0.02)
 
-        grpc_final = await asyncio.wait_for(owner_settled(), timeout=5.0)
+        grpc_final = await asyncio.wait_for(owner_settled(), timeout=60.0)
         await ca.close()
         await cb.close()
     finally:

@@ -19,6 +19,7 @@ from gubernator_tpu.ops.engine import (
     REQ32_ROWS,
     _jitted_tick,
     build_layer_plan,
+    group_upad,
     pack_wide_rows,
 )
 from gubernator_tpu.ops.tick32 import jitted_layered_pipeline
@@ -30,6 +31,31 @@ NOW = 1_700_000_000_000
 
 ORACLE = _jitted_tick(CAP, "columns", sorted_input=True, compact_resp=True,
                       compact_req=True)
+
+# Every plan of this module runs through ONE layered program.  The
+# plan's layer count is quantized (2, 4, then multiples of 4) and each
+# new (w0, k_pad) is a new trace and compile, which the batches below
+# used to pay one by one; w0 is group_upad(B) for every batch this
+# narrow, and the layer stack pads to the planner's own ceiling with
+# the same all-padding layers it pads with (slot = CAP heads, count 1).
+KPAD = 32   # build_layer_plan's max_layers
+
+
+def _layered(state, packed, plan, now):
+    mh0, cnt0, mhk, cntk, uidx, rank, kpad = plan
+    assert mh0.shape[1] == group_upad(B) and kpad <= KPAD
+    extra = KPAD - kpad
+    mhk = np.concatenate(
+        [mhk, np.zeros((extra,) + mhk.shape[1:], np.int32)])
+    mhk[kpad - 1:, R32["slot"], :] = CAP
+    cntk = np.concatenate(
+        [cntk, np.ones((extra, cntk.shape[1]), np.int32)])
+    fn = jitted_layered_pipeline(CAP, "columns", group_upad(B), KPAD)
+    return fn(
+        state, jnp.asarray(mh0), jnp.asarray(cnt0), jnp.asarray(mhk),
+        jnp.asarray(cntk), packed, jnp.asarray(uidx), jnp.asarray(rank),
+        jnp.int64(now),
+    )
 
 
 def _mixed_batch(rng, reset_frac=0.1, now=NOW):
@@ -84,17 +110,11 @@ def test_layered_matches_oracle(seed):
         m, n = _mixed_batch(rng)
         plan = build_layer_plan(m, n, CAP, NOW)
         assert plan is not None, "eligible batch must plan"
-        mh0, cnt0, mhk, cntk, uidx, rank, kpad = plan
-        fn = jitted_layered_pipeline(CAP, "columns", mh0.shape[1], kpad)
         packed = jnp.asarray(m)
         s1 = jax.tree.map(jnp.asarray, BucketState.zeros(CAP))
         s2 = jax.tree.map(jnp.asarray, BucketState.zeros(CAP))
         s1, r1 = ORACLE(s1, packed, jnp.int64(NOW))
-        s2, r2 = fn(
-            s2, jnp.asarray(mh0), jnp.asarray(cnt0), jnp.asarray(mhk),
-            jnp.asarray(cntk), packed, jnp.asarray(uidx),
-            jnp.asarray(rank), jnp.int64(NOW),
-        )
+        s2, r2 = _layered(s2, packed, plan, NOW)
         np.testing.assert_array_equal(
             np.asarray(r1)[:, :n], np.asarray(r2)[:, :n])
         for a, b in zip(jax.tree.leaves(s1), jax.tree.leaves(s2)):
@@ -110,15 +130,9 @@ def test_layered_chains_across_ticks():
         m, n = _mixed_batch(rng, now=NOW + t)
         plan = build_layer_plan(m, n, CAP, NOW + t)
         assert plan is not None
-        mh0, cnt0, mhk, cntk, uidx, rank, kpad = plan
-        fn = jitted_layered_pipeline(CAP, "columns", mh0.shape[1], kpad)
         packed = jnp.asarray(m)
         s1, r1 = ORACLE(s1, packed, jnp.int64(NOW + t))
-        s2, r2 = fn(
-            s2, jnp.asarray(mh0), jnp.asarray(cnt0), jnp.asarray(mhk),
-            jnp.asarray(cntk), packed, jnp.asarray(uidx),
-            jnp.asarray(rank), jnp.int64(NOW + t),
-        )
+        s2, r2 = _layered(s2, packed, plan, NOW + t)
         np.testing.assert_array_equal(
             np.asarray(r1)[:, :n], np.asarray(r2)[:, :n])
     for a, b in zip(jax.tree.leaves(s1), jax.tree.leaves(s2)):

@@ -10,10 +10,45 @@ from gubernator_tpu.types import Algorithm, RateLimitRequest, Status
 NOW = 1_700_000_000_000
 
 
+# Every MeshTickEngine traces and compiles its own sharded programs
+# (seconds each on 8 virtual devices), so the module builds ONE engine
+# per distinct parameter set and the tests tell their state apart by
+# key prefix and their metrics by difference.
 @pytest.fixture(scope="module")
 def engine():
     mesh = make_mesh(jax.devices())
     return MeshTickEngine(mesh=mesh, local_capacity=128, max_batch=64)
+
+
+@pytest.fixture(scope="module")
+def engine64():
+    """A second, smaller full-mesh engine: the load side of the snapshot
+    round trip and the sharded side of the single-chip comparison."""
+    return MeshTickEngine(
+        mesh=make_mesh(jax.devices()), local_capacity=64, max_batch=64)
+
+
+@pytest.fixture(scope="module")
+def row_engines():
+    """Two row-layout engines (Pallas in interpret mode on the CPU):
+    the second only ever loads what the first exported."""
+    return tuple(
+        MeshTickEngine(mesh=make_mesh(), local_capacity=32, max_batch=16,
+                       table_layout="row")
+        for _ in range(2))
+
+
+@pytest.fixture(scope="module")
+def store_engines():
+    """Two engines over ONE MockStore: what the first writes through,
+    the second (which never saw the key) reads through on its miss."""
+    from gubernator_tpu.store import MockStore
+
+    store = MockStore()
+    return store, tuple(
+        MeshTickEngine(mesh=make_mesh(), local_capacity=32, max_batch=16,
+                       store=store)
+        for _ in range(2))
 
 
 def req(key, hits=1, limit=10, duration=60_000, **kw):
@@ -82,14 +117,13 @@ def test_spill_chunking_beyond_tick_budget():
     assert all(r.error == "" and r.remaining == 99 for r in out)
 
 
-def test_mesh_snapshot_roundtrip():
+def test_mesh_snapshot_roundtrip(engine, engine64):
     """Loader.Save/Load over the sharded table (see TickEngine analog)."""
-    mesh = make_mesh(jax.devices())
-    e1 = MeshTickEngine(mesh=mesh, local_capacity=64, max_batch=64)
+    e1, e2 = engine, engine64
     e1.process([req(f"snap{i}", hits=3, limit=9) for i in range(40)], now=NOW)
-    items = e1.export_items()
+    items = [it for it in e1.export_items()
+             if it["key"].startswith("mesh_snap")]
     assert len(items) == 40
-    e2 = MeshTickEngine(mesh=mesh, local_capacity=64, max_batch=64)
     e2.load_items(items, now=NOW)
     out = e2.process(
         [req(f"snap{i}", hits=0, limit=9) for i in range(40)], now=NOW
@@ -97,16 +131,18 @@ def test_mesh_snapshot_roundtrip():
     assert all(r.remaining == 6 for r in out), out
 
 
-def test_matches_single_device_engine():
+def test_matches_single_device_engine(engine64):
     """The sharded tick must agree with the single-chip engine bit-for-bit
     — including same-tick duplicate keys: both engines sequence same-slot
     requests in arrival order (stable slot sorts on both paths), so even
     duplicate-bearing windows must match decision for decision."""
     from gubernator_tpu.ops.engine import TickEngine
 
-    mesh = make_mesh(jax.devices())
-    m_eng = MeshTickEngine(mesh=mesh, local_capacity=64, max_batch=64)
-    s_eng = TickEngine(capacity=512, max_batch=256)
+    m_eng = engine64
+    routed0 = m_eng.metric_routed_windows
+    # the single-chip programs test_ragged_parity_fuzz_vs_single_chip
+    # compiles too (they are cached by capacity and width)
+    s_eng = TickEngine(capacity=2048, max_batch=64)
     rng = np.random.default_rng(7)
     for t in range(6):
         reqs = [
@@ -141,16 +177,14 @@ def test_matches_single_device_engine():
                 y.error,
             )
     # The routed flat format served every window (no silent fallback).
-    assert m_eng.metric_routed_windows == 6
+    assert m_eng.metric_routed_windows - routed0 == 6
     assert m_eng.metric_routed_overflows == 0
 
 
-def test_mesh_row_layout_matches_columns():
+def test_mesh_row_layout_matches_columns(row_engines):
     """The Pallas row layout on the sharded mesh (interpret mode on CPU)
     must agree with the column layout decision for decision."""
-    row = MeshTickEngine(
-        mesh=make_mesh(), local_capacity=32, max_batch=16, table_layout="row"
-    )
+    row = row_engines[0]
     col = MeshTickEngine(
         mesh=make_mesh(), local_capacity=32, max_batch=16,
         table_layout="columns",
@@ -164,16 +198,12 @@ def test_mesh_row_layout_matches_columns():
                [(r.status, r.remaining, r.reset_time) for r in b]
 
 
-def test_mesh_row_layout_snapshot_roundtrip():
-    eng = MeshTickEngine(
-        mesh=make_mesh(), local_capacity=32, max_batch=16, table_layout="row"
-    )
+def test_mesh_row_layout_snapshot_roundtrip(row_engines):
+    eng, e2 = row_engines
     eng.process([req(f"snapr{i}", hits=2, limit=9) for i in range(20)], now=NOW)
-    items = eng.export_items()
+    items = [it for it in eng.export_items()
+             if it["key"].startswith("mesh_snapr")]
     assert len(items) == 20
-    e2 = MeshTickEngine(
-        mesh=make_mesh(), local_capacity=32, max_batch=16, table_layout="row"
-    )
     e2.load_items(items, now=NOW + 1)
     out = e2.process([req("snapr3", hits=0, limit=9)], now=NOW + 1)[0]
     assert out.remaining == 7
@@ -363,27 +393,22 @@ def test_ragged_parity_fuzz_vs_single_chip(engine):
     assert engine.metric_routed_overflows == over0 == 0
 
 
-def test_mesh_store_write_and_read_through():
+def test_mesh_store_write_and_read_through(store_engines):
     """Store on the sharded engine: on_change after every mutation,
     get() consulted on miss, remove() on eviction-by-reset."""
-    from gubernator_tpu.store import MockStore
-
-    store = MockStore()
-    eng = MeshTickEngine(
-        mesh=make_mesh(), local_capacity=32, max_batch=16, store=store
-    )
+    store, (eng, eng2) = store_engines
+    changes0 = store.called["OnChange()"]
     eng.process([req("st1", hits=2, limit=10)], now=NOW)
-    assert store.called["OnChange()"] == 1
+    assert store.called["OnChange()"] - changes0 == 1
     item = store.data["mesh_st1"]
     assert item["remaining"] == 8
 
-    # A fresh engine read-throughs the persisted state on miss.
-    eng2 = MeshTickEngine(
-        mesh=make_mesh(), local_capacity=32, max_batch=16, store=store
-    )
+    # An engine that never saw the key read-throughs the persisted
+    # state on its miss.
+    gets0 = store.called["Get()"]
     out = eng2.process([req("st1", hits=1, limit=10)], now=NOW + 1)[0]
     assert out.remaining == 7
-    assert store.called["Get()"] >= 1
+    assert store.called["Get()"] - gets0 >= 1
 
 
 def test_mesh_store_via_instance_config():

@@ -208,6 +208,9 @@ async def test_global_hits_apply_locally_when_owner():
         InstanceConfig(behaviors=behaviors, cache_size=256)
     )
     try:
+        # The instance is new: its first window traces and lowers the
+        # tick, which must not run against the wait below.
+        await inst.apply_local([req(name="gl", key="warm", hits=0, limit=10)])
         r = req(name="gl", key="own", hits=3, limit=10,
                 behavior=Behavior.GLOBAL)
         inst.global_mgr.queue_hit(r)
@@ -221,7 +224,7 @@ async def test_global_hits_apply_locally_when_owner():
                     return
                 await asyncio.sleep(0.01)
 
-        await asyncio.wait_for(settled(), timeout=5)
+        await asyncio.wait_for(settled(), timeout=30)
     finally:
         await inst.close()
 

@@ -29,6 +29,7 @@ from gubernator_tpu.transport.tlsutil import (
     setup_tls,
 )
 from gubernator_tpu.types import PeerInfo, RateLimitRequest, Status
+from tests.helpers import spread_keys
 
 
 @pytest.fixture(scope="module")
@@ -211,8 +212,7 @@ async def test_mtls_cluster_forwarding(ca_files):
     # set_peers applies asynchronously — poll until the picker is live.
     key = None
     for _ in range(300):  # up to 15s: suite-load makes propagation slow
-        for i in range(64):
-            cand = f"k{i}"
+        for cand in spread_keys(64):
             peer = d1.instance.get_peer(f"test_tls_{cand}")
             if peer is not None and not peer.info.is_owner:
                 key = cand
