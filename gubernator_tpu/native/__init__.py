@@ -26,18 +26,34 @@ _SOURCES = {
     "libguber_slotmap.so": "slotmap.cc",
     "libguber_wire.so": "wirecodec.cc",
 }
+# library -> the newest symbol this checkout binds from it.  ``*.so`` is
+# git-ignored and make compares only mtimes, so a library an older
+# checkout built can be newer than its source and still lack it: that
+# counts as stale too (rebuilt, or refused with the WARNING), so a
+# library is never bound half-way.
+_NEWEST_SYMBOL = {"libguber_slotmap.so": b"guber_slotmap_pack_window"}
 _lib: Optional[ctypes.CDLL] = None
 _build_attempted = False
 _paths: dict = {}   # library name -> resolved path / None, once per process
 
 
 def _stale(name: str) -> bool:
-    """True when library ``name`` is absent or older than its source."""
+    """True when library ``name`` is absent, older than its source, or
+    lacks the newest symbol this checkout binds from it."""
     so = os.path.join(_DIR, name)
     src = os.path.join(_DIR, _SOURCES[name])
     if not os.path.exists(so):
         return True
-    return os.path.exists(src) and os.path.getmtime(src) > os.path.getmtime(so)
+    if os.path.exists(src) and os.path.getmtime(src) > os.path.getmtime(so):
+        return True
+    symbol = _NEWEST_SYMBOL.get(name)
+    if symbol is None:
+        return False
+    # The dynamic string table holds every exported name; reading the
+    # file (tens of KB) loads nothing, so a rebuild can still replace it.
+    # guber: allow-G001(one read per library per process - library_path memoizes the answer, every later hot-path call hits _paths) # guber: allow-G002(same one-shot read at first load, memoized in _paths) # guber: allow-G007(same one-shot read - a cold-start cost beside the dlopen it guards, never steady-state)
+    with open(so, "rb") as f:
+        return symbol not in f.read()
 
 
 def _try_build() -> None:
@@ -48,7 +64,10 @@ def _try_build() -> None:
     try:
         # guber: allow-G001(one-shot memoized toolchain build at first use - every later hot-path call hits the cached .so) # guber: allow-G007(same one-shot build - serialized behind _build_attempted, a cold-start cost, never steady-state)
         subprocess.run(
-            ["make", "-C", _DIR, "-s"],
+            # -B, for the stale libraries alone: one that only lacks a
+            # symbol is newer than its source, and make would leave it.
+            ["make", "-C", _DIR, "-s", "-B",
+             *(name for name in _SOURCES if _stale(name))],
             check=True,
             capture_output=True,
             timeout=120,
@@ -73,9 +92,11 @@ def library_path(name: str) -> Optional[str]:
             _try_build()
         if _stale(name):
             log.warning(
-                "native library %s is missing or older than %s and could "
-                "not be built; using the slower pure-Python fallback",
+                "native library %s is missing or stale (older than %s, or "
+                "without %s) and could not be built; using the slower "
+                "pure-Python fallback",
                 name, _SOURCES[name],
+                _NEWEST_SYMBOL.get(name, b"its newest symbol").decode(),
             )
             _paths[name] = None
         else:
@@ -114,6 +135,28 @@ def load_library() -> Optional[ctypes.CDLL]:
         np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
         np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
     ]
+    i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+    # The window pass is bound twice: through CDLL, which drops the GIL
+    # for the call, and through PyDLL, which holds it.  Dropping it is
+    # what lets the gRPC thread and the resolver run beside a wide
+    # window's pass; taking it back costs a hand-off (0.1-0.3 ms a time
+    # on the served path), which a narrow window's few microseconds of
+    # work do not repay (NativeSlotMap.pack_window chooses).
+    lib.pack_window_gil_held = ctypes.PyDLL(so).guber_slotmap_pack_window
+    for fn in (lib.guber_slotmap_pack_window, lib.pack_window_gil_held):
+        fn.restype = ctypes.c_int64
+        fn.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, i64p, ctypes.c_int64,
+            i64p, i64p, i64p, i64p, i64p, i64p, i64p,  # the request columns
+            ctypes.c_int64, ctypes.c_int64,            # now, stop_on_miss
+            i32p, ctypes.c_int64,                      # slab, its width
+            i64p, np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
+            i64p,                                      # slots, known, inv
+            i64p, ctypes.c_int64,                      # last_access, tick
+            np.ctypeslib.ndpointer(np.bool_, flags="C_CONTIGUOUS"),
+            i32p, ctypes.c_int64, i64p,                # plan scratch, info
+        ]
     lib.guber_slotmap_mapped.argtypes = [
         ctypes.c_void_p,
         np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
@@ -251,6 +294,76 @@ class NativeSlotMap:
             self._h, as_char_p(blob), offsets, n, slots, known
         )
         return slots, known
+
+    # guber_slotmap_pack_window's statuses (slotmap.cc PackStatus).
+    PACK_UNIQUE, PACK_GROUPED, PACK_DUPS_NO_PLAN = 0, 1, 2
+    PACK_RESOLVED_ONLY, PACK_NOT_TAKEN = -1, -2
+    # Rows from which pack_window drops the GIL for its call (numpy's
+    # own ufunc loops drop it above 500 elements).
+    PACK_GIL_FREE_ROWS = 512
+
+    def pack_window(self, cols, m32: np.ndarray, now: int,
+                    stop_on_miss: bool, last_access: np.ndarray, tick: int,
+                    dirty: np.ndarray, upad_cap: int):
+        """The host pack of one window in one native call (the GIL
+        released for all of it from PACK_GIL_FREE_ROWS rows): keys ->
+        slots, the leased ``(19, b)`` slab ``m32`` cleaned (zeros, the
+        slot row at the sentinel, ``capacity``) and the REQ32 rows
+        written into it in slot order, and the grouped plan where the
+        window's duplicates qualify (the numpy chain ``resolve_blob`` -> ``engine.pack_cols_req32`` ->
+        ``sort_packed_by_slot`` -> ``build_group_plan``, which it
+        equals array for array).  Every packed slot is stamped ``tick``
+        in ``last_access`` and marked in ``dirty`` where its row moves
+        state (both ``capacity`` long).  ``upad_cap``: the widest head
+        block the plan can need, ``engine.group_upad(b, n)``.
+
+        Returns ``(status, slots, known, inv, n_miss, plan)``.
+        PACK_NOT_TAKEN: a row is Gregorian, nothing was done.
+        PACK_RESOLVED_ONLY: a key found no slot, or ``stop_on_miss`` and
+        a key was new; ``slots`` / ``known`` are ``resolve_blob``'s and
+        the slab is untouched.  Otherwise the slab is packed and sorted
+        and ``inv`` maps request order to sorted lanes; for PACK_GROUPED
+        ``plan`` is ``build_group_plan``'s ``(mhead, count, uidx, rank,
+        u)``, else None."""
+        n = len(cols)
+        columns = [
+            np.ascontiguousarray(c, np.int64) for c in (
+                cols.key_offsets, cols.hits, cols.limit, cols.duration,
+                cols.algorithm, cols.behavior, cols.created_at, cols.burst)
+        ]
+        if len(columns[0]) != n + 1 or any(len(c) != n for c in columns[1:]):
+            raise ValueError("request columns disagree on the row count")
+        rows, b = m32.shape
+        if rows != 19 or n > b:
+            raise ValueError(f"a ({rows}, {b}) slab cannot hold {n} REQ32 lanes")
+        if len(last_access) != self.capacity or len(dirty) != self.capacity:
+            raise ValueError("per-slot arrays must be capacity long")
+        slots = np.empty(n, np.int64)
+        known = np.empty(n, np.uint8)
+        inv = np.empty(n, np.int64)
+        # The plan's arrays, laid out uidx[b] rank[b] count[upad]
+        # mhead[19][upad].  Fresh every window: they are uploaded
+        # asynchronously, and jax may read them until the copy is done.
+        scratch = np.empty(2 * b + (rows + 1) * upad_cap, np.int32)
+        info = np.zeros(3, np.int64)
+        call = (
+            self._lib.guber_slotmap_pack_window
+            if n >= self.PACK_GIL_FREE_ROWS else self._lib.pack_window_gil_held
+        )
+        status = call(
+            self._h, as_char_p(cols.key_blob), columns[0], n, *columns[1:],
+            now, stop_on_miss, m32, b, slots, known, inv,
+            last_access, tick, dirty, scratch, len(scratch), info,
+        )
+        n_miss, u, upad = info.tolist()
+        plan = None
+        if status == self.PACK_GROUPED:
+            at = 2 * b + upad
+            plan = (
+                scratch[at:at + rows * upad].reshape(rows, upad),
+                scratch[2 * b:at], scratch[:b], scratch[b:2 * b], u,
+            )
+        return status, slots, known, inv, n_miss, plan
 
     def release_batch(self, slots: np.ndarray) -> None:
         """Release a batch of slots in one native call."""

@@ -9,6 +9,7 @@
 //
 // Exposed as a plain C ABI for ctypes (no pybind11 in the image).
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
 #include <string>
@@ -98,9 +99,12 @@ struct SlotMap {
     return found ? table[idx] : -1;
   }
 
-  int64_t assign(const char* key, int64_t len) {
+  // get-or-assign with one hash and one probe: the slot (or -1 when the
+  // key is new and the table is full); *known says it had a mapping.
+  int64_t resolve(const char* key, int64_t len, uint8_t* known) {
     uint64_t h = fnv1a(key, len);
     auto [idx, found] = probe(key, len, h);
+    *known = found;
     if (found) return table[idx];
     if (free_list.empty()) return -1;
     int64_t s = free_list.back();
@@ -111,6 +115,11 @@ struct SlotMap {
     keys[s].assign(key, len);
     ++count;
     return s;
+  }
+
+  int64_t assign(const char* key, int64_t len) {
+    uint8_t known;
+    return resolve(key, len, &known);
   }
 
   void release(int64_t slot) {
@@ -169,17 +178,219 @@ void guber_slotmap_resolve_batch(void* p, const char* blob,
                                  int64_t* out_slots, uint8_t* out_known) {
   auto* m = static_cast<SlotMap*>(p);
   for (int64_t i = 0; i < n; ++i) {
-    const char* key = blob + offsets[i];
-    int64_t len = offsets[i + 1] - offsets[i];
-    int64_t existing = m->get(key, len);
-    if (existing >= 0) {
-      out_slots[i] = existing;
-      out_known[i] = 1;
-    } else {
-      out_slots[i] = m->assign(key, len);
-      out_known[i] = 0;
+    out_slots[i] = m->resolve(blob + offsets[i], offsets[i + 1] - offsets[i],
+                              &out_known[i]);
+  }
+}
+
+// ---------------------------------------------------------------------
+// The window pass: one call from a window's request columns to everything
+// its tick needs (engine.TickEngine._build_cols).  It is the numpy chain
+// resolve_blob -> pack_cols_req32 -> sort_packed_by_slot ->
+// build_group_plan of ops/engine.py, which stays as the fallback and as
+// the reference tests/test_group_plan.py holds this pass to, array for
+// array.  Bound through ctypes twice (native/__init__.py): a wide
+// window's call runs with the GIL released, a narrow one's keeps it.
+//
+// Row layout of the (19, b) int32 request slab: engine.REQ32_INDEX.
+// Narrow rows, then (lo, hi) pairs of the int64 columns.
+// ---------------------------------------------------------------------
+namespace {
+
+enum Req32Row : int {
+  kSlot = 0, kKnown = 1, kAlgorithm = 2, kBehavior = 3, kValid = 4,
+  kHits = 5, kLimit = 7, kDuration = 9, kCreatedAt = 11, kBurst = 13,
+  kGregExp = 15, kGregDur = 17, kReq32Rows = 19,
+};
+constexpr int64_t kCreatedUnset = -1;       // reqcols.CREATED_UNSET
+constexpr int64_t kGregorian = 4;           // Behavior.DURATION_IS_GREGORIAN
+constexpr int64_t kResetRemaining = 8;      // Behavior.RESET_REMAINING
+constexpr int32_t kLeaky = 1;               // Algorithm.LEAKY_BUCKET
+
+// What guber_slotmap_pack_window returns.
+enum PackStatus : int64_t {
+  kPackUnique = 0,        // slab packed and sorted, no slot repeats
+  kPackGrouped = 1,       // ... slots repeat, and the grouped plan is built
+  kPackDupsNoPlan = 2,    // ... slots repeat, no grouped plan (not eligible)
+  kPackResolvedOnly = -1, // keys resolved, nothing packed: a key found no
+                          // slot, or the caller asked to stop at a miss
+  kPackNotTaken = -2,     // nothing done: a row needs the host's calendar
+};
+
+inline int64_t pad_pow2(int64_t v) {
+  return v <= 1 ? 1 : static_cast<int64_t>(next_pow2(static_cast<uint64_t>(v)));
+}
+
+// Sort (slot << 32 | request row) keys: the stable sort of the lanes by
+// slot, arrival order breaking ties.  LSD radix over the slot's bits
+// (11 at a time; slots are below capacity), a comparison sort for the
+// few rows of a narrow window.
+void sort_lanes(std::vector<uint64_t>& keys, int64_t capacity) {
+  const size_t n = keys.size();
+  if (n <= 64) {
+    std::sort(keys.begin(), keys.end());
+    return;
+  }
+  constexpr int kBits = 11;
+  constexpr uint64_t kDigits = 1 << kBits;
+  std::vector<uint64_t> tmp(n);
+  for (int shift = 32; (capacity - 1) >> (shift - 32) > 0; shift += kBits) {
+    uint32_t at[kDigits + 1] = {0};
+    for (uint64_t k : keys) ++at[((k >> shift) & (kDigits - 1)) + 1];
+    for (uint64_t d = 0; d < kDigits; ++d) at[d + 1] += at[d];
+    for (uint64_t k : keys) tmp[at[(k >> shift) & (kDigits - 1)]++] = k;
+    keys.swap(tmp);
+  }
+}
+
+}  // namespace
+
+// Every loop below walks ONE row of the slab at a time: its rows lie a
+// power of two apart, so a loop that touched all nineteen at one lane
+// would keep evicting its own cache lines.
+//
+// cols: the seven int64 request columns of n rows each, in the order
+// hits, limit, duration, algorithm, behavior, created_at, burst.
+// m32: a leased (19, b) slab, in any state: a pass that packs cleans it
+//   first, a pass that returns a negative status leaves it untouched.
+// out_slots / out_known / out_inv: n entries each, request order.
+// last_access / dirty: the engine's per-slot arrays (capacity entries);
+//   every packed slot is stamped with tick, and marked dirty where its
+//   row moves state (hits != 0, a new key, or RESET_REMAINING).
+// plan: plan_cap int32 of scratch, written only for kPackGrouped as
+//   uidx[b] rank[b] count[upad] mhead[19][upad]  (upad in info[2]).
+// info: n_miss, u, upad.
+int64_t guber_slotmap_pack_window(
+    void* p, const char* blob, const int64_t* offsets, int64_t n,
+    const int64_t* hits, const int64_t* limit, const int64_t* duration,
+    const int64_t* algorithm, const int64_t* behavior,
+    const int64_t* created_at, const int64_t* burst, int64_t now,
+    int64_t stop_on_miss, int32_t* m32, int64_t b, int64_t* out_slots,
+    uint8_t* out_known, int64_t* out_inv, int64_t* last_access, int64_t tick,
+    uint8_t* dirty, int32_t* plan, int64_t plan_cap, int64_t* info) {
+  auto* m = static_cast<SlotMap*>(p);
+  for (int64_t i = 0; i < n; ++i) {
+    if (behavior[i] & kGregorian) return kPackNotTaken;
+  }
+
+  // 1. keys -> (slot, known), as guber_slotmap_resolve_batch.
+  int64_t n_miss = 0;
+  bool unplaced = false;
+  for (int64_t i = 0; i < n; ++i) {
+    out_slots[i] = m->resolve(blob + offsets[i], offsets[i + 1] - offsets[i],
+                              &out_known[i]);
+    unplaced |= out_slots[i] < 0;
+    n_miss += !out_known[i];
+  }
+  info[0] = n_miss;
+  if (unplaced || (stop_on_miss && n_miss)) return kPackResolvedOnly;
+
+  // 2 + 3. The slab cleaned (zeros, every lane aimed at the sentinel),
+  // then the lanes in slot order and the REQ32 rows written straight
+  // into them: src[j] is the request row that sorted lane j holds.
+  std::fill_n(m32, kReq32Rows * b, 0);
+  std::fill_n(m32 + kSlot * b, b, static_cast<int32_t>(m->capacity));
+  std::vector<uint64_t> order(n);
+  for (int64_t i = 0; i < n; ++i) {
+    order[i] = (static_cast<uint64_t>(out_slots[i]) << 32) |
+               static_cast<uint64_t>(i);
+  }
+  sort_lanes(order, m->capacity);
+  std::vector<int32_t> src(n);
+  int32_t* slot_row = m32 + kSlot * b;
+  bool has_dups = false;
+  for (int64_t j = 0; j < n; ++j) {
+    int32_t i = src[j] = static_cast<int32_t>(order[j] & 0xFFFFFFFFu);
+    int32_t slot = slot_row[j] = static_cast<int32_t>(order[j] >> 32);
+    has_dups |= j > 0 && slot == slot_row[j - 1];
+    out_inv[i] = j;
+    last_access[slot] = tick;
+    if (hits[i] != 0 || !out_known[i] || (behavior[i] & kResetRemaining)) {
+      dirty[slot] = 1;
     }
   }
+  auto put_narrow = [&](int row, auto&& value) {
+    int32_t* out = m32 + row * b;
+    for (int64_t j = 0; j < n; ++j) out[j] = static_cast<int32_t>(value(src[j]));
+  };
+  auto put_wide = [&](int row, auto&& value) {
+    int32_t* lo = m32 + row * b;
+    int32_t* hi = lo + b;
+    for (int64_t j = 0; j < n; ++j) {
+      int64_t v = value(src[j]);
+      lo[j] = static_cast<int32_t>(static_cast<uint32_t>(v));
+      hi[j] = static_cast<int32_t>(v >> 32);
+    }
+  };
+  put_narrow(kKnown, [&](int32_t i) { return out_known[i]; });
+  put_narrow(kAlgorithm, [&](int32_t i) { return algorithm[i]; });
+  put_narrow(kBehavior, [&](int32_t i) { return behavior[i]; });
+  std::fill_n(m32 + kValid * b, n, 1);
+  put_wide(kHits, [&](int32_t i) { return hits[i]; });
+  put_wide(kLimit, [&](int32_t i) { return limit[i]; });
+  put_wide(kDuration, [&](int32_t i) { return duration[i]; });
+  put_wide(kCreatedAt, [&](int32_t i) {
+    return created_at[i] != kCreatedUnset ? created_at[i] : now;
+  });
+  put_wide(kBurst, [&](int32_t i) { return burst[i]; });
+  if (!has_dups) return kPackUnique;
+
+  // 4. The grouped plan, by engine.build_group_plan's rules: worth it
+  // only when followers are at least an eighth of the rows ...
+  int64_t u = 1;
+  for (int64_t j = 1; j < n; ++j) u += slot_row[j] != slot_row[j - 1];
+  if (n - u < std::max<int64_t>(1, n / 8)) return kPackDupsNoPlan;
+  // ... and only when every follower folds into its head: identical to
+  // its neighbour in every parameter row (both halves), known, hits > 0,
+  // no RESET_REMAINING / Gregorian, a head that provably comes out
+  // alive (duration > 0, created_at >= now), token or leaky.
+  std::vector<uint8_t> folds(n, 1);
+  for (int r = kAlgorithm; r < kReq32Rows; ++r) {
+    if (r == kValid) continue;
+    const int32_t* row = m32 + r * b;
+    for (int64_t j = 1; j < n; ++j) folds[j] &= row[j] == row[j - 1];
+  }
+  auto wide_at = [&](int row, int64_t j) {
+    return (static_cast<int64_t>(m32[(row + 1) * b + j]) << 32) |
+           static_cast<uint32_t>(m32[row * b + j]);
+  };
+  for (int64_t j = 1; j < n; ++j) {
+    if (slot_row[j] != slot_row[j - 1]) continue;
+    if (!folds[j] || m32[kKnown * b + j] == 0 || wide_at(kHits, j) <= 0 ||
+        (m32[kBehavior * b + j] & (kResetRemaining | kGregorian)) != 0 ||
+        wide_at(kDuration, j) <= 0 || wide_at(kCreatedAt, j) < now ||
+        m32[kAlgorithm * b + j] > kLeaky) {
+      return kPackDupsNoPlan;
+    }
+  }
+  int64_t upad = pad_pow2(std::max({u, int64_t{256}, b / 4}));
+  if (2 * b + (kReq32Rows + 1) * upad > plan_cap) return kPackDupsNoPlan;
+  int32_t* uidx = plan;
+  int32_t* rank = plan + b;
+  int32_t* count = plan + 2 * b;
+  int32_t* mhead = plan + 2 * b + upad;
+  std::vector<int32_t> starts(u + 1, static_cast<int32_t>(n));
+  for (int64_t j = 0, g = -1; j < n; ++j) {
+    if (j == 0 || slot_row[j] != slot_row[j - 1]) starts[++g] = j;
+    uidx[j] = static_cast<int32_t>(g);
+    rank[j] = static_cast<int32_t>(j - starts[g]);
+  }
+  // Lanes past n read the last head column; heads past u aim at the
+  // guard row with a count of one.
+  std::fill(uidx + n, uidx + b, static_cast<int32_t>(upad - 1));
+  std::fill(rank + n, rank + b, 0);
+  for (int64_t g = 0; g < u; ++g) count[g] = starts[g + 1] - starts[g];
+  std::fill(count + u, count + upad, 1);
+  for (int r = 0; r < kReq32Rows; ++r) {
+    const int32_t* row = m32 + r * b;
+    int32_t* head = mhead + r * upad;
+    for (int64_t g = 0; g < u; ++g) head[g] = row[starts[g]];
+    std::fill(head + u, head + upad,
+              r == kSlot ? static_cast<int32_t>(m->capacity) : 0);
+  }
+  info[1] = u;
+  info[2] = upad;
+  return kPackGrouped;
 }
 
 // Fill out[slot] = 1 for every slot that currently has a key (the engine's

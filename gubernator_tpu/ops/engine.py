@@ -442,9 +442,12 @@ class StagingRing:
         self.metric_leases = 0
         self.metric_fallback_allocs = 0
 
-    def lease(self, b: int) -> np.ndarray:
+    def lease(self, b: int, clean: bool = True) -> np.ndarray:
         """A zeroed (rows, b) slab with the slot row pre-set to the
-        padding sentinel (padding lanes scatter out of bounds)."""
+        padding sentinel (padding lanes scatter out of bounds).
+        ``clean=False`` hands the slab out as its last window left it:
+        the caller's packer cleans it (:meth:`clean`, or the native
+        window pass, which does it with the GIL released)."""
         ring = self._stage.get(b)
         if ring is None:
             ring = self._stage[b] = [
@@ -472,9 +475,14 @@ class StagingRing:
             slot[1] = None
             m = slot[0]
             self._leased = slot
+        if clean:
+            self.clean(m)
+        return m
+
+    def clean(self, m: np.ndarray) -> None:
+        """Zero a slab and aim every lane at the padding sentinel."""
         m.fill(0)
         m[REQ32_INDEX["slot"]] = self.sentinel
-        return m
 
     def telemetry(self) -> dict:
         """Snapshot for /debug/state: ring shape, per-width slab counts
@@ -2069,17 +2077,19 @@ def make_slot_map(capacity: int):
 
 
 def describe_engine(device, devices: int, layout: str, fused: bool,
-                    warmup_seconds: float) -> dict:
+                    warmup_seconds: float, native_pack: bool = False) -> dict:
     """What an engine resolved to at construction — the daemon logs it
     once at start, so the backend that answers is never a guess (layout,
     fused and warm-up follow jax's default backend; see
-    make_layout_choice, tick32._resolve_fused, _warmup)."""
+    make_layout_choice, tick32._resolve_fused, _warmup; native_pack:
+    the host pack is the native window pass, TickEngine._build_cols)."""
     return {
         "platform": device.platform,
         "device_kind": device.device_kind,
         "devices": devices,
         "layout": layout,
         "fused": fused,
+        "native_pack": native_pack,
         "warmup_seconds": round(warmup_seconds, 3),
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,
     }
@@ -2357,6 +2367,11 @@ class TickEngine:
         self.metric_h2d_windows = 0
         self.metric_h2d_overlapped = 0
         self.slots = make_slot_map(self.capacity)
+        # The host pack in one native call (_build_cols): there with the
+        # native slot map, and then taken by every window it can answer.
+        # Windows it packed, to read against metric_h2d_windows.
+        self._native_pack = hasattr(self.slots, "pack_window")
+        self.metric_native_pack_windows = 0
         self._last_access = np.zeros(self.capacity, np.int64)
         # Slots mutated since the last export — the incremental snapshot's
         # working set (export_columns(dirty_only=True)).  Marked at the
@@ -2448,6 +2463,7 @@ class TickEngine:
             self.device, 1, self.layout,
             self.layout == "row" and _resolve_fused(None),
             self.warmup_seconds,
+            native_pack=self._native_pack,
         )
 
     def _warmup(self) -> None:
@@ -2772,26 +2788,39 @@ class TickEngine:
 
     @hot_path
     def _lease_matrix(self, b: int) -> np.ndarray:
-        """A zeroed (REQ32_ROWS, b) staging slab from the per-width ring
-        (slot row pre-set to the padding sentinel) — see
-        :class:`StagingRing` for the recycle contract.  Called under the
-        engine lock (ring state is unsynchronized)."""
+        """A (REQ32_ROWS, b) staging slab from the per-width ring — see
+        :class:`StagingRing` for the recycle contract.  Zeroed, its slot
+        row at the padding sentinel, unless the native window pass is
+        there to do that (it cleans the slab it packs; the numpy pack
+        cleans one the pass hands back).  Called under the engine lock
+        (ring state is unsynchronized)."""
         fr = flightrec.get()
-        if fr is None:
-            return self._staging.lease(b)
-        t0 = time.perf_counter()
-        m = self._staging.lease(b)
-        fr.note(fr.active(), "lease", time.perf_counter() - t0)
+        t0 = time.perf_counter() if fr is not None else 0.0
+        m = self._staging.lease(b, clean=not self._native_pack)
+        if fr is not None:
+            fr.note(fr.active(), "lease", time.perf_counter() - t0)
         return m
 
     @hot_path
     def _build_cols(self, cols: ReqColumns, now: int):
-        """Resolve keys to slots and pack the padded (12, B) request matrix
-        from a columnar batch — zero per-request Python on the no-error,
-        no-store path: one native blob resolve + a dozen vectorized numpy
-        writes + one argsort.
+        """One window's host pack: keys to slots, the padded (19, B)
+        request matrix in slot order, the dirty marks, and the grouped
+        plan where duplicates qualify.  Returns ``(m, n, errors, inv,
+        has_dups, plan)``.
 
-        A single int64 matrix means one H2D transfer per tick; per-transfer
+        The window the served path sees all day takes ONE native call
+        (native/slotmap.cc guber_slotmap_pack_window; for a wide window
+        ctypes drops the GIL for all of it, so the gRPC thread and the
+        resolver run beside it, where some hundred short numpy calls
+        each dropped the GIL and waited to take it back; a narrow
+        window's call keeps it, NativeSlotMap.pack_window).  What the
+        batch shows decides, no knob: a Gregorian row
+        (host calendar math), a key that finds no slot (reclaim and
+        retry), or a new key with a Store or cold tier to ask first
+        takes :meth:`_build_cols_numpy` — from the slots the native pass
+        already resolved, where it got that far.
+
+        A single int32 matrix means one H2D transfer per tick; per-transfer
         latency dominates small ticks.
         """
         n = len(cols)
@@ -2803,6 +2832,39 @@ class TickEngine:
         # are compiled at warmup.
         b = next(w for w in self._widths if w >= n)
         m = self._lease_matrix(b)
+        resolved = None
+        if self._native_pack:
+            sm = self.slots
+            status, slots, known, inv, n_miss, plan = sm.pack_window(
+                cols, m, now,
+                self.store is not None or self.cold is not None,
+                self._last_access, self._tick_count, self._dirty,
+                group_upad(b, n),
+            )
+            if status >= 0:
+                if n_miss:
+                    self._pending.update(slots[known == 0].tolist())
+                    # Insert pressure near a full table: reclaim in the
+                    # background (see _build_cols_numpy).
+                    self._maybe_trigger_reclaim()
+                self.metric_hits += n - n_miss
+                self.metric_misses += n_miss
+                self.metric_native_pack_windows += 1
+                return m, n, {}, inv, status != sm.PACK_UNIQUE, plan
+            if status == sm.PACK_RESOLVED_ONLY:
+                resolved = slots, known
+            self._staging.clean(m)  # the pass left the slab as leased
+        return self._build_cols_numpy(cols, now, m, resolved)
+
+    @hot_path
+    def _build_cols_numpy(self, cols: ReqColumns, now: int, m: np.ndarray,
+                          resolved=None):
+        """:meth:`_build_cols` in numpy, for the windows the native pass
+        leaves (and for the pure-Python slot map): one blob resolve
+        (``resolved``: the native pass's ``(slots, known)`` where it has
+        them already, so no key is resolved twice) + a dozen vectorized
+        numpy writes + one argsort + the plan."""
+        n = len(cols)
         R = REQ32_INDEX
         errors: Dict[int, str] = {}
 
@@ -2830,13 +2892,13 @@ class TickEngine:
             # guber: allow-G001(builds a host index list, never device)
             sel = np.array([i for i in range(n) if i not in errors], np.int64)
             if len(sel) == 0:
-                return m, n, errors, np.arange(n, dtype=np.int64), False
+                return m, n, errors, np.arange(n, dtype=np.int64), False, None
             slots, known = self.slots.resolve_batch(
                 [cols.key_bytes(int(i)) for i in sel]
             )
         else:
             sel = None  # the whole batch, contiguous
-            slots, known = self.slots.resolve_blob(
+            slots, known = resolved or self.slots.resolve_blob(
                 cols.key_blob, cols.key_offsets
             )
         if (slots < 0).any():
@@ -2882,7 +2944,7 @@ class TickEngine:
                 slots = slots[keep]
                 known = known[keep]
                 if len(slots) == 0:
-                    return m, n, errors, np.arange(n, dtype=np.int64), False
+                    return m, n, errors, np.arange(n, dtype=np.int64), False, None
         self._last_access[slots] = self._tick_count
         miss = known == 0
         self._pending.update(slots[miss].tolist())
@@ -2923,7 +2985,30 @@ class TickEngine:
         # dispatch to the parts-native program, duplicate-bearing ones
         # to the merge-capable program).
         inv, has_dups = sort_packed_by_slot(m, n, self.capacity)
-        return m, n, errors, inv, has_dups
+        # Dirty marking feeds export_columns(dirty_only=True); pure
+        # queries — hits == 0 on a known slot, no RESET_REMAINING —
+        # read bucket state without moving it, so marking them would
+        # inflate deltas under read-heavy traffic (advisor finding).
+        # Unknown slots always mark (the tick creates the row), as
+        # does RESET (removal/refill).  A leaky-bucket query can
+        # refill tokens on device, but the refill is derived from
+        # (updated_at, now) and recomputes identically after a
+        # baseline+delta restore, so skipping it loses nothing.
+        tick_slots = m[R["slot"], :n]
+        hr = R["hits"]
+        mutating = (
+            (m[hr, :n] != 0)
+            | (m[hr + 1, :n] != 0)
+            | (m[R["known"], :n] == 0)
+            | ((m[R["behavior"], :n] & int(Behavior.RESET_REMAINING)) != 0)
+        )
+        mut_slots = tick_slots[mutating & (tick_slots < self.capacity)]
+        if len(mut_slots):
+            self._dirty[mut_slots] = True
+        plan = (
+            build_group_plan(m, n, self.capacity, now) if has_dups else None
+        )
+        return m, n, errors, inv, has_dups, plan
 
     @hot_path
     def _promote_misses(
@@ -3073,21 +3158,17 @@ class TickEngine:
             self._last_now = max(self._last_now, now)
             self._tick_count += 1
             # Flight-recorder stage notes (docs/observability.md): "pack"
-            # covers slot resolve + matrix fill + argsort (the lease is
-            # also broken out inside _lease_matrix); "h2d" the queued
-            # device dispatch below.
+            # covers slot resolve + matrix fill + sort + dirty marks +
+            # the grouped plan, native or numpy (the lease is also
+            # broken out inside _lease_matrix); "h2d" the queued device
+            # dispatch below.
             fr = flightrec.get()
             t_pack = time.perf_counter() if fr is not None else 0.0
-            packed, n, errors, inv, has_dups = self._build_cols(cols, now)
+            packed, n, errors, inv, has_dups, plan = self._build_cols(
+                cols, now)
             if fr is not None:
                 fr.note(fr.active(), "pack", time.perf_counter() - t_pack)
             dev_m = None
-            # Named range in XProf captures (utils/tracing.py): device
-            # tick vs host packing shows up separated in the profile.
-            plan = (
-                build_group_plan(packed, n, self.capacity, now)
-                if has_dups else None
-            )
             t_h2d = time.perf_counter() if fr is not None else 0.0
             # Structural tick-path evidence: any SSD lookup issued while
             # the tick-dispatch block below runs would land in this
@@ -3097,6 +3178,8 @@ class TickEngine:
             ssd_reads0 = (
                 self.ssd.metric_lookup_calls if self.ssd is not None else 0
             )
+            # Named range in XProf captures (utils/tracing.py): device
+            # tick vs host packing shows up separated in the profile.
             with tracing.profile_annotation("guber.tick"):
                 if plan is not None:
                     # Grouped tick: unique heads through the parts
@@ -3175,27 +3258,6 @@ class TickEngine:
                     self.ssd.metric_lookup_calls - ssd_reads0
                 )
             self._pending.clear()
-            tick_slots = packed[REQ32_INDEX["slot"], :n]
-            # Dirty marking feeds export_columns(dirty_only=True); pure
-            # queries — hits == 0 on a known slot, no RESET_REMAINING —
-            # read bucket state without moving it, so marking them would
-            # inflate deltas under read-heavy traffic (advisor finding).
-            # Unknown slots always mark (the tick creates the row), as
-            # does RESET (removal/refill).  A leaky-bucket query can
-            # refill tokens on device, but the refill is derived from
-            # (updated_at, now) and recomputes identically after a
-            # baseline+delta restore, so skipping it loses nothing.
-            hr = REQ32_INDEX["hits"]
-            mutating = (
-                (packed[hr, :n] != 0)
-                | (packed[hr + 1, :n] != 0)
-                | (packed[REQ32_INDEX["known"], :n] == 0)
-                | ((packed[REQ32_INDEX["behavior"], :n]
-                    & int(Behavior.RESET_REMAINING)) != 0)
-            )
-            mut_slots = tick_slots[mutating & (tick_slots < self.capacity)]
-            if len(mut_slots):
-                self._dirty[mut_slots] = True
             slots_req = (
                 packed[REQ32_INDEX["slot"], :n][inv].astype(np.int64)
                 if self.store is not None

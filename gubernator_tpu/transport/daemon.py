@@ -816,6 +816,10 @@ class Daemon:
             engine_tel["h2d_windows"] = eng.metric_h2d_windows
             engine_tel["h2d_overlap_ratio"] = round(
                 eng.h2d_overlap_ratio(), 4)
+        if hasattr(eng, "metric_native_pack_windows"):
+            # over h2d_windows: the share of windows the native host
+            # pack answered (TickEngine._build_cols)
+            engine_tel["native_pack_windows"] = eng.metric_native_pack_windows
         staging = getattr(eng, "_staging", None)
         if staging is not None and hasattr(staging, "telemetry"):
             engine_tel["staging_ring"] = staging.telemetry()

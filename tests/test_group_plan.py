@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.ops import engine as E
+from gubernator_tpu.ops.reqcols import pack_blob
 from gubernator_tpu.types import Behavior, RateLimitRequest
 
 NOW = 1_700_000_000_000
@@ -22,12 +23,23 @@ def req(k, hits=1, limit=10, duration=60_000, **kw):
     )
 
 
+# Module-scoped, and a geometry other suite files compile too (tier-1 runs
+# near the driver's budget): the tests below that take it bring their own
+# keys, or only ask what the host pack made of a window.
+@pytest.fixture(scope="module")
+def eng():
+    return E.TickEngine(capacity=512, max_batch=64)
+
+
 def mk_engines(**kw):
     a = E.TickEngine(capacity=512, max_batch=256, **kw)
     b = E.TickEngine(capacity=512, max_batch=256, **kw)
     # Engine b: grouped path disabled — every duplicate batch takes the
-    # sequential rank-round program (the oracle).
+    # sequential rank-round program (the oracle).  Its host pack is the
+    # numpy one, whose plan step run_pair patches out; engine a's is the
+    # native pass wherever the library is there.
     b._tick32m = None
+    b._native_pack = False
     return a, b
 
 
@@ -105,22 +117,21 @@ def test_leaky_group_fraction_and_reset():
     ])
 
 
-def test_ineligible_batches_fall_back():
+def test_ineligible_batches_fall_back(eng):
     """RESET rows, parameter changes, and queries inside a duplicate
     group must reject the plan (sequential semantics preserved)."""
-    cap = 512
+    cap = eng.capacity
     mixes = [
         [req("a"), req("a", behavior=Behavior.RESET_REMAINING)],
         [req("a", hits=2), req("a", hits=3)],
         [req("a"), req("a", hits=0)],
         [req("a", limit=5), req("a", limit=6)],
     ]
-    eng = E.TickEngine(capacity=cap, max_batch=64)
     eng.process([req("a")], now=NOW)  # make the key known
     for reqs in mixes:
         cols = E.ReqColumns.from_requests(reqs)
-        m, n, errors, inv, has_dups = eng._build_cols(cols, NOW)
-        assert has_dups
+        m, n, errors, inv, has_dups, plan = eng._build_cols(cols, NOW)
+        assert has_dups and plan is None, reqs
         assert E.build_group_plan(m, n, cap, NOW) is None, reqs
     # ...and the engine still answers them correctly (rank rounds).
     rs = eng.process(
@@ -128,26 +139,239 @@ def test_ineligible_batches_fall_back():
     assert rs[0].remaining + 3 == rs[1].remaining + 2 + 3 or True
 
 
-def test_unique_batches_skip_plan():
-    eng = E.TickEngine(capacity=512, max_batch=64)
+def test_unique_batches_skip_plan(eng):
     cols = E.ReqColumns.from_requests([req(f"u{i}") for i in range(8)])
-    m, n, errors, inv, has_dups = eng._build_cols(cols, NOW)
-    assert not has_dups
+    m, n, errors, inv, has_dups, plan = eng._build_cols(cols, NOW)
+    assert not has_dups and plan is None
 
 
-def test_dead_head_groups_fall_back():
+def test_dead_head_groups_fall_back(eng):
     """A duplicate group whose head cannot come out alive (non-positive
     duration, or created_at backdated past now) must keep the sequential
     program: the x64 path re-installs expired buckets per member, which
     the closed-form fold cannot express."""
-    cap = 512
-    eng = E.TickEngine(capacity=cap, max_batch=64)
+    cap = eng.capacity
     eng.process([req("a")], now=NOW)
     for bad in (
         [req("a", duration=-5)] * 3,
         [req("a", created_at=NOW - 10_000)] * 3,
     ):
         cols = E.ReqColumns.from_requests(bad)
-        m, n, errors, inv, has_dups = eng._build_cols(cols, NOW)
-        assert has_dups
+        m, n, errors, inv, has_dups, plan = eng._build_cols(cols, NOW)
+        assert has_dups and plan is None
         assert E.build_group_plan(m, n, cap, NOW) is None, bad[0]
+
+
+# ----------------------------------------------------------------------
+# The native window pass (native/slotmap.cc guber_slotmap_pack_window)
+# against the numpy chain it replaces on the served path: resolve_blob +
+# pack_cols_req32 + sort_packed_by_slot + build_group_plan.  numpy and
+# native only: no jit, no engine.
+# ----------------------------------------------------------------------
+PACK_KINDS = (
+    "unique", "one_pair", "zipf", "leaky", "new_keys",
+    # a hot key's duplicates that may not fold
+    "reset", "param", "hits0", "backdated", "duration0", "zoo",
+    # windows the pass hands back
+    "gregorian", "full_table", "stop_on_miss",
+)
+PACK_CAP = 8192
+
+
+def _pack_window_case(kind, n, rng):
+    """(cols, keys to seed the slot map with, capacity) for one window."""
+    if kind in ("unique", "one_pair"):
+        ranks = rng.permutation(n)
+        if kind == "one_pair" and n > 1:
+            ranks[-1] = ranks[0]
+    else:
+        universe = max(2, n // 2)
+        w = 1.0 / np.arange(1, universe + 1)
+        ranks = rng.choice(universe, size=n, p=w / w.sum())
+    keys = [b"pk_%d" % r for r in ranks]
+    col = lambda v: np.asarray(v, np.int64)  # noqa: E731
+    c = dict(
+        hits=col(1 + ranks % 3),
+        limit=col(np.where(ranks % 11 == 0, 1 << 33, 10 + ranks % 7)),
+        duration=col(np.full(n, 60_000)),
+        algorithm=col(ranks % 2 if kind == "leaky" else np.zeros(n)),
+        behavior=col(np.where(ranks % 5 == 0,
+                              int(Behavior.DRAIN_OVER_LIMIT), 0)),
+        created_at=col(np.where(ranks % 3 == 0, NOW + 7, E.CREATED_UNSET)),
+        burst=col(ranks % 2 * 5),
+    )
+    hot = np.flatnonzero(ranks == np.bincount(ranks).argmax())
+    follower = hot[1] if len(hot) > 1 else hot[0]
+    if kind == "reset":
+        c["behavior"][follower] |= int(Behavior.RESET_REMAINING)
+    elif kind == "param":
+        c["limit"][follower] += 1
+    elif kind == "hits0":
+        c["hits"][follower] = 0
+    elif kind == "backdated":
+        c["created_at"][hot] = NOW - 10_000
+    elif kind == "duration0":
+        c["duration"][hot] = -5
+    elif kind == "zoo":
+        c["algorithm"][hot] = int(E.Algorithm.SLIDING_WINDOW)
+    elif kind == "gregorian":
+        c["behavior"][follower] |= int(Behavior.DURATION_IS_GREGORIAN)
+    blob, offsets = pack_blob(keys)
+    cols = E.ReqColumns(blob, offsets, **c)
+    seed = sorted(set(keys))
+    if kind in ("new_keys", "stop_on_miss"):
+        seed = seed[::2]
+    cap = PACK_CAP
+    if kind == "full_table":   # room for the seeded half of the keys only
+        seed = seed[::2]
+        cap = len(seed)
+    return cols, seed, cap
+
+
+@pytest.mark.parametrize("n", [1, 22, 1000, 4000, 4096])
+@pytest.mark.parametrize("kind", PACK_KINDS)
+def test_native_pack_window_equals_numpy_chain(kind, n):
+    from gubernator_tpu.native import NativeSlotMap, load_library
+
+    if load_library() is None:
+        pytest.skip("native slotmap library unavailable")
+    rng = np.random.default_rng(n * 31 + PACK_KINDS.index(kind))
+    cols, seed, cap = _pack_window_case(kind, n, rng)
+    b = 1024 if n <= 1024 else 4096
+    R = E.REQ32_INDEX
+    tick = 41
+
+    def fresh():
+        sm = NativeSlotMap(cap)
+        sm.assign_batch(seed)
+        m = np.zeros((E.REQ32_ROWS, b), np.int32)
+        m[R["slot"]] = cap
+        return sm, m, np.zeros(cap, np.int64), np.zeros(cap, bool)
+
+    # The numpy chain (TickEngine._build_cols_numpy without its errors).
+    ref, m_ref, seen_ref, dirty_ref = fresh()
+    slots, known = ref.resolve_blob(cols.key_blob, cols.key_offsets)
+    taken = (
+        not (cols.behavior & int(Behavior.DURATION_IS_GREGORIAN)).any()
+        and (slots >= 0).all()
+        and not (kind == "stop_on_miss" and (known == 0).any())
+    )
+    plan_ref = inv_ref = dups_ref = None
+    if taken:
+        E.pack_cols_req32(m_ref, cols, slots, known, NOW, slice(0, n))
+        inv_ref, dups_ref = E.sort_packed_by_slot(m_ref, n, cap)
+        if dups_ref:
+            plan_ref = E.build_group_plan(m_ref, n, cap, NOW)
+        seen_ref[slots] = tick
+        moves = ((cols.hits != 0) | (known == 0) | (
+            cols.behavior & int(Behavior.RESET_REMAINING) != 0))
+        dirty_ref[slots[moves]] = True
+
+    nat, m_nat, seen_nat, dirty_nat = fresh()
+    if taken:   # the pass cleans the slab it packs, whatever it held
+        m_nat[:] = rng.integers(-9, 9, m_nat.shape)
+    status, slots_n, known_n, inv_n, n_miss, plan_n = nat.pack_window(
+        cols, m_nat, NOW, kind == "stop_on_miss", seen_nat, tick, dirty_nat,
+        E.group_upad(b, n))
+
+    # What each kind is there to show (from a window wide enough to).
+    if n >= 22:
+        want = {
+            "unique": nat.PACK_UNIQUE, "one_pair": nat.PACK_DUPS_NO_PLAN,
+            "zipf": nat.PACK_GROUPED, "leaky": nat.PACK_GROUPED,
+            "new_keys": nat.PACK_GROUPED,
+            "gregorian": nat.PACK_NOT_TAKEN,
+            "full_table": nat.PACK_RESOLVED_ONLY,
+            "stop_on_miss": nat.PACK_RESOLVED_ONLY,
+        }.get(kind, nat.PACK_DUPS_NO_PLAN)
+        assert status == want
+    assert (status >= 0) == bool(taken)
+    np.testing.assert_array_equal(m_nat, m_ref)
+    np.testing.assert_array_equal(seen_nat, seen_ref)
+    np.testing.assert_array_equal(dirty_nat, dirty_ref)
+    if status == nat.PACK_NOT_TAKEN:
+        # nothing was done: the slot map is as seeded
+        ref, _, _, _ = fresh()
+    else:
+        np.testing.assert_array_equal(slots_n, slots)
+        np.testing.assert_array_equal(known_n, known)
+        assert n_miss == int((known == 0).sum())
+    if taken:
+        np.testing.assert_array_equal(inv_n, inv_ref)
+        assert (status != nat.PACK_UNIQUE) == dups_ref
+        assert (plan_n is None) == (plan_ref is None)
+        if plan_ref is not None:
+            for got, exp in zip(plan_n[:4], plan_ref[:4]):
+                assert got.dtype == exp.dtype and got.shape == exp.shape
+                assert got.flags.c_contiguous
+                np.testing.assert_array_equal(got, exp)
+            assert plan_n[4] == plan_ref[4]
+    # ... and the slot map is left in the same state.
+    every = np.arange(cap)
+    assert len(nat) == len(ref)
+    assert nat.keys_batch(every) == ref.keys_batch(every)
+    probe, offs = pack_blob([b"pk_%d" % r for r in range(n + 2)])
+    for a, b_ in zip(nat.resolve_blob(probe, offs),
+                     ref.resolve_blob(probe, offs)):
+        np.testing.assert_array_equal(a, b_)
+
+
+# ----------------------------------------------------------------------
+# When the native pass engages: by what the batch shows, no knob.
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["plain", "gregorian", "full_table", "store"])
+def test_native_pack_engages_by_what_the_batch_shows(eng, kind, monkeypatch):
+    """``metric_native_pack_windows`` rises by one for the window the
+    served path sees all day, and not for a Gregorian row, a key that
+    finds the table full, or a new key on a Store-backed engine; what
+    those windows answer is what the numpy pack answers."""
+    from gubernator_tpu.store import MockStore
+    from gubernator_tpu.utils import timeutil
+
+    if not eng._native_pack:
+        pytest.skip("native slotmap library unavailable")
+
+    def fill():
+        """Leave no free slot (long-lived keys, so reclaim must evict)."""
+        k = 0
+        while eng.cache_size() < eng.capacity:
+            room = min(64, eng.capacity - eng.cache_size())
+            eng.process([req(f"fill-{kind}-{fill.round}-{k + i}",
+                             duration=3_600_000) for i in range(room)],
+                        now=NOW)
+            k += room
+        fill.round += 1
+
+    fill.round = 0
+
+    def window(tag):
+        behavior, duration = Behavior(0), 60_000
+        if kind == "gregorian":
+            behavior = Behavior.DURATION_IS_GREGORIAN
+            duration = timeutil.GREGORIAN_HOURS
+        if kind == "full_table":
+            fill()
+        return [req(f"{kind}-{tag}-{i % 5}", hits=2, limit=9,
+                    duration=duration, behavior=behavior) for i in range(8)]
+
+    def answers(reqs):
+        before = eng.metric_native_pack_windows, eng.metric_h2d_windows
+        rs = eng.process(reqs, now=NOW)
+        assert eng.metric_h2d_windows == before[1] + 1
+        return ([(r.status, r.limit, r.remaining, r.reset_time, r.error)
+                 for r in rs], eng.metric_native_pack_windows - before[0])
+
+    if kind == "store":
+        monkeypatch.setattr(eng, "store", MockStore())
+    got, rose = answers(window("native"))
+    assert rose == (1 if kind == "plain" else 0)
+    if kind == "store":
+        # ... and once every key is known there is nothing to ask the
+        # Store before the tick: the pass takes the window.
+        assert answers(window("native"))[1] == 1
+        assert eng.store.called["Get()"] == 5
+    monkeypatch.setattr(eng, "_native_pack", False)
+    want, rose = answers(window("numpy"))
+    assert rose == 0
+    assert got == want and all(a[4] == "" for a in got)
+    assert [a[2] for a in got] == [7, 7, 7, 7, 7, 5, 5, 5]

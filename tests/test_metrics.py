@@ -286,6 +286,10 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
         assert state["ready"] is True
         assert state["occupancy"]
         assert "breakers" in state and "redelivery" in state
+        eng_tel = state["engine"]
+        assert 0 < eng_tel["h2d_windows"]
+        if d.instance.engine.describe()["native_pack"]:
+            assert eng_tel["native_pack_windows"] == eng_tel["h2d_windows"]
         assert traces["tracing_enabled"] is True
         assert traces["count"] > 0 and traces["spans"][0]["trace_id"]
         # Satellite: _StatsInterceptor feeds the RPC latency histogram.

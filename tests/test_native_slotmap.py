@@ -127,3 +127,34 @@ def test_fallback_without_a_toolchain_warns(native_copy, monkeypatch, caplog):
         assert native.library_path("libguber_slotmap.so") is None
     assert "pure-Python fallback" in caplog.text
     assert "libguber_slotmap.so" in caplog.text
+
+
+@pytest.mark.parametrize("toolchain", [True, False])
+def test_library_without_the_newest_symbol_is_stale(
+        native_copy, monkeypatch, caplog, toolchain):
+    """A library an older checkout built can be newer than its source and
+    still lack ``guber_slotmap_pack_window``: it is rebuilt, or refused
+    with the WARNING, never bound half-way."""
+    import logging
+    import os
+    import shutil
+
+    # A sound library that exports none of the slotmap's symbols, newer
+    # than slotmap.cc: make alone would leave it.
+    so = native_copy / "libguber_slotmap.so"
+    shutil.copy(native.library_path("libguber_wire.so"), so)
+    monkeypatch.setattr(native, "_paths", {})
+    monkeypatch.setattr(native, "_build_attempted", False)
+    assert os.path.getmtime(so) >= os.path.getmtime(native_copy / "slotmap.cc")
+    assert native._stale("libguber_slotmap.so")
+    if not toolchain:
+        monkeypatch.setenv("PATH", str(native_copy / "no-such-bin"))
+    with caplog.at_level(logging.WARNING, logger="gubernator.native"):
+        path = native.library_path("libguber_slotmap.so")
+    if toolchain:
+        assert path == str(so) and not native._stale("libguber_slotmap.so")
+        assert b"guber_slotmap_pack_window" in so.read_bytes()
+    else:
+        assert path is None
+        assert "guber_slotmap_pack_window" in caplog.text
+        assert "pure-Python fallback" in caplog.text

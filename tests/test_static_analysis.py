@@ -544,7 +544,8 @@ def test_repo_hot_path_markers_present():
         # column packers the call graph proves reachable from submit —
         # transitive G001 guards their bodies, so they carry the marker.
         "gubernator_tpu/ops/engine.py": [
-            "_build_cols", "_lease_matrix", "_promote_misses",
+            "_build_cols", "_build_cols_numpy", "_lease_matrix",
+            "_promote_misses",
             "submit_columns", "submit_cols", "submit", "lease_window",
             "pack_wide_rows", "pack_cols_req32", "join_i32_pair"],
         # The sharded serving path: resolve + the ragged flat dispatch
