@@ -1,11 +1,15 @@
-"""Scalar Python references for the zoo algorithms — the test oracle.
+"""Scalar Python references — the test oracle.
 
-Each function mirrors its vmapped counterpart line for line (same
-clamps, same precedence, same integer math) the way
-``tests/test_token_bucket.py`` / ``test_leaky_bucket.py`` pin the
-reference Go semantics for the legacy pair.  The parity fuzz drives the
-real engine and this module with identical request streams and demands
-bit-identical responses and exported state.
+The zoo functions mirror their vmapped counterparts line for line (same
+clamps, same precedence, same integer math).  ``token_bucket`` and
+``leaky_bucket`` are the plain reference of the legacy pair, written
+from upstream ``algorithms.go`` (``tokenBucket`` / ``tokenBucketNewItem``
+/ ``leakyBucket`` / ``leakyBucketNewItem``) in Python ``int`` and
+``float``: int64-exact while callers stay in range, and IEEE float64,
+every operation rounded, which is what the served tick's leaky path has
+to equal bit for bit (ops/b64.py).  Nothing of ``ops/`` is imported.
+The parity fuzz drives the real engine and this module with identical
+request streams and demands bit-identical responses and exported state.
 
 State is a plain dict of the logical BucketState fields (``None`` for
 an absent item); requests are dicts with ``hits``/``limit``/
@@ -209,7 +213,155 @@ def concurrency(s: Optional[dict], req: dict, now: int
     return new_state, resp
 
 
+def _live(s: Optional[dict], now: int) -> bool:
+    """The cache holds the item: present, in use, not expired (the
+    stored algorithm is the caller's to look at)."""
+    return (s is not None and bool(s.get("in_use", True))
+            and now <= s["expire_at"])
+
+
+def _legacy_resp(status: int, limit: int, remaining: int, reset_time: int
+                 ) -> RespDict:
+    return {
+        "status": int(status), "limit": limit, "remaining": remaining,
+        "reset_time": reset_time,
+        "over_limit": status == Status.OVER_LIMIT,
+    }
+
+
+def token_bucket(s: Optional[dict], req: dict, now: int
+                 ) -> Tuple[Optional[StateDict], RespDict]:
+    """Upstream ``tokenBucket`` (algorithms.go:37-252).  ``now`` is the
+    cache's clock (expiry); the arithmetic runs on the request's
+    ``created_at``, as upstream's does.  A RESET_REMAINING on a live item
+    removes it: the new state is ``None``.  DURATION_IS_GREGORIAN is not
+    implemented."""
+    behavior = req.get("behavior", 0)
+    if behavior & Behavior.DURATION_IS_GREGORIAN:
+        raise NotImplementedError("DURATION_IS_GREGORIAN")
+    hits, limit, duration = req["hits"], req["limit"], req["duration"]
+    t = req["created_at"]
+    if _live(s, now) and behavior & Behavior.RESET_REMAINING:
+        return None, _legacy_resp(Status.UNDER_LIMIT, limit, limit, 0)
+    if not _live(s, now) or s.get("algorithm", 0) != Algorithm.TOKEN_BUCKET:
+        # tokenBucketNewItem
+        expire = t + duration
+        over = hits > limit
+        remaining = limit if over else limit - hits
+        new = _base_state(req)
+        new.update(
+            algorithm=int(Algorithm.TOKEN_BUCKET), remaining=remaining,
+            created_at=t, updated_at=0, burst=0,
+            status=int(Status.UNDER_LIMIT), expire_at=expire)
+        return new, _legacy_resp(
+            Status.OVER_LIMIT if over else Status.UNDER_LIMIT,
+            limit, remaining, expire)
+
+    b = dict(s)
+    if b["limit"] != limit:
+        b["remaining"] = max(b["remaining"] + limit - b["limit"], 0)
+        b["limit"] = limit
+    # The answer is drawn up before a changed duration renews the item.
+    status, remaining, reset = b["status"], b["remaining"], b["expire_at"]
+    if b["duration"] != duration:
+        expire = b["created_at"] + duration
+        if expire <= t:
+            expire = t + duration
+            b["created_at"] = t
+            b["remaining"] = limit
+        b["expire_at"] = expire
+        b["duration"] = duration
+        reset = expire
+    if hits == 0:
+        return b, _legacy_resp(status, limit, remaining, reset)
+    if remaining == 0 and hits > 0:
+        b["status"] = int(Status.OVER_LIMIT)
+        return b, _legacy_resp(Status.OVER_LIMIT, limit, remaining, reset)
+    if b["remaining"] == hits:
+        b["remaining"] = 0
+        return b, _legacy_resp(status, limit, 0, reset)
+    if hits > b["remaining"]:
+        if behavior & Behavior.DRAIN_OVER_LIMIT:
+            b["remaining"] = 0
+            remaining = 0
+        return b, _legacy_resp(Status.OVER_LIMIT, limit, remaining, reset)
+    b["remaining"] -= hits
+    return b, _legacy_resp(status, limit, b["remaining"], reset)
+
+
+def leaky_bucket(s: Optional[dict], req: dict, now: int
+                 ) -> Tuple[StateDict, RespDict]:
+    """Upstream ``leakyBucket`` (algorithms.go:260-493).  ``remaining_f``
+    is a Python float: ``float(i)``, ``/``, ``+=``, ``-=`` and ``int()``
+    are Go's ``float64(i)``, the IEEE operations and ``int64(f)``, one
+    rounding each.  ``limit`` and ``duration`` are positive (upstream
+    would divide by zero as well).  DURATION_IS_GREGORIAN is not
+    implemented."""
+    behavior = req.get("behavior", 0)
+    if behavior & Behavior.DURATION_IS_GREGORIAN:
+        raise NotImplementedError("DURATION_IS_GREGORIAN")
+    hits, limit, duration = req["hits"], req["limit"], req["duration"]
+    burst = req.get("burst", 0) or limit
+    t = req["created_at"]
+    rate = float(duration) / float(limit)
+    irate = int(rate)
+
+    if not _live(s, now) or s.get("algorithm", 0) != Algorithm.LEAKY_BUCKET:
+        # leakyBucketNewItem
+        over = hits > burst
+        left = 0 if over else burst - hits
+        new = _base_state(req)
+        new.update(
+            algorithm=int(Algorithm.LEAKY_BUCKET), remaining=0,
+            remaining_f=float(left), created_at=t, updated_at=t,
+            burst=burst, status=int(Status.UNDER_LIMIT),
+            expire_at=t + duration)
+        return new, _legacy_resp(
+            Status.OVER_LIMIT if over else Status.UNDER_LIMIT,
+            limit, left, t + (limit - left) * irate)
+
+    b = dict(s)
+    if behavior & Behavior.RESET_REMAINING:
+        b["remaining_f"] = float(burst)
+    if b["burst"] != burst:
+        if burst > int(b["remaining_f"]):
+            b["remaining_f"] = float(burst)
+        b["burst"] = burst
+    b["limit"] = limit
+    b["duration"] = duration
+    if hits != 0:
+        b["expire_at"] = t + duration
+    elapsed = t - b["updated_at"]
+    leak = float(elapsed) / rate
+    if int(leak) > 0:
+        b["remaining_f"] += leak
+        b["updated_at"] = t
+    if int(b["remaining_f"]) > burst:
+        b["remaining_f"] = float(burst)
+    rem = int(b["remaining_f"])
+    reset = t + (limit - rem) * irate
+    if rem == 0 and hits > 0:
+        return b, _legacy_resp(Status.OVER_LIMIT, limit, rem, reset)
+    if rem == hits:
+        b["remaining_f"] = 0.0
+        return b, _legacy_resp(Status.UNDER_LIMIT, limit, 0,
+                               t + limit * irate)
+    if hits > rem:
+        if behavior & Behavior.DRAIN_OVER_LIMIT:
+            b["remaining_f"] = 0.0
+            rem = 0
+        return b, _legacy_resp(Status.OVER_LIMIT, limit, rem, reset)
+    if hits == 0:
+        return b, _legacy_resp(Status.UNDER_LIMIT, limit, rem, reset)
+    b["remaining_f"] -= float(hits)
+    rem = int(b["remaining_f"])
+    return b, _legacy_resp(Status.UNDER_LIMIT, limit, rem,
+                           t + (limit - rem) * irate)
+
+
 REFERENCE = {
+    Algorithm.TOKEN_BUCKET: token_bucket,
+    Algorithm.LEAKY_BUCKET: leaky_bucket,
     Algorithm.SLIDING_WINDOW: sliding_window,
     Algorithm.GCRA: gcra,
     Algorithm.CONCURRENCY: concurrency,
@@ -218,5 +370,5 @@ REFERENCE = {
 
 def transition(s: Optional[dict], req: dict, now: int
                ) -> Tuple[StateDict, RespDict]:
-    """Dispatch on ``req['algorithm']`` (zoo members only)."""
+    """Dispatch on ``req['algorithm']``."""
     return REFERENCE[Algorithm(int(req["algorithm"]))](s, req, now)

@@ -317,7 +317,8 @@ class NativeSlotMap:
         state (both ``capacity`` long).  ``upad_cap``: the widest head
         block the plan can need, ``engine.group_upad(b, n)``.
 
-        Returns ``(status, slots, known, inv, n_miss, plan)``.
+        Returns ``(status, slots, known, inv, n_miss, plan, n_leaky)``
+        (``n_leaky``: rows packed with algorithm LEAKY).
         PACK_NOT_TAKEN: a row is Gregorian, nothing was done.
         PACK_RESOLVED_ONLY: a key found no slot, or ``stop_on_miss`` and
         a key was new; ``slots`` / ``known`` are ``resolve_blob``'s and
@@ -345,7 +346,7 @@ class NativeSlotMap:
         # mhead[19][upad].  Fresh every window: they are uploaded
         # asynchronously, and jax may read them until the copy is done.
         scratch = np.empty(2 * b + (rows + 1) * upad_cap, np.int32)
-        info = np.zeros(3, np.int64)
+        info = np.zeros(4, np.int64)
         call = (
             self._lib.guber_slotmap_pack_window
             if n >= self.PACK_GIL_FREE_ROWS else self._lib.pack_window_gil_held
@@ -355,7 +356,7 @@ class NativeSlotMap:
             now, stop_on_miss, m32, b, slots, known, inv,
             last_access, tick, dirty, scratch, len(scratch), info,
         )
-        n_miss, u, upad = info.tolist()
+        n_miss, u, upad, n_leaky = info.tolist()
         plan = None
         if status == self.PACK_GROUPED:
             at = 2 * b + upad
@@ -363,7 +364,7 @@ class NativeSlotMap:
                 scratch[at:at + rows * upad].reshape(rows, upad),
                 scratch[2 * b:at], scratch[:b], scratch[b:2 * b], u,
             )
-        return status, slots, known, inv, n_miss, plan
+        return status, slots, known, inv, n_miss, plan, n_leaky
 
     def release_batch(self, slots: np.ndarray) -> None:
         """Release a batch of slots in one native call."""

@@ -259,7 +259,7 @@ void sort_lanes(std::vector<uint64_t>& keys, int64_t capacity) {
 //   row moves state (hits != 0, a new key, or RESET_REMAINING).
 // plan: plan_cap int32 of scratch, written only for kPackGrouped as
 //   uidx[b] rank[b] count[upad] mhead[19][upad]  (upad in info[2]).
-// info: n_miss, u, upad.
+// info: n_miss, u, upad, n_leaky (rows packed with algorithm LEAKY).
 int64_t guber_slotmap_pack_window(
     void* p, const char* blob, const int64_t* offsets, int64_t n,
     const int64_t* hits, const int64_t* limit, const int64_t* duration,
@@ -323,7 +323,12 @@ int64_t guber_slotmap_pack_window(
     }
   };
   put_narrow(kKnown, [&](int32_t i) { return out_known[i]; });
-  put_narrow(kAlgorithm, [&](int32_t i) { return algorithm[i]; });
+  int64_t n_leaky = 0;
+  put_narrow(kAlgorithm, [&](int32_t i) {
+    n_leaky += algorithm[i] == kLeaky;
+    return algorithm[i];
+  });
+  info[3] = n_leaky;
   put_narrow(kBehavior, [&](int32_t i) { return behavior[i]; });
   std::fill_n(m32 + kValid * b, n, 1);
   put_wide(kHits, [&](int32_t i) { return hits[i]; });

@@ -605,6 +605,25 @@ def group_upad(b: int, u: int = 0) -> int:
     return pad_pow2(max(u, 256, b // 4))
 
 
+def grouped_warm_shapes(widths: tuple, deep: bool) -> list:
+    """The (batch width, head width) shapes of the grouped program that
+    ``TickEngine._warmup`` compiles: every batch width at its floor, and
+    with ``deep`` (a serving chip) at every deeper head width
+    ``group_upad`` can give it too.  A shape first met under traffic is
+    traced and lowered on the dispatch thread, compile cache or not:
+    seconds in which nothing is answered, at no fixed time (a window of
+    one call that is mostly one hot key plans to another head width
+    than its neighbours', and can first come minutes in)."""
+    shapes = []
+    for w in widths:
+        upad = group_upad(w)
+        shapes.append((w, upad))
+        while deep and upad < w:
+            upad *= 2
+            shapes.append((w, upad))
+    return shapes
+
+
 def _param_rows_equal_prev(m: np.ndarray, nl: int) -> np.ndarray:
     """(nl,) bool: row i carries identical request parameters to row
     i-1 (the 17 REQ32 parameter rows both duplicate planners fold on —
@@ -2412,6 +2431,9 @@ class TickEngine:
         self.metric_hits = 0
         self.metric_misses = 0
         self.metric_over_limit = 0
+        # Rows dispatched with algorithm LEAKY: the share of decisions
+        # that take the float64 leaky path (ops/b64.py).
+        self.metric_leaky_rows = 0
         self.metric_unexpired_evictions = 0
         self.metric_layered_ticks = 0
         # Tiering telemetry: cold lookups that hit on the miss path,
@@ -2459,12 +2481,14 @@ class TickEngine:
     def describe(self) -> dict:
         from gubernator_tpu.ops.tick32 import _resolve_fused
 
-        return describe_engine(
+        d = describe_engine(
             self.device, 1, self.layout,
             self.layout == "row" and _resolve_fused(None),
             self.warmup_seconds,
             native_pack=self._native_pack,
         )
+        d["leaky_rows"] = self.metric_leaky_rows
+        return d
 
     def _warmup(self) -> None:
         """Compile the tick/install programs now (first compile is seconds;
@@ -2475,11 +2499,11 @@ class TickEngine:
         D2H of each buffer shape is paid here and not on the first live
         request, where a slow one would blow the 500ms peer batch_timeout
         and trigger forward retries that double-count hits."""
-        warm_sequential = jax.default_backend() == "tpu"
+        on_chip = jax.default_backend() == "tpu"
         for w in self._widths:
             m = np.zeros((REQ32_ROWS, w), np.int32)
             m[REQ32_INDEX["slot"]] = self.capacity
-            if warm_sequential:
+            if on_chip:
                 # The sequential chained-unit program only serves
                 # adversarial duplicate shapes; like the layered warmup
                 # below, eager-compiling it is a serving chip's live-
@@ -2497,13 +2521,13 @@ class TickEngine:
         # Warm the grouped (scatter-add) pipeline at each width's floor
         # head shape (group_upad — the shape every sub-quantum hot-key
         # window hits) so the first grouped batch doesn't pay the
-        # compile on a live deadline.  Deeper head widths stay lazy.
+        # compile on a live deadline; on a serving chip at the deeper
+        # head widths too (grouped_warm_shapes).
         # Gated to serving-scale engines: test-cluster engines (small
         # capacity, usually no duplicate traffic) skip the extra
         # compiles.
         if self.capacity >= (1 << 14):
-            for w in self._widths:
-                upad = group_upad(w)
+            for w, upad in grouped_warm_shapes(self._widths, on_chip):
                 mh = np.zeros((REQ32_ROWS, upad), np.int32)
                 mh[REQ32_INDEX["slot"]] = self.capacity
                 self.state, resp = self._tick32m(
@@ -2514,7 +2538,7 @@ class TickEngine:
                     jnp.int64(0),
                 )
                 np.asarray(resp)
-        if self.capacity >= (1 << 16) and jax.default_backend() == "tpu":
+        if self.capacity >= (1 << 16) and on_chip:
             # Warm the layered pipeline's most common shape (w0 at the
             # narrow width's floor, 2 layers — what a typical mixed-herd
             # serving batch plans to) so the first live one doesn't pay
@@ -2835,7 +2859,7 @@ class TickEngine:
         resolved = None
         if self._native_pack:
             sm = self.slots
-            status, slots, known, inv, n_miss, plan = sm.pack_window(
+            status, slots, known, inv, n_miss, plan, n_leaky = sm.pack_window(
                 cols, m, now,
                 self.store is not None or self.cold is not None,
                 self._last_access, self._tick_count, self._dirty,
@@ -2849,6 +2873,7 @@ class TickEngine:
                     self._maybe_trigger_reclaim()
                 self.metric_hits += n - n_miss
                 self.metric_misses += n_miss
+                self.metric_leaky_rows += n_leaky
                 self.metric_native_pack_windows += 1
                 return m, n, {}, inv, status != sm.PACK_UNIQUE, plan
             if status == sm.PACK_RESOLVED_ONLY:
@@ -2975,6 +3000,8 @@ class TickEngine:
         # reads on device.
         ix = slice(0, n) if sel is None else sel
         pack_cols_req32(m, cols, slots, known, now, ix)
+        self.metric_leaky_rows += int(np.count_nonzero(
+            m[R["algorithm"], ix] == int(Algorithm.LEAKY_BUCKET)))
         # Sort the batch by slot (stable: same-slot requests keep arrival
         # order, the duplicate-sequencing contract).  The tick's
         # sorted-input path then does all segment math with neighbor

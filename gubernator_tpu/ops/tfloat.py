@@ -1,21 +1,24 @@
-"""Triple-float32 arithmetic — f64-class precision in pure f32/i32 ops.
+"""The stored form of the leaky bucket's float64 ``remaining``: an exact
+three-way float32 split, and ~70-bit Dekker arithmetic on it.
 
 The leaky bucket's ``remaining`` is a float64 in the reference
-(store.go:29-35) and is stored on device as an exact three-way Dekker
-float32 split (ops/buckets.py STATE_DTYPES).  On TPU there is no native
-f64 — XLA's X64 rewriter emulates it (float32-pair class precision) and
-Mosaic cannot compile under ``jax_enable_x64`` at all.  This module does
-the drip arithmetic *directly on the stored (hi, mid, lo) triple*:
-three non-overlapping f32 parts carry up to ~72 mantissa bits, at or
-above both IEEE f64 (53) and XLA's own TPU emulation, in ops Mosaic can
-compile (f32 add/sub/mul/div/floor + i32 logic).
+(store.go:29-35) and is stored on device as a (hi, mid, lo) float32
+triple (ops/buckets.py STATE_DTYPES), which holds any float64 of the
+envelope exactly.  On TPU there is no native f64: XLA's X64 rewriter
+emulates it with a float32 pair (~49 bits) and Mosaic cannot compile
+under ``jax_enable_x64`` at all.
 
-All functions are shape-polymorphic and elementwise.  Error-free
-transforms (two_sum / two_prod via Dekker splitting — no FMA required)
-keep results exact when they are representable, which covers the golden
-suites' integral rates and drips; accumulated drip fractions carry
-~70-bit precision, the same equivalence class the previous x64 path
-provided on TPU silicon.
+What is guaranteed, and where: the served transition's leaky arithmetic
+is **IEEE binary64, every operation rounded to nearest even**, computed
+in :mod:`gubernator_tpu.ops.b64` on the float64's bit pattern; this
+module's triple is only how the value is *stored* (``b64.from_triple`` /
+``b64.to_triple`` convert exactly at that boundary).  The triple
+arithmetic below (add / mul_f / div / floor, error-free transforms via
+Dekker splitting, ~70 bits, never rounded to 53) no longer computes any
+answer: it is the quotient *estimate* of ``i64pair.div_floor_pos``,
+which corrects it with an exact integer remainder.
+
+All functions are shape-polymorphic and elementwise.
 
 Domain: finite values, |x| < 2^63 for integer interop (the rate
 limiter's envelope — the reference itself stores token counts in f64,
@@ -192,44 +195,11 @@ def floor_to_pair(t: T3) -> p64.I64:
     return p64.select(too_low, p64.add(cand, one), cand)
 
 
-def trunc_to_pair(t: T3) -> p64.I64:
-    """trunc(t) toward zero as an i64 pair — Go's ``int64(float64)``
-    conversion (algorithms.go:377 ``int64(rate)``).  Equal to floor for
-    t >= 0; one above floor for negative non-integers (a negative leaky
-    rate from a negative duration is the one engine input where the two
-    differ)."""
-    fl = floor_to_pair(t)
-    neg_frac = ~ge_zero(t) & gt_zero(sub(t, from_pair(fl)))
-    return p64.select(neg_frac, p64.add(fl, p64.const(1, t.hi)), fl)
-
-
 def ge_zero(t: T3):
     """t >= 0 for a renormalized triple (sign of leading nonzero part)."""
     return (t.hi > 0) | (
         (t.hi == 0) & ((t.mid > 0) | ((t.mid == 0) & (t.lo >= 0)))
     )
-
-
-def gt_zero(t: T3):
-    return (t.hi > 0) | (
-        (t.hi == 0) & ((t.mid > 0) | ((t.mid == 0) & (t.lo > 0)))
-    )
-
-
-def ge(a: T3, b: T3):
-    return ge_zero(sub(a, b))
-
-
-def gt(a: T3, b: T3):
-    return gt_zero(sub(a, b))
-
-
-def ge_pair(t: T3, v: p64.I64):
-    return ge(t, from_pair(v))
-
-
-def gt_pair(t: T3, v: p64.I64):
-    return gt(t, from_pair(v))
 
 
 def to_np(t: T3):

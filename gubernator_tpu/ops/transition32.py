@@ -6,8 +6,12 @@ Semantically this is :func:`gubernator_tpu.ops.buckets.bucket_transition`
 ``leakyBucket()``, algorithms.go:37-493, every branch and quirk in the
 same precedence) restated over the *storage* representation — i64 fields
 as (lo, hi) int32 pairs (:mod:`gubernator_tpu.ops.i64pair`), the leaky
-``remaining`` float64 as its Dekker triple-f32 split
-(:mod:`gubernator_tpu.ops.tfloat`).  Running on the parts directly:
+``remaining`` float64 stored as a float32 triple
+(:mod:`gubernator_tpu.ops.tfloat`) and computed as IEEE binary64 on its
+bit pattern (:mod:`gubernator_tpu.ops.b64`): every floating step of
+upstream's ``leakyBucket`` rounds here as float64 does, so the answers
+are its ``int64()`` truncations, never one off.  Running on the parts
+directly:
 
 * removes ``jax_enable_x64`` from the tick entirely (XLA's generic
   64-bit emulation and the bitcast-heavy row<->logical conversion were
@@ -29,6 +33,7 @@ from jax import lax
 
 from gubernator_tpu.algos import ZOO_MIN
 from gubernator_tpu.algos import table as zoo_table
+from gubernator_tpu.ops import b64
 from gubernator_tpu.ops import i64pair as p64
 from gubernator_tpu.ops import tfloat as tf
 from gubernator_tpu.ops.i64pair import I64
@@ -94,6 +99,7 @@ def transition32(now: I64, s: PState, r: PReq) -> tuple[PState, PResp]:
     zero = p64.const(0, r.slot)
     one = p64.const(1, r.slot)
     zero_t = tf.zeros_like(r.slot)
+    zero_b = b64.zeros_like(r.slot)
 
     reset_b = (r.behavior & jnp.int32(Behavior.RESET_REMAINING)) != 0
     drain_b = (r.behavior & jnp.int32(Behavior.DRAIN_OVER_LIMIT)) != 0
@@ -106,7 +112,7 @@ def transition32(now: I64, s: PState, r: PReq) -> tuple[PState, PResp]:
     h = r.hits
     h_query = p64.is_zero(h)
     h_pos = p64.gt(h, zero)
-    safe_limit_t = tf.from_pair(p64.select(p64.is_zero(r.limit), one, r.limit))
+    safe_limit = p64.select(p64.is_zero(r.limit), one, r.limit)
 
     # ------------------------------------------------------------------
     # TOKEN BUCKET
@@ -170,60 +176,61 @@ def transition32(now: I64, s: PState, r: PReq) -> tuple[PState, PResp]:
     # ------------------------------------------------------------------
     burst = p64.select(p64.is_zero(r.burst), r.limit, r.burst)
     leak_exist = exists & algo_match
+    burst_b = b64.from_pair(burst)
 
-    b_rem0 = tf.select(reset_b, tf.from_pair(burst), s.remaining_f)
+    b_rem0 = b64.select(reset_b, burst_b, b64.from_triple(s.remaining_f))
     burst_changed = p64.ne(s.burst, burst)
-    b_rem1 = tf.select(
-        burst_changed & p64.gt(burst, tf.floor_to_pair(b_rem0)),
-        tf.from_pair(burst),
+    b_rem1 = b64.select(
+        burst_changed & p64.gt(burst, b64.trunc_to_pair(b_rem0)),
+        burst_b,
         b_rem0,
     )
-    rate = tf.div(
-        tf.from_pair(p64.select(greg_b, r.greg_dur, r.duration)),
-        safe_limit_t,
+    # One division serves both branches: an existing bucket's rate takes
+    # the Gregorian interval, a new bucket's the raw duration (quirk).
+    rate = b64.div(
+        b64.from_pair(
+            p64.select(leak_exist & greg_b, r.greg_dur, r.duration)),
+        b64.from_pair(safe_limit),
     )
     duration_eff = p64.select(greg_b, p64.sub(r.greg_exp, now), r.duration)
     elapsed = p64.sub(r.created_at, s.updated_at)
-    rate_zero = (rate.hi == 0) & (rate.mid == 0) & (rate.lo == 0)
-    one_t = tf.from_f32(jnp.ones(shape, F32))
-    leak = tf.div(tf.from_pair(elapsed), tf.select(rate_zero, one_t, rate))
-    # int64(leak) > 0  <=>  leak >= 1 (negatives truncate toward zero)
-    leaked = tf.ge(leak, one_t)
-    b_rem2 = tf.select(leaked, tf.add(b_rem1, leak), b_rem1)
+    leak = b64.div(
+        b64.from_pair(elapsed),
+        b64.select(b64.is_zero(rate), b64.const(1.0, r.slot), rate))
+    leaked = p64.gt(b64.trunc_to_pair(leak), zero)
+    b_rem2 = b64.select(leaked, b64.add(b_rem1, leak), b_rem1)
     b_upd = p64.select(leaked, r.created_at, s.updated_at)
-    # int64(b_rem2) > burst  <=>  b_rem2 >= burst + 1 (b_rem2, burst >= 0)
-    b_rem3 = tf.select(
-        tf.ge_pair(b_rem2, p64.add(burst, one)), tf.from_pair(burst), b_rem2)
+    b_rem3 = b64.select(
+        p64.gt(b64.trunc_to_pair(b_rem2), burst), burst_b, b_rem2)
 
-    rem_i = tf.floor_to_pair(b_rem3)
-    # Go converts the float rate with int64(rate) — trunc toward zero,
-    # which differs from floor when a negative duration makes the rate
-    # negative (algorithms.go:336,377).
-    rate_i = tf.trunc_to_pair(rate)
+    rem_i = b64.trunc_to_pair(b_rem3)
+    # Go converts the float rate with int64(rate): toward zero, also for
+    # the negative rate of a negative duration (algorithms.go:336,377).
+    rate_i = b64.trunc_to_pair(rate)
     l_at_zero = p64.is_zero(rem_i) & h_pos
     l_exact = ~l_at_zero & p64.eq(rem_i, h)
     l_over = ~l_at_zero & ~l_exact & p64.gt(h, rem_i)
     l_query = ~l_at_zero & ~l_exact & ~l_over & h_query
     l_dec = ~l_at_zero & ~l_exact & ~l_over & ~l_query
 
-    le_remf = tf.select(
+    b_left = b64.sub(b_rem3, b64.from_pair(h))
+    le_remf = b64.select(
         l_exact,
-        zero_t,
-        tf.select(
+        zero_b,
+        b64.select(
             l_over,
-            tf.select(drain_b, zero_t, b_rem3),
-            tf.select(l_dec, tf.sub(b_rem3, tf.from_pair(h)), b_rem3),
+            b64.select(drain_b, zero_b, b_rem3),
+            b64.select(l_dec, b_left, b_rem3),
         ),
     )
     le_resp_status = jnp.where(l_at_zero | l_over, OVER, UNDER)
-    # trunc(b_rem3 - h) == floor(b_rem3) - h: h integral, result >= 0
     le_resp_rem = p64.select(
         l_exact,
         zero,
         p64.select(
             l_over,
             p64.select(drain_b, zero, rem_i),
-            p64.select(l_dec, p64.sub(rem_i, h), rem_i),
+            p64.select(l_dec, b64.trunc_to_pair(b_left), rem_i),
         ),
     )
     le_reset_rem = p64.select(l_over, rem_i, le_resp_rem)
@@ -232,17 +239,16 @@ def transition32(now: I64, s: PState, r: PReq) -> tuple[PState, PResp]:
     le_expire = p64.select(
         ~h_query, p64.add(r.created_at, duration_eff), s.expire_at)
 
-    ln_rate_i = tf.trunc_to_pair(
-        tf.div(tf.from_pair(r.duration), safe_limit_t))
     ln_duration = p64.select(greg_b, p64.sub(r.greg_exp, now), r.duration)
     ln_over = p64.gt(h, burst)
-    ln_remf = tf.select(
-        ln_over, zero_t, tf.from_pair(p64.sub(burst, h)))
+    ln_remf = b64.select(
+        ln_over, zero_b, b64.from_pair(p64.sub(burst, h)))
     ln_resp_rem = p64.select(ln_over, zero, p64.sub(burst, h))
     ln_resp_reset = p64.add(
-        r.created_at, p64.mul(p64.sub(r.limit, ln_resp_rem), ln_rate_i))
+        r.created_at, p64.mul(p64.sub(r.limit, ln_resp_rem), rate_i))
     ln_resp_status = jnp.where(ln_over, OVER, UNDER)
     ln_expire = p64.add(r.created_at, ln_duration)
+    leaky_remf = b64.to_triple(b64.select(leak_exist, le_remf, ln_remf))
 
     # ------------------------------------------------------------------
     # ALGORITHM ZOO (gubernator_tpu/algos): the same policy table the
@@ -272,11 +278,6 @@ def transition32(now: I64, s: PState, r: PReq) -> tuple[PState, PResp]:
         lk = p64.select(leak_exist, le, ln)
         return p64.select(is_token, tok, lk)
 
-    def selt(tr, te, tn, le, ln):
-        tok = tf.select(tok_reset, tr, tf.select(tok_exist, te, tn))
-        lk = tf.select(leak_exist, le, ln)
-        return tf.select(is_token, tok, lk)
-
     # 0/1 int32 lanes, not bool: Mosaic cannot lower selects between
     # bool vectors (i8->i1 truncation); the != 0 at the end emits a
     # plain compare instead.
@@ -296,8 +297,8 @@ def transition32(now: I64, s: PState, r: PReq) -> tuple[PState, PResp]:
             zs.remaining,
             sel64(zero, te_rem, tn_rem, s.remaining, s.remaining)),
         remaining_f=tf.select(
-            is_zoo, zero_t,
-            selt(zero_t, s.remaining_f, s.remaining_f, le_remf, ln_remf)),
+            is_zoo | (is_token & tok_reset), zero_t,
+            tf.select(is_token, s.remaining_f, leaky_remf)),
         duration=z64(
             r.duration,
             sel64(zero, r.duration, r.duration, r.duration, ln_duration)),
@@ -557,8 +558,8 @@ def merged_fold32(now: I64, new_s: PState, r: PReq, count: jnp.ndarray
 
     is_tok = r.algorithm == jnp.int32(Algorithm.TOKEN_BUCKET)
     h = p64.select(p64.gt(r.hits, zero), r.hits, one)  # div-safe
-    f0_floor = tf.floor_to_pair(new_s.remaining_f)
-    base = p64.select(is_tok, new_s.remaining, f0_floor)
+    f0 = b64.from_triple(new_s.remaining_f)
+    base = p64.select(is_tok, new_s.remaining, b64.trunc_to_pair(f0))
     base_pos = p64.select(p64.is_neg(base), zero, base)  # div domain
     q = p64.div_floor_pos(base_pos, h)
     li = p64.from_i32(count - 1)
@@ -591,10 +592,15 @@ def merged_fold32(now: I64, new_s: PState, r: PReq, count: jnp.ndarray
         | (p64.gt(base, zero) & drain & p64.gt(li, q))
     )
     li_capped = p64.min_(li, q)
+    # ``li`` float64 subtractions of ``h``, one after another, are each
+    # exact while remaining_f < 2^53 (h is whole and the difference has
+    # no more bits than remaining_f), so they equal this one.  Beyond
+    # 2^53 upstream's own count is no longer exact.
     remf_last = tf.select(
         zero_f,
         zero_t,
-        tf.sub(new_s.remaining_f, tf.from_pair(p64.mul(li_capped, h))),
+        b64.to_triple(
+            b64.sub(f0, b64.from_pair(p64.mul(li_capped, h)))),
     )
 
     safe_limit = p64.select(p64.is_zero(r.limit), one, r.limit)

@@ -142,6 +142,7 @@ class TickLoop:
         self._synced_ssd_demotions = 0
         self._synced_ssd_compactions = 0
         self._synced_shed = 0
+        self._synced_leaky_rows = 0
         self._synced_routed = 0
         self._synced_routed_overflows = 0
         self._cond = sanitize.condition("TickLoop._cond")
@@ -671,6 +672,10 @@ class TickLoop:
         if shed > self._synced_shed:
             m.shed_requests.inc(shed - self._synced_shed)
             self._synced_shed = shed
+        leaky = getattr(self.engine, "metric_leaky_rows", 0)
+        if leaky > self._synced_leaky_rows:
+            m.leaky_rows.inc(leaky - self._synced_leaky_rows)
+            self._synced_leaky_rows = leaky
         if cold is not None:
             demos = cold.metric_demotions
             if demos > self._synced_demotions:

@@ -820,6 +820,10 @@ class Daemon:
             # over h2d_windows: the share of windows the native host
             # pack answered (TickEngine._build_cols)
             engine_tel["native_pack_windows"] = eng.metric_native_pack_windows
+        if hasattr(eng, "metric_leaky_rows"):
+            # over cache hits + misses: the share of rows on the
+            # float64 leaky path
+            engine_tel["leaky_rows"] = eng.metric_leaky_rows
         staging = getattr(eng, "_staging", None)
         if staging is not None and hasattr(staging, "telemetry"):
             engine_tel["staging_ring"] = staging.telemetry()

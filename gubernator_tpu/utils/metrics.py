@@ -470,6 +470,12 @@ class Metrics:
             "(the rest of their batch was still served).",
             registry=reg,
         )
+        self.leaky_rows = Counter(
+            "gubernator_tpu_leaky_rows",
+            "Rows dispatched to the device with algorithm LEAKY_BUCKET: "
+            "the decisions that take the float64 leaky path.",
+            registry=reg,
+        )
 
         # Fault-tolerant peer path (docs/resilience.md): per-peer breaker
         # state, redelivery accounting for GLOBAL hits/broadcasts that

@@ -82,6 +82,13 @@ async def test_service_concurrent_clients_exact_accounting():
     d = await spawn_daemon(conf)
     limit, n_clients, per_client = 200, 64, 8
     try:
+        # The grouped program's first trace-and-lower, with no deadline
+        # and on a key of its own: the race below meets no compile.
+        warm = DaemonClient(d.advertise_address)
+        await warm.get_rate_limits(
+            [_req("svc-warm", limit=limit)] * 2, timeout=None)
+        await warm.close()
+
         async def one_client():
             c = DaemonClient(d.advertise_address)
             under = 0

@@ -270,7 +270,7 @@ def test_native_pack_window_equals_numpy_chain(kind, n):
     nat, m_nat, seen_nat, dirty_nat = fresh()
     if taken:   # the pass cleans the slab it packs, whatever it held
         m_nat[:] = rng.integers(-9, 9, m_nat.shape)
-    status, slots_n, known_n, inv_n, n_miss, plan_n = nat.pack_window(
+    status, slots_n, known_n, inv_n, n_miss, plan_n, n_leaky = nat.pack_window(
         cols, m_nat, NOW, kind == "stop_on_miss", seen_nat, tick, dirty_nat,
         E.group_upad(b, n))
 
@@ -298,6 +298,7 @@ def test_native_pack_window_equals_numpy_chain(kind, n):
         assert n_miss == int((known == 0).sum())
     if taken:
         np.testing.assert_array_equal(inv_n, inv_ref)
+        assert n_leaky == int((cols.algorithm == 1).sum())
         assert (status != nat.PACK_UNIQUE) == dups_ref
         assert (plan_n is None) == (plan_ref is None)
         if plan_ref is not None:
@@ -363,6 +364,7 @@ def test_native_pack_engages_by_what_the_batch_shows(eng, kind, monkeypatch):
 
     if kind == "store":
         monkeypatch.setattr(eng, "store", MockStore())
+    leaky_before = eng.metric_leaky_rows
     got, rose = answers(window("native"))
     assert rose == (1 if kind == "plain" else 0)
     if kind == "store":
@@ -375,3 +377,43 @@ def test_native_pack_engages_by_what_the_batch_shows(eng, kind, monkeypatch):
     assert rose == 0
     assert got == want and all(a[4] == "" for a in got)
     assert [a[2] for a in got] == [7, 7, 7, 7, 7, 5, 5, 5]
+    assert eng.metric_leaky_rows == leaky_before      # token buckets all
+
+
+@pytest.mark.parametrize("pack", ["native", "numpy"])
+def test_leaky_rows_are_counted_by_either_pack(eng, pack, monkeypatch):
+    """``metric_leaky_rows`` rises by the window's rows with algorithm
+    LEAKY, in the native pass (where the row's algorithm is in hand) and
+    in the numpy chain alike."""
+    if pack == "native" and not eng._native_pack:
+        pytest.skip("native slotmap library unavailable")
+    if pack == "numpy":
+        monkeypatch.setattr(eng, "_native_pack", False)
+    reqs = [req(f"lk-{pack}-{i % 6}", hits=1, limit=50, duration=60_000,
+                algorithm=int(i % 3 == 0)) for i in range(24)]
+    before = eng.metric_leaky_rows, eng.metric_native_pack_windows
+    rs = eng.process(reqs, now=NOW)
+    assert all(r.error == "" for r in rs)
+    assert eng.metric_leaky_rows - before[0] == 8
+    assert eng.metric_native_pack_windows - before[1] == (pack == "native")
+
+
+@pytest.mark.parametrize("widths,deep,want", [
+    ((1024, 4096), True, [(1024, 256), (1024, 512), (1024, 1024),
+                          (4096, 1024), (4096, 2048), (4096, 4096)]),
+    ((1024, 4096), False, [(1024, 256), (4096, 1024)]),
+    ((512,), True, [(512, 256), (512, 512)]),
+    ((256,), True, [(256, 256)]),
+])
+def test_grouped_warm_shapes(widths, deep, want):
+    """``_warmup`` compiles the grouped program at every batch width's
+    floor and, on a serving chip, at every head width a window can plan
+    to: each is a width ``group_upad`` gives, and none is left out."""
+    got = E.grouped_warm_shapes(widths, deep)
+    assert got == want
+    for b in widths:
+        plans = {E.group_upad(b, u) for u in range(1, b + 1)}
+        warmed = {upad for w, upad in got if w == b}
+        assert warmed <= plans and E.group_upad(b) in warmed
+        if deep:
+            assert warmed == plans

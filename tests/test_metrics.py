@@ -262,8 +262,10 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
     try:
         assert flightrec.enabled()
         client = DaemonClient(d.advertise_address)
+        # k1 is a leaky bucket: 3 of the 12 rows take the leaky path
         reqs = [RateLimitRequest(name="dbg", unique_key=f"k{i}", hits=1,
-                                 limit=10, duration=60000) for i in range(4)]
+                                 limit=10, duration=60000, algorithm=int(i == 1))
+                for i in range(4)]
         for _ in range(3):
             await client.get_rate_limits(reqs)
         await client.close()
@@ -290,6 +292,11 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
         assert 0 < eng_tel["h2d_windows"]
         if d.instance.engine.describe()["native_pack"]:
             assert eng_tel["native_pack_windows"] == eng_tel["h2d_windows"]
+        # metric_leaky_rows: on the engine, in /debug/state, in Prometheus
+        assert d.instance.engine.metric_leaky_rows == 3
+        assert eng_tel["leaky_rows"] == 3
+        assert d.instance.engine.describe()["leaky_rows"] == 3
+        assert d.metrics.sample("gubernator_tpu_leaky_rows_total") == 3
         assert traces["tracing_enabled"] is True
         assert traces["count"] > 0 and traces["spans"][0]["trace_id"]
         # Satellite: _StatsInterceptor feeds the RPC latency histogram.

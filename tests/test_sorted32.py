@@ -110,10 +110,10 @@ def test_sorted32_chains_across_ticks():
 def test_trunc_to_pair_negative_rate():
     """Negative leaky rates (negative durations) convert Go-style —
     trunc toward zero, not floor (algorithms.go int64(rate))."""
+    from gubernator_tpu.ops import b64
     from gubernator_tpu.ops import i64pair as p64
-    from gubernator_tpu.ops import tfloat as tf
 
     for v in (-0.357, -5.0, -5.9, 0.9, 5.9, -(2.0 ** 40) - 0.5):
-        t = tf.from_f32(jnp.full((4,), np.float32(v)))
-        got = p64.to_np(tf.trunc_to_pair(t))[0]
-        assert got == int(v), (v, got)
+        t = b64.from_np(np.full(4, np.float32(v), np.float64))
+        got = p64.to_np(b64.trunc_to_pair(t))[0]
+        assert got == int(np.float32(v)), (v, got)

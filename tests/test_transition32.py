@@ -1,13 +1,13 @@
 """Differential test: transition32 (parts-native) vs bucket_transition
 (the jax_enable_x64 oracle) across every branch of the decision tree.
 
-Integer outputs (status, remaining, reset_time, over_limit, and every
-integer state field) must match EXACTLY.  The leaky float remaining
-matches exactly when rates are exactly representable (all golden-suite
-shapes; the generator draws (duration, limit) pairs with exact
-quotients) — at non-representable rates f64 and the ~70-bit triple can
-legitimately round a drip boundary differently (double rounding), which
-is checked separately as a consistency property, not exact equality.
+Every output must match EXACTLY: status, remaining, reset_time,
+over_limit, every integer state field, and the leaky float remaining bit
+for bit (the parts path computes IEEE binary64 on the bit pattern,
+ops/b64.py; on the CPU the x64 oracle is true float64).  Below the
+differential: the served tick and the grouped fold against the plain
+reference (algos/reference.py ``token_bucket`` / ``leaky_bucket``,
+upstream algorithms.go in Python int and float).
 """
 
 import numpy as np
@@ -183,21 +183,16 @@ def test_differential_vs_x64_oracle(seed):
         np.testing.assert_array_equal(
             np.asarray(getattr(got_state, f)),
             np.asarray(getattr(want_state, f)), err_msg=f)
-    # float remaining: the triple carries MORE precision than f64, so at
-    # inexact leak quotients (elapsed/rate with a repeating expansion)
-    # the stored value can sit a few f64-ulps from the CPU-f64 oracle —
-    # the same drift class the previous on-TPU x64 emulation (a ~49-bit
-    # float32 pair) already had vs CPU f64.  Integer-visible outputs
-    # above are exact.
-    np.testing.assert_allclose(
+    # float remaining: IEEE binary64, every operation rounded as the
+    # CPU's float64 rounds it
+    np.testing.assert_array_equal(
         tf.to_np(got_state.remaining_f),
-        np.asarray(want_state.remaining_f), rtol=1e-14, atol=1e-12)
+        np.asarray(want_state.remaining_f))
 
 
 def test_rough_rate_consistency():
     """Non-representable rates (duration/limit with repeating binary
-    expansion): exact f64 equality is not guaranteed at drip boundaries,
-    but the parts path must keep its own invariants: response remaining
+    expansion): the parts path keeps its invariants: response remaining
     == floor(stored remaining_f) for under-limit leaky decisions, and
     status consistent with remaining."""
     rng = np.random.default_rng(99)
@@ -275,3 +270,170 @@ def test_matrix_adapters_roundtrip():
         tf.to_np(ps.remaining_f), state["remaining_f"])
     back = jax.jit(pstate_to_matrix)(ps)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(mat))
+
+
+# ----------------------------------------------------------------------
+# Against the plain reference (algos/reference.py)
+# ----------------------------------------------------------------------
+def _join(lo, hi):
+    return (hi.astype(np.int64) << 32) | (lo.astype(np.int64) & 0xFFFFFFFF)
+
+
+@pytest.mark.parametrize("seed", [2])
+def test_leaky_probe_through_the_served_tick(seed):
+    """4,000 leaky keys, limit x duration from the population's sets, 240
+    touches about a second apart (each key on its own clock), hits 1,
+    through ``tick32.jitted_tick32`` (what ``TickEngine`` dispatches for
+    a unique window, on the CPU's column layout) against
+    ``reference.leaky_bucket``.  Where the float64 sum ``remaining +
+    leak`` rounds to a whole number the ~70-bit triple read one off for
+    a few touches: 16 of these 960,000 answers (keys 984, 2037, 3165)
+    before the leaky path became IEEE binary64; 0 now."""
+    from gubernator_tpu.algos import reference
+    from gubernator_tpu.ops import engine as E
+    from gubernator_tpu.ops.tick32 import jitted_tick32
+
+    n, touches, width = 4000, 240, 4096
+    rng = np.random.default_rng(seed)
+    limit = rng.choice([5, 20, 100, 1000, 2**33], n)
+    duration = rng.choice([3_600_000, 7_200_000, 86_400_000], n)
+    tick = jitted_tick32(width, "columns")
+    zeros, _, _ = E._layout_ops("columns")
+    state = jax.tree.map(jnp.asarray, zeros(width))
+    R, lanes = E.REQ32_INDEX, slice(0, n)
+    now = NOW
+    created = np.full(n, now)
+    ref = [None] * n
+    bad = []
+    for t in range(touches):
+        now += 1000
+        created = created + 1000 + rng.integers(-40, 41, n)
+        m = np.zeros((E.REQ32_ROWS, width), np.int32)
+        m[R["slot"]] = width
+        m[R["slot"], lanes] = np.arange(n)
+        m[R["known"], lanes] = t > 0
+        m[R["algorithm"], lanes] = int(Algorithm.LEAKY_BUCKET)
+        m[R["valid"], lanes] = 1
+        E.pack_wide_rows(m, "hits", np.ones(n, np.int64), lanes)
+        E.pack_wide_rows(m, "limit", limit, lanes)
+        E.pack_wide_rows(m, "duration", duration, lanes)
+        E.pack_wide_rows(m, "created_at", created, lanes)
+        state, resp = tick(state, jnp.asarray(m), jnp.int64(now))
+        resp = np.asarray(resp)
+        got = zip(resp[0, lanes].tolist(),
+                  _join(resp[2, lanes], resp[3, lanes]).tolist(),
+                  _join(resp[4, lanes], resp[5, lanes]).tolist())
+        for i, g in enumerate(got):
+            ref[i], r = reference.leaky_bucket(ref[i], {
+                "hits": 1, "limit": int(limit[i]),
+                "duration": int(duration[i]), "algorithm": 1, "burst": 0,
+                "created_at": int(created[i])}, now)
+            if g != (r["status"], r["remaining"], r["reset_time"]):
+                bad.append((t, i, g, r))
+    assert not bad, (len(bad), bad[:4])
+
+
+@pytest.mark.parametrize("seed", [7, 8])
+def test_fold_equals_sequential_float64_subtractions(seed):
+    """A uniform group of ``count`` duplicates on a leaky row with a
+    fractional ``remaining_f``: the closed-form fold stores what
+    ``count - 1`` follower requests, one after another through
+    ``reference.leaky_bucket``, leave: the float64, bit for bit."""
+    from gubernator_tpu.algos import reference
+    from gubernator_tpu.ops.transition32 import merged_fold32
+
+    rng = np.random.default_rng(seed)
+    n = 1024
+    limit = rng.choice([20, 100, 1000, 2**33], n)
+    duration = np.full(n, 3_600_000)
+    hits = rng.choice([1, 2, 3, 7], n)
+    count = rng.integers(1, 65, n)
+    drain = rng.random(n) < 0.3
+    # the row as the head's transition left it
+    whole = np.where(rng.random(n) < 0.5, rng.integers(0, 40, n),
+                     rng.integers(0, limit + 1))
+    rem_f = np.minimum(whole + rng.random(n) * (rng.random(n) < 0.8),
+                       limit.astype(np.float64))
+    state, req = gen_batch(rng, n)
+    state.update(
+        algorithm=np.ones(n, np.int64), limit=limit, duration=duration,
+        remaining_f=rem_f, burst=limit, created_at=np.full(n, NOW),
+        updated_at=np.full(n, NOW), expire_at=NOW + duration,
+        in_use=np.ones(n, bool))
+    req.update(
+        hits=hits, limit=limit, duration=duration,
+        algorithm=np.ones(n, np.int64), burst=np.zeros(n, np.int64),
+        behavior=np.where(drain, int(Behavior.DRAIN_OVER_LIMIT), 0),
+        created_at=np.full(n, NOW), known=np.ones(n, bool),
+        greg_exp=np.zeros(n, np.int64), greg_dur=np.zeros(n, np.int64))
+    ps, pr = to_parts(state, req)
+    folded, _ = jax.jit(merged_fold32)(
+        p64.from_np(np.int64(NOW)), ps, pr, jnp.asarray(count, jnp.int32))
+    got = tf.to_np(folded.remaining_f)
+    for i in range(n):
+        s = {k: (v[i].item() if hasattr(v[i], "item") else v[i])
+             for k, v in state.items()}
+        r = {k: int(req[k][i]) for k in (
+            "hits", "limit", "duration", "algorithm", "behavior", "burst",
+            "created_at")}
+        for _ in range(int(count[i]) - 1):
+            s, _resp = reference.leaky_bucket(s, r, NOW)
+        assert got[i].tobytes() == np.float64(s["remaining_f"]).tobytes(), (
+            i, rem_f[i], hits[i], count[i], got[i], s["remaining_f"])
+
+
+@pytest.fixture(scope="module")
+def served_engine():
+    # a geometry other suite files compile too (ROADMAP's standing
+    # constraint)
+    from gubernator_tpu.ops import engine as E
+
+    return E.TickEngine(capacity=512, max_batch=64)
+
+
+@pytest.mark.parametrize("seed", [21, 22, 23])
+def test_mixed_histories_against_the_plain_reference(served_engine, seed):
+    """Seeded histories of a mixed population (half leaky, bursts 0 / 10
+    / 50, duplicates in a window, RESET_REMAINING and DRAIN_OVER_LIMIT,
+    expiries) through the served tick (``TickEngine.process``: the
+    unique, grouped and sequential programs, as the window's shape
+    decides) against ``reference.token_bucket`` / ``leaky_bucket``:
+    every answer equal."""
+    from gubernator_tpu.algos import reference
+    from gubernator_tpu.types import RateLimitRequest
+
+    rng = np.random.default_rng(seed)
+    eng = served_engine
+    keys = 40
+    algo = (np.arange(keys) % 2).tolist()
+    limit = rng.choice([5, 20, 100, 1000, 2**33], keys).tolist()
+    duration = rng.choice([3_000, 3_600_000, 7_200_000], keys).tolist()
+    burst = rng.choice([0, 10, 50], keys).tolist()
+    ref = {}
+    now = NOW + seed * 100_000_000
+    for _ in range(60):
+        now += int(rng.integers(1, 2_500))
+        ids = rng.choice(keys, int(rng.integers(1, 60)),
+                         p=(w := 1 / np.arange(1, keys + 1)) / w.sum())
+        reqs = []
+        for k in ids.tolist():
+            pick = rng.random()
+            behavior = (Behavior.RESET_REMAINING if pick < 0.03 else
+                        Behavior.DRAIN_OVER_LIMIT if pick < 0.3 else
+                        Behavior(0))
+            reqs.append(RateLimitRequest(
+                name=f"mixed{seed}", unique_key=f"k{k}",
+                hits=int(rng.choice([0, 1, 1, 1, 2, 5])), limit=limit[k],
+                duration=duration[k], algorithm=Algorithm(algo[k]),
+                behavior=behavior, burst=burst[k] if algo[k] else 0))
+        for q, a in zip(reqs, eng.process(reqs, now=now)):
+            ref[q.unique_key], r = reference.transition(
+                ref.get(q.unique_key), {
+                    "hits": q.hits, "limit": q.limit,
+                    "duration": q.duration, "algorithm": int(q.algorithm),
+                    "behavior": int(q.behavior), "burst": q.burst,
+                    "created_at": now}, now)
+            assert not a.error
+            assert (int(a.status), a.limit, a.remaining, a.reset_time) == (
+                r["status"], r["limit"], r["remaining"], r["reset_time"]
+            ), (q, a, r)
