@@ -1608,7 +1608,7 @@ async def _loopback_bench(engine, n_keys):
             cols, errors, special = parsed
             mat, errs = await inst.get_rate_limits_columns(cols)
             t1 = time.perf_counter() if fr is not None else 0.0
-            out = fastwire.encode_resp(mat)
+            out, _over = fastwire.encode_resp(mat)
             if fr is not None:
                 fr.edge("encode", time.perf_counter() - t1)
             # Client-side decode closes the loop (the response bytes

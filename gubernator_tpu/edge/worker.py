@@ -69,6 +69,7 @@ from gubernator_tpu.ops.reqcols import (
     IngestOverloadError,
     ReqColumns,
     key_blob_from_parts,
+    slab_addr,
 )
 from gubernator_tpu.transport import fastwire
 
@@ -87,16 +88,19 @@ class _WorkerSlabLease:
     until publish), so release — parse-failure cleanup — is a no-op and
     the cursor simply reuses the slab."""
 
-    __slots__ = ("ints", "flags", "blob", "index")
+    __slots__ = ("ints", "flags", "blob", "addr", "index")
 
-    def __init__(self, ints, flags, blob, index):
+    def __init__(self, ints, flags, blob, addr, index):
         self.ints = ints
         self.flags = flags
         self.blob = blob
+        self.addr = addr
         self.index = index
 
     def release(self) -> None:
         pass
+
+    cancel = release  # a batch wider than the slab: never published
 
 
 class _WorkerArena:
@@ -112,19 +116,20 @@ class _WorkerArena:
         self.ring = ring
         self.max_batch = seg.max_batch
         self.blob_cap = seg.blob_cap
+        self._addr = [
+            slab_addr(seg.req_ints[i], seg.req_flags[i], seg.req_blob[i])
+            for i in range(seg.slabs)
+        ]
         self.last: Optional[_WorkerSlabLease] = None
 
-    def lease(self, n: int, blob_cap: int) -> Optional[_WorkerSlabLease]:
-        if n > self.max_batch or blob_cap > self.blob_cap:
-            return None
+    def lease(self) -> Optional[_WorkerSlabLease]:
         idx = self.ring.try_claim()
         if idx is None:
             return None
-        ints = self.seg.req_ints[idx]
-        ints[:, : n + 1] = 0
-        flags = self.seg.req_flags[idx]
-        flags[:n] = 0
-        self.last = _WorkerSlabLease(ints, flags, self.seg.req_blob[idx], idx)
+        seg = self.seg
+        self.last = _WorkerSlabLease(
+            seg.req_ints[idx], seg.req_flags[idx], seg.req_blob[idx],
+            self._addr[idx], idx)
         return self.last
 
     def fits(self, n: int, blob_cap: int) -> bool:
@@ -546,7 +551,7 @@ def _encode_reply(mat: np.ndarray, errors: dict) -> bytes:
     error items take the pb object path, mirroring the daemon's
     fallback."""
     if not errors:
-        return fastwire.encode_resp(np.ascontiguousarray(mat))
+        return fastwire.encode_resp(mat)[0]
     from gubernator_tpu import pb
 
     status, limit, remaining, reset = (

@@ -31,7 +31,10 @@ _SOURCES = {
 # checkout built can be newer than its source and still lack it: that
 # counts as stale too (rebuilt, or refused with the WARNING), so a
 # library is never bound half-way.
-_NEWEST_SYMBOL = {"libguber_slotmap.so": b"guber_slotmap_pack_window"}
+_NEWEST_SYMBOL = {
+    "libguber_slotmap.so": b"guber_slotmap_pack_window",
+    "libguber_wire.so": b"guber_decode_req",
+}
 _lib: Optional[ctypes.CDLL] = None
 _build_attempted = False
 _paths: dict = {}   # library name -> resolved path / None, once per process

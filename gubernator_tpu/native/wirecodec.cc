@@ -223,6 +223,76 @@ int64_t guber_parse_req(const uint8_t* buf, int64_t len,
   return n;
 }
 
+// Slots of guber_decode_req's summary, before the algorithm histogram.
+enum : int { kSumN = 0, kSumFlagsOr, kSumBadAlgorithm, kSumSpecial,
+             kSumBlobLen, kSumHist };
+
+// The serving edge's whole decode in one call: count the items, parse
+// them into the caller's slab, and tell the caller what it would
+// otherwise walk the columns to learn.
+//
+//   ints        9 rows of int64, row_stride elements apart: name_len,
+//               hits, limit, duration, algorithm, behavior, burst,
+//               created_at (n each) and key_off (n + 1); rows hold
+//               max_rows + 1 elements.  The first n + 1 of every row
+//               are zeroed here (proto3 absents must read 0);
+//   flags       max_rows uint8 (guber_parse_req's out_flags);
+//   blob        blob_cap staging bytes for the packed keys;
+//   summary     kSumHist + algorithm_max + 1 int64: n, the OR of the
+//               flags, whether any algorithm lies outside
+//               [0, algorithm_max], whether any item is special (a
+//               behavior bit of special_behavior, or metadata), the
+//               blob's length, and the count of each valid algorithm.
+//
+// created_at reads created_unset where absent or 0 ("the server stamps
+// now").  Returns n, -1 on malformed input, or -2 with summary[kSumN]
+// set when n > max_rows or len + n > blob_cap (the slab is too small;
+// nothing was written).
+int64_t guber_decode_req(const uint8_t* buf, int64_t len,
+                         int64_t* ints, int64_t row_stride,
+                         uint8_t* flags, uint8_t* blob,
+                         int64_t max_rows, int64_t blob_cap,
+                         int64_t algorithm_max, int64_t special_behavior,
+                         int64_t created_unset, int64_t* summary) {
+  const int64_t n = guber_wire_count(buf, len);
+  if (n < 0) return -1;
+  summary[kSumN] = n;
+  if (n > max_rows || len + n > blob_cap) return -2;
+  for (int r = 0; r < 9; ++r)
+    std::memset(ints + r * row_stride, 0, (n + 1) * sizeof(int64_t));
+  std::memset(flags, 0, n);
+  int64_t* const algorithm = ints + 4 * row_stride;
+  int64_t* const behavior = ints + 5 * row_stride;
+  int64_t* const created_at = ints + 7 * row_stride;
+  int64_t* const key_off = ints + 8 * row_stride;
+  if (guber_parse_req(buf, len, blob, blob_cap, key_off, ints,
+                      ints + row_stride, ints + 2 * row_stride,
+                      ints + 3 * row_stride, algorithm, behavior,
+                      ints + 6 * row_stride, created_at, flags) != n)
+    return -1;
+  int64_t* const hist = summary + kSumHist;
+  for (int64_t a = 0; a <= algorithm_max; ++a) hist[a] = 0;
+  uint8_t flags_or = 0;
+  int64_t behavior_or = 0;
+  bool bad_algorithm = false;
+  for (int64_t i = 0; i < n; ++i) {
+    if (created_at[i] == 0) created_at[i] = created_unset;
+    flags_or |= flags[i];
+    behavior_or |= behavior[i];
+    if (static_cast<uint64_t>(algorithm[i]) >
+        static_cast<uint64_t>(algorithm_max))
+      bad_algorithm = true;
+    else
+      ++hist[algorithm[i]];
+  }
+  summary[kSumFlagsOr] = flags_or;
+  summary[kSumBadAlgorithm] = bad_algorithm;
+  summary[kSumSpecial] =
+      (flags_or & kHasMetadata) || (behavior_or & special_behavior);
+  summary[kSumBlobLen] = key_off[n];
+  return n;
+}
+
 // Parse a serialized GetRateLimitsResp (or GetPeerRateLimitsResp — same
 // shape, field 1 repeated RateLimitResp) into a (5, n) column block:
 // status, limit, remaining, reset_time, and a has-error flag (1 when the
@@ -380,56 +450,41 @@ int64_t guber_encode_req(const uint8_t* key_blob, const int64_t* key_off,
   return w.p - out;
 }
 
-// Serialize a GetRateLimitsResp from the engine's (5, n) response
-// matrix rows (status, limit, remaining, reset_time; row 4 over_limit is
-// not a wire field).  Proto3 zero-omission matches the protobuf library
-// byte for byte for items with no error/metadata.  Returns bytes
-// written or -needed when out_cap is too small.
-int64_t guber_encode_resp(const int64_t* status, const int64_t* limit,
-                          const int64_t* remaining,
-                          const int64_t* reset_time,
-                          int64_t n, uint8_t* out, int64_t out_cap) {
-  int64_t total = 0;
-  for (int64_t i = 0; i < n; ++i) {
-    int64_t sz = 0;
-    if (status[i]) sz += 1 + varint_size(static_cast<uint64_t>(status[i]));
-    if (limit[i]) sz += 1 + varint_size(static_cast<uint64_t>(limit[i]));
-    if (remaining[i])
-      sz += 1 + varint_size(static_cast<uint64_t>(remaining[i]));
-    if (reset_time[i])
-      sz += 1 + varint_size(static_cast<uint64_t>(reset_time[i]));
-    total += 1 + varint_size(sz) + sz;
-  }
-  if (total > out_cap) return -total;
+// Serialize a GetRateLimitsResp from the engine's (5, n) int64 response
+// matrix: rows status, limit, remaining, reset_time and over_limit
+// (row 4: not a wire field, summed into *over_limit), row_stride
+// elements apart, each contiguous (the tick loop hands out column
+// slices of one wider matrix).  Proto3 zero-omission matches the
+// protobuf library byte for byte for items with no error/metadata.  An
+// item is at most 46 bytes (four fields of 1 tag + 10 varint bytes, and
+// a 2-byte header since 44 < 128), so out_cap >= 46 n always fits.
+// Returns bytes written, or -1 when out_cap is smaller than that.
+int64_t guber_encode_resp_mat(const int64_t* mat, int64_t row_stride,
+                              int64_t n, uint8_t* out, int64_t out_cap,
+                              int64_t* over_limit) {
+  if (out_cap < 46 * n) return -1;
+  const int64_t* const over = mat + 4 * row_stride;
   Writer w{out, out + out_cap};
+  int64_t over_sum = 0;
   for (int64_t i = 0; i < n; ++i) {
     int64_t sz = 0;
-    if (status[i]) sz += 1 + varint_size(static_cast<uint64_t>(status[i]));
-    if (limit[i]) sz += 1 + varint_size(static_cast<uint64_t>(limit[i]));
-    if (remaining[i])
-      sz += 1 + varint_size(static_cast<uint64_t>(remaining[i]));
-    if (reset_time[i])
-      sz += 1 + varint_size(static_cast<uint64_t>(reset_time[i]));
+    for (int f = 0; f < 4; ++f) {
+      const int64_t v = mat[f * row_stride + i];
+      if (v) sz += 1 + varint_size(static_cast<uint64_t>(v));
+    }
     w.varint((1u << 3) | 2);
     w.varint(sz);
-    if (status[i]) {
-      w.varint((1u << 3) | 0);
-      w.varint(static_cast<uint64_t>(status[i]));
+    for (int f = 0; f < 4; ++f) {
+      const int64_t v = mat[f * row_stride + i];
+      if (v) {
+        w.varint(((f + 1u) << 3) | 0);
+        w.varint(static_cast<uint64_t>(v));
+      }
     }
-    if (limit[i]) {
-      w.varint((2u << 3) | 0);
-      w.varint(static_cast<uint64_t>(limit[i]));
-    }
-    if (remaining[i]) {
-      w.varint((3u << 3) | 0);
-      w.varint(static_cast<uint64_t>(remaining[i]));
-    }
-    if (reset_time[i]) {
-      w.varint((4u << 3) | 0);
-      w.varint(static_cast<uint64_t>(reset_time[i]));
-    }
-    if (!w.ok) return -1;
+    over_sum += over[i];
   }
+  if (!w.ok) return -1;
+  *over_limit = over_sum;
   return w.p - out;
 }
 

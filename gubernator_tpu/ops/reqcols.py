@@ -80,6 +80,10 @@ class ReqColumns:
     # :meth:`release` once the tick has consumed the columns so the slab
     # recycles.  Plain batches carry None and release() is a no-op.
     lease: Optional["ArenaLease"] = field(default=None, repr=False)
+    # Count of each algorithm value 0..ALGORITHM_MAX over the batch, as
+    # the native decode counted it (fastwire.parse_req); None where the
+    # columns came from anywhere else, and the reader counts itself.
+    algo_hist: Optional[Sequence[int]] = field(default=None, repr=False)
 
     def __len__(self) -> int:
         return len(self.hits)
@@ -240,22 +244,47 @@ class IngestOverloadError(RuntimeError):
 
 class ArenaLease:
     """One leased slab of a :class:`ColumnArena` (views handed to the
-    decoder plus the release token).  Thread-safe release; idempotent."""
+    decoder plus the release token).  Thread-safe release; idempotent.
 
-    __slots__ = ("arena", "index", "ints", "flags", "blob")
+    ``addr`` is what the native decode takes in place of the arrays:
+    ``(ints address, ints row stride in elements, flags address, blob
+    address, rows the slab holds, blob bytes)`` — plain integers, made
+    once a slab when the arena is built."""
+
+    __slots__ = ("arena", "index", "ints", "flags", "blob", "addr")
 
     def __init__(self, arena: "ColumnArena", index: int,
-                 ints: np.ndarray, flags: np.ndarray, blob: np.ndarray):
+                 ints: np.ndarray, flags: np.ndarray, blob: np.ndarray,
+                 addr: tuple):
         self.arena = arena
         self.index = index
         self.ints = ints
         self.flags = flags
         self.blob = blob
+        self.addr = addr
 
     def release(self) -> None:
         arena, self.arena = self.arena, None
         if arena is not None:
             arena._release(self.index)
+
+    def cancel(self) -> None:
+        """Hand the slab back unused: the decode found the batch wider
+        than the slab.  Counted as the size miss it is, not as a lease,
+        and no window completed, so the fallback budget stands."""
+        arena, self.arena = self.arena, None
+        if arena is not None:
+            arena._cancel(self.index)
+
+
+def slab_addr(ints: np.ndarray, flags: np.ndarray, blob: np.ndarray) -> tuple:
+    """:attr:`ArenaLease.addr` of one decode block: ``ints`` is (9, rows
+    + 1) int64 with contiguous rows, ``flags`` and ``blob`` contiguous
+    uint8."""
+    return (
+        ints.ctypes.data, ints.strides[0] // 8, flags.ctypes.data,
+        blob.ctypes.data, ints.shape[1] - 1, blob.shape[0],
+    )
 
 
 class ColumnArena:
@@ -272,10 +301,11 @@ class ColumnArena:
     blob's bytes materialization, which the native slotmap requires).
 
     Bounded by construction: a batch wider than ``max_batch`` (or a key
-    blob larger than the slab), or a lease request while every slab is
-    busy (more concurrent in-flight windows than ``slabs``), returns
-    None and the caller falls back to plain allocation — the arena is a
-    fast path, never a correctness constraint.  ``slabs`` should cover
+    blob larger than the slab) hands its lease back, a lease request
+    while every slab is busy (more concurrent in-flight windows than
+    ``slabs``) returns None, and either way the caller falls back to
+    plain allocation — the arena is a fast path, never a correctness
+    constraint.  ``slabs`` should cover
     the tick pipeline depth plus decode concurrency
     (GUBER_INGEST_ARENA_SLABS; see docs/tpu-performance.md).
     """
@@ -294,6 +324,10 @@ class ColumnArena:
             (self.n_slabs, 9, self.max_batch + 1), np.int64)
         self._flags = np.zeros((self.n_slabs, self.max_batch), np.uint8)
         self._blob = np.empty((self.n_slabs, self.blob_cap), np.uint8)
+        self._addr = [
+            slab_addr(self._ints[i], self._flags[i], self._blob[i])
+            for i in range(self.n_slabs)
+        ]
         self._busy = [False] * self.n_slabs
         self._next = 0
         self._lock = sanitize.lock("ColumnArena._lock")
@@ -317,14 +351,13 @@ class ColumnArena:
         self.metric_fallbacks = 0
 
     @hot_path
-    def lease(self, n: int, blob_cap: int) -> Optional[ArenaLease]:
-        """A slab for an ``n``-row decode needing ``blob_cap`` staging
-        bytes, or None (caller allocates).  The returned views are
-        already zeroed where the decoder requires zeros (proto3 absent
-        fields must read 0)."""
-        if n > self.max_batch or blob_cap > self.blob_cap:
-            self.metric_misses += 1
-            return None
+    def lease(self) -> Optional[ArenaLease]:
+        """A free slab, or None when every one is busy (the caller
+        allocates, within :meth:`try_fallback`'s budget).  The decoder
+        leases before it knows the batch's size (one native call counts
+        and parses) and cancels (:meth:`ArenaLease.cancel`) the lease of
+        a batch it finds wider than the slab.  The slab is not cleaned:
+        the decoder zeroes the region it uses."""
         with self._lock:
             idx = -1
             for k in range(self.n_slabs):
@@ -338,13 +371,8 @@ class ColumnArena:
             self._busy[idx] = True
             self._next = (idx + 1) % self.n_slabs
             self.metric_leases += 1
-        ints = self._ints[idx]
-        # Zero only the region this decode reads/writes, not the slab:
-        # the decoder writes only fields present on the wire.
-        ints[:, : n + 1] = 0
-        flags = self._flags[idx]
-        flags[:n] = 0
-        return ArenaLease(self, idx, ints, flags, self._blob[idx])
+        return ArenaLease(self, idx, self._ints[idx], self._flags[idx],
+                          self._blob[idx], self._addr[idx])
 
     def fits(self, n: int, blob_cap: int) -> bool:
         """Whether an ``n``-row decode could EVER lease here — False is a
@@ -369,6 +397,12 @@ class ColumnArena:
         with self._lock:
             self._busy[index] = False
             self._window_fallback_rows = 0
+
+    def _cancel(self, index: int) -> None:
+        with self._lock:
+            self._busy[index] = False
+            self.metric_leases -= 1
+            self.metric_misses += 1
 
     def in_use(self) -> int:
         with self._lock:

@@ -334,6 +334,10 @@ class V1Instance:
                         fallback_limit=fallback_limit)
             if slabs > 0 else None
         )
+        # Calls through the raw-bytes edge, and those of them that the
+        # two native passes answered (count_edge_call; /debug/state).
+        self.metric_edge_calls = 0
+        self.metric_edge_native_calls = 0
         # Multi-process streaming edge (docs/edge.md): attached by the
         # daemon when GUBER_EDGE_WORKERS > 0; closed before the tick
         # loop so in-flight shm windows resolve while it still runs.
@@ -689,22 +693,32 @@ class V1Instance:
             and hasattr(self.engine, "submit_cols")
         )
 
-    async def get_rate_limits_columns(self, cols, deadline: float = None):
+    async def get_rate_limits_columns(self, cols, deadline: float = None,
+                                      over_from_encode: bool = False):
         """Columnar GetRateLimits (the fast path; see
         columns_fast_path_ok).  Returns ``((5, n) matrix, errors)`` in
         request order; the transport writes wire responses straight from
         the matrix.  ``deadline`` is the batch's absolute admission
-        deadline stamped at the serving edge (docs/overload.md)."""
+        deadline stamped at the serving edge (docs/overload.md);
+        ``over_from_encode`` as in :meth:`_columns_tick`."""
         if len(cols) > MAX_BATCH_SIZE:
             self.metrics.check_error_counter.labels(error="Request too large").inc()
             raise BatchTooLargeError(
                 f"Requests.RateLimits list too large; max size is '{MAX_BATCH_SIZE}'"
             )
-        return await self._columns_tick(cols, deadline=deadline)
+        return await self._columns_tick(
+            cols, deadline=deadline, over_from_encode=over_from_encode)
 
     async def _columns_tick(self, cols, public: bool = True,
-                            deadline: float = None):
+                            deadline: float = None,
+                            over_from_encode: bool = False):
         """One tick-loop submission for a columnar batch + metrics.
+
+        ``over_from_encode``: the caller encodes an answer without
+        per-item errors with ``fastwire.encode_resp`` and adds the
+        over-limit count that pass returns to ``over_limit_counter``
+        itself; only an answer with errors, which it does not encode,
+        is counted here.
 
         ``public`` marks the public GetRateLimits edge, which alone
         carries the concurrent-checks gauge and the GetRateLimits
@@ -725,12 +739,13 @@ class V1Instance:
             self.metrics.getratelimit_counter.labels(calltype="local").inc(
                 len(cols) - len(errors)
             )
-            self._count_algorithms(cols.algorithm)
-            from gubernator_tpu.ops.engine import masked_over_limit
+            self._count_algorithms(cols.algorithm, cols.algo_hist)
+            if errors or not over_from_encode:
+                from gubernator_tpu.ops.engine import masked_over_limit
 
-            over = masked_over_limit(mat, errors)
-            if over:
-                self.metrics.over_limit_counter.inc(over)
+                over = masked_over_limit(mat, errors)
+                if over:
+                    self.metrics.over_limit_counter.inc(over)
             return mat, errors
         finally:
             dt = time.perf_counter() - t0
@@ -768,21 +783,34 @@ class V1Instance:
 
         return asyncio.ensure_future(run())
 
-    def _count_algorithms(self, algorithms) -> None:
+    def _count_algorithms(self, algorithms, hist=None) -> None:
         """Per-algorithm traffic split (gubernator_tpu_algorithm_requests).
 
         ``algorithms`` is host-side (a list or the batch's numpy column —
         never a device value).  Out-of-range lanes were rejected with
-        per-item errors at the edge and are skipped here.
+        per-item errors at the edge and are skipped here.  ``hist`` is
+        the same count where the native decode already made it
+        (``ReqColumns.algo_hist``): no pass over the column then.
         """
-        a = np.asarray(algorithms, np.int64)
-        ok = (a >= 0) & (a <= int(ALGORITHM_MAX))
-        counts = np.bincount(a[ok], minlength=int(ALGORITHM_MAX) + 1)
-        for v, c in enumerate(counts):
+        if hist is None:
+            a = np.asarray(algorithms, np.int64)
+            ok = (a >= 0) & (a <= int(ALGORITHM_MAX))
+            hist = np.bincount(
+                a[ok], minlength=int(ALGORITHM_MAX) + 1).tolist()
+        for v, c in enumerate(hist):
             if c:
                 self.metrics.algorithm_requests.labels(
                     algorithm=Algorithm(v).name.lower()
-                ).inc(int(c))
+                ).inc(c)
+
+    def count_edge_call(self, native: bool) -> None:
+        """One call through the raw-bytes edge (transport/daemon.py
+        ``_raw_columns_edge``); ``native`` when the two native passes
+        answered it, decode and encode, with no fallback between."""
+        self.metric_edge_calls += 1
+        self.metric_edge_native_calls += native
+        self.metrics.edge_calls.labels(
+            path="native" if native else "fallback").inc()
 
     async def apply_local(
         self, reqs: List[RateLimitRequest]
@@ -998,7 +1026,8 @@ class V1Instance:
             and hasattr(self.engine, "submit_cols")
         )
 
-    async def get_peer_rate_limits_columns(self, cols, deadline: float = None):
+    async def get_peer_rate_limits_columns(self, cols, deadline: float = None,
+                                           over_from_encode: bool = False):
         """Columnar owner-side handling of a relayed batch (the peer-edge
         twin of get_rate_limits_columns; eligibility per
         peer_columns_fast_path_ok).  Peer admission class: relayed
@@ -1009,7 +1038,9 @@ class V1Instance:
                 f"'PeerRequest.rate_limits' list too large; max size is "
                 f"'{MAX_BATCH_SIZE}'"
             )
-        return await self._columns_tick(cols, public=False, deadline=deadline)
+        return await self._columns_tick(
+            cols, public=False, deadline=deadline,
+            over_from_encode=over_from_encode)
 
     async def get_peer_rate_limits(
         self, requests: Sequence[RateLimitRequest]

@@ -184,7 +184,7 @@ def _sync_arena_metrics(arena, metrics) -> None:
     """Mirror the arena's plain-int fallback counter into the
     gubernator_tpu_arena_fallbacks family (delta sync, the tick loop's
     engine-counter pattern)."""
-    if arena is None or metrics is None:
+    if arena is None:
         return
     synced = getattr(arena, "_synced_fallbacks", 0)
     if arena.metric_fallbacks > synced:
@@ -192,8 +192,8 @@ def _sync_arena_metrics(arena, metrics) -> None:
         arena._synced_fallbacks = arena.metric_fallbacks
 
 
-async def _raw_columns_edge(raw, context, gate_ok, tick, msg_type,
-                            arena=None, deadline=None, metrics=None):
+async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
+                            msg_type, deadline=None):
     """The shared raw-bytes fast path of both rate-limit edges: native
     wire parse → columns → device tick → native wire encode, with no
     protobuf objects.  Returns ``(result, msg)``: ``result`` is the
@@ -202,12 +202,23 @@ async def _raw_columns_edge(raw, context, gate_ok, tick, msg_type,
     the protobuf message if one was already parsed along the way (so
     the caller's object path doesn't parse twice).
 
-    ``arena`` (the instance's ingest ColumnArena) makes the decode land
-    in a preallocated slab — zero per-batch allocation.  The tick loop
+    A plain call enters native code twice on this thread
+    (docs/architecture.md, "Two native crossings a call"):
+    the decode, whose summary says whether Python has to look at the
+    columns at all, and the encode, which hands back the over-limit
+    count beside the bytes.  ``instance.metric_edge_native_calls`` over
+    ``metric_edge_calls`` is the share of calls answered that way.
+
+    The instance's ingest ColumnArena makes the decode land in a
+    preallocated slab — zero per-batch allocation.  The tick loop
     releases the slab after packing; batches that bail to the object
     path release it here."""
     msg = None
-    if gate_ok:
+    native = False
+    try:
+        if not gate_ok:
+            return None, msg
+        arena, metrics = instance.ingest_arena, instance.metrics
         # Flight-recorder transport edges: per-batch decode/encode CPU
         # (folded into window records — see utils/flightrec.py).
         fr = flightrec.get()
@@ -218,33 +229,40 @@ async def _raw_columns_edge(raw, context, gate_ok, tick, msg_type,
             # Bounded ingest (docs/overload.md): arena exhaustion past
             # the fallback budget is backpressure, not an allocation —
             # answer retriable RESOURCE_EXHAUSTED so clients back off.
-            if metrics is not None:
-                metrics.admission_shed.labels(reason="backpressure").inc()
+            metrics.admission_shed.labels(reason="backpressure").inc()
             await context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
         _sync_arena_metrics(arena, metrics)
         if fr is not None:
             fr.edge("decode", time.perf_counter() - t0)
-        if parsed is None:  # codec unavailable or malformed bytes
+        decoded = parsed is not None
+        if not decoded:  # codec unavailable or malformed bytes
             msg = await _parse_pb(msg_type, raw, context)
             parsed = convert.columns_from_pb(msg.requests)
         cols, errors, special = parsed
-        if not special and not errors:
-            try:
-                mat, errs = await tick(cols, deadline=deadline)
-            except BatchTooLargeError as e:
-                cols.release()  # rejected before the tick loop saw it
-                await context.abort(grpc.StatusCode.OUT_OF_RANGE, str(e))
-            if not errs:
-                # Native wire encoding straight from the matrix; the
-                # method's pass-through serializer ships bytes as-is.
-                t1 = time.perf_counter() if fr is not None else 0.0
-                out = fastwire.encode_resp(mat)
-                if fr is not None:
-                    fr.edge("encode", time.perf_counter() - t1)
-                return out, msg
+        if special or errors:
+            cols.release()  # object path re-parses; the slab is dead weight
+            return None, msg
+        try:
+            mat, errs = await tick(cols, deadline=deadline,
+                                   over_from_encode=True)
+        except BatchTooLargeError as e:
+            cols.release()  # rejected before the tick loop saw it
+            await context.abort(grpc.StatusCode.OUT_OF_RANGE, str(e))
+        if errs:
             return _item_responses(mat, errs), msg
-        cols.release()  # object path re-parses; the slab is dead weight
-    return None, msg
+        # Native wire encoding straight from the matrix; the method's
+        # pass-through serializer ships bytes as-is.  The same pass
+        # counts the over-limit items, which the service left to it.
+        t1 = time.perf_counter() if fr is not None else 0.0
+        out, over = fastwire.encode_resp(mat)
+        if fr is not None:
+            fr.edge("encode", time.perf_counter() - t1)
+        if over:
+            metrics.over_limit_counter.inc(over)
+        native = decoded
+        return out, msg
+    finally:
+        instance.count_edge_call(native)
 
 
 class V1Servicer:
@@ -267,13 +285,11 @@ class V1Servicer:
     async def GetRateLimits(self, raw: bytes, context):
         deadline = _edge_deadline(context, self._default_budget())
         fast, msg = await _raw_columns_edge(
-            raw, context,
+            raw, context, self.instance,
             self.instance.columns_fast_path_ok(),
             self.instance.get_rate_limits_columns,
             pb.GetRateLimitsReq,
-            arena=self.instance.ingest_arena,
             deadline=deadline,
-            metrics=self.instance.metrics,
         )
         if fast is not None:
             if isinstance(fast, bytes):
@@ -353,13 +369,11 @@ class PeersServicer:
     async def GetPeerRateLimits(self, raw: bytes, context):
         deadline = _edge_deadline(context, self._default_budget())
         fast, msg = await _raw_columns_edge(
-            raw, context,
+            raw, context, self.instance,
             self.instance.peer_columns_fast_path_ok(),
             self.instance.get_peer_rate_limits_columns,
             peers_pb.GetPeerRateLimitsReq,
-            arena=self.instance.ingest_arena,
             deadline=deadline,
-            metrics=self.instance.metrics,
         )
         if fast is not None:
             if isinstance(fast, bytes):
@@ -809,6 +823,12 @@ class Daemon:
                 "leases": arena.metric_leases,
                 "misses": arena.metric_misses,
             }
+        # native over calls: the share of raw-bytes calls that the two
+        # native passes answered (_raw_columns_edge)
+        body["edge_calls"] = {
+            "calls": inst.metric_edge_calls,
+            "native": inst.metric_edge_native_calls,
+        }
         if inst.edge_plane is not None:
             body["edge"] = inst.edge_plane.debug_state()
         engine_tel: dict = {}

@@ -21,6 +21,7 @@ against the protobuf library in tests/test_fastwire.py.
 from __future__ import annotations
 
 import ctypes
+import threading
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -32,8 +33,9 @@ from gubernator_tpu.ops.reqcols import (
     ColumnArena,
     IngestOverloadError,
     ReqColumns,
+    slab_addr,
 )
-from gubernator_tpu.types import Behavior
+from gubernator_tpu.types import ALGORITHM_MAX, Behavior
 from gubernator_tpu.utils.hotpath import hot_path
 
 _I64 = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
@@ -51,8 +53,34 @@ _HAS_CREATED = 8
 # fast path cannot express).
 _SPECIAL_BEHAVIOR = int(Behavior.GLOBAL) | int(Behavior.MULTI_REGION)
 
+# guber_decode_req's summary (wirecodec.cc kSum*): n, the OR of the
+# item flags, any algorithm out of range, any special item, the key
+# blob's length, then the count of each algorithm 0..ALGORITHM_MAX.
+_ALGORITHM_MAX = int(ALGORITHM_MAX)
+_Summary = ctypes.c_int64 * (5 + _ALGORITHM_MAX + 1)
+_TOO_WIDE = -2
+
 _lib = None
 _load_attempted = False
+
+
+class _Scratch(threading.local):
+    """The encode's output buffer and over-limit cell, one a thread, the
+    buffer grown to the widest call."""
+
+    cap = 0
+
+    def __init__(self):
+        self.over = ctypes.c_int64()
+        self.over_ref = ctypes.byref(self.over)
+
+    def grow(self, cap: int) -> None:
+        self.cap = max(cap, 2 * self.cap)
+        self.buf = ctypes.create_string_buffer(self.cap)
+        self.addr = ctypes.addressof(self.buf)
+
+
+_scratch = _Scratch()
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -68,11 +96,28 @@ def load() -> Optional[ctypes.CDLL]:
     lib = ctypes.CDLL(so)
     lib.guber_wire_count.restype = ctypes.c_int64
     lib.guber_wire_count.argtypes = [ctypes.c_char_p, ctypes.c_int64]
-    lib.guber_parse_req.restype = ctypes.c_int64
-    lib.guber_parse_req.argtypes = [
+    # The two calls of the serving edge take addresses and integers:
+    # nothing is converted a call (an ndpointer argument costs ~4 us).
+    # They are bound through PyDLL and keep the GIL: ~50 and ~45 us a
+    # 1,000-item call, against a hand-over each way when it is dropped.
+    # On the chip the two read alike (PERF.md, PR 31), and held is the
+    # one that leaves this thread nothing to wait for.  The count (~5
+    # us) goes with them: a decode that found every slab busy makes it.
+    held = ctypes.PyDLL(so)
+    lib.guber_wire_count = held.guber_wire_count
+    lib.guber_decode_req = held.guber_decode_req
+    lib.guber_encode_resp_mat = held.guber_encode_resp_mat
+    lib.guber_decode_req.restype = ctypes.c_int64
+    lib.guber_decode_req.argtypes = [
         ctypes.c_char_p, ctypes.c_int64,
-        _U8, ctypes.c_int64, _I64, _I64,
-        _I64, _I64, _I64, _I64, _I64, _I64, _I64, _U8,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_void_p,
+    ]
+    lib.guber_encode_resp_mat.restype = ctypes.c_int64
+    lib.guber_encode_resp_mat.argtypes = [
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
     ]
     lib.guber_parse_resp.restype = ctypes.c_int64
     lib.guber_parse_resp.argtypes = [
@@ -83,11 +128,6 @@ def load() -> Optional[ctypes.CDLL]:
     lib.guber_encode_req.argtypes = [
         ctypes.c_char_p, _I64, _I64,
         _I64, _I64, _I64, _I64, _I64, _I64, _I64, _U8,
-        ctypes.c_int64, _U8, ctypes.c_int64,
-    ]
-    lib.guber_encode_resp.restype = ctypes.c_int64
-    lib.guber_encode_resp.argtypes = [
-        _I64, _I64, _I64, _I64,
         ctypes.c_int64, _U8, ctypes.c_int64,
     ]
     _lib = lib
@@ -107,6 +147,13 @@ def parse_req(
     native library is unavailable or the bytes are malformed (caller
     falls back to ``pb.GetRateLimitsReq.FromString``).
 
+    One native call (``guber_decode_req``) counts the items, parses them
+    and reports what the edge and the service would otherwise walk the
+    columns for: ``special``, whether any item needs an error string,
+    and the algorithm histogram that rides on ``cols.algo_hist``.  Only
+    a batch the arena cannot hold (too wide, or every slab busy) costs
+    a second call, to count before it allocates.
+
     With ``arena`` (ops.reqcols.ColumnArena) the decode lands in a
     preallocated slab and the returned columns — key blob included —
     are views into it: zero per-window allocation and zero copies
@@ -119,18 +166,24 @@ def parse_req(
     if lib is None:
         return None
     ln = len(data)
-    n = lib.guber_wire_count(data, ln)
-    if n < 0:
-        return None
-    if n == 0:
+    if ln == 0:
         return ReqColumns.empty(), {}, False
-    blob_cap = ln + n
-    lease = arena.lease(n, blob_cap) if arena is not None else None
+    summary = _Summary()
+    got = None
+    lease = arena.lease() if arena is not None else None
     if lease is not None:
-        ints = lease.ints
-        blob = lease.blob
-        flags_full = lease.flags
-    else:
+        ints, flags_full, blob = lease.ints, lease.flags, lease.blob
+        got = lib.guber_decode_req(
+            data, ln, *lease.addr, _ALGORITHM_MAX, _SPECIAL_BEHAVIOR,
+            CREATED_UNSET, summary)
+        if got == _TOO_WIDE:
+            lease.cancel()
+            lease = None
+    if lease is None:
+        n = summary[0] if got == _TOO_WIDE else lib.guber_wire_count(data, ln)
+        if n < 0:
+            return None
+        blob_cap = ln + n
         # Bounded fallback (docs/overload.md): a size miss (batch wider
         # than any slab) always plain-allocates, but busy-slab
         # exhaustion spends the arena's per-window fallback budget —
@@ -139,31 +192,29 @@ def parse_req(
                 and not arena.try_fallback(n)):
             raise IngestOverloadError(
                 "ingest arena exhausted and fallback budget spent")
+        # One block for all int64 outputs; the decode zeroes it.
         blob = np.empty(blob_cap, np.uint8)
-        # One zeroed block for all int64 outputs (native writes only the
-        # fields present on the wire; proto3 absents must read 0): a
-        # single memset beats ten allocations at serving batch rates.
-        ints = np.zeros((9, n + 1), np.int64)
-        flags_full = np.zeros(n, np.uint8)
+        ints = np.empty((9, n + 1), np.int64)
+        flags_full = np.empty(n, np.uint8)
+        got = lib.guber_decode_req(
+            data, ln, *slab_addr(ints, flags_full, blob), _ALGORITHM_MAX,
+            _SPECIAL_BEHAVIOR, CREATED_UNSET, summary)
+    if got < 0:
+        if lease is not None:
+            lease.release()
+        return None
+    n = got
+    if n == 0:
+        if lease is not None:
+            lease.release()
+        return ReqColumns.empty(), {}, False
     off = ints[8, : n + 1]
     name_len, hits, limit, duration, algorithm, behavior, burst, created = (
         ints[i, :n] for i in range(8)
     )
-    flags = flags_full[:n]
-    got = lib.guber_parse_req(
-        data, ln, blob, len(blob), off, name_len,
-        hits, limit, duration, algorithm, behavior, burst, created, flags,
-    )
-    if got != n:
-        if lease is not None:
-            lease.release()
-        return None
-    # created_at: absent OR explicit 0 → "server stamps now"
-    # (convert.columns_from_pb parity).
-    created[created == 0] = CREATED_UNSET
     errors: Dict[int, str] = {}
-    # guber: allow-G001(flags is host numpy, never a device value)
-    if bool((flags & (_NAME_EMPTY | _KEY_EMPTY)).any()):
+    if summary[1] & (_NAME_EMPTY | _KEY_EMPTY):
+        flags = flags_full[:n]
         for i in np.flatnonzero(flags & (_NAME_EMPTY | _KEY_EMPTY)):
             errors[int(i)] = (
                 "field 'unique_key' cannot be empty"
@@ -173,26 +224,20 @@ def parse_req(
     # Out-of-range algorithm values must fail loudly here: the kernels'
     # branchless per-lane dispatch would otherwise silently run an
     # unknown enum as a token bucket (algos/__init__.py).
-    bad_algo = invalid_algorithm_mask(algorithm)
-    # guber: allow-G001(algorithm is host numpy, never a device value)
-    if bool(bad_algo.any()):
-        for i in np.flatnonzero(bad_algo):
+    if summary[2]:
+        for i in np.flatnonzero(invalid_algorithm_mask(algorithm)):
             errors.setdefault(int(i), algorithm_error(algorithm[i]))
-    # guber: allow-G001(flags/behavior are host numpy, never device)
-    special = bool((flags & _HAS_METADATA).any()) or bool(
-        (behavior & _SPECIAL_BEHAVIOR).any()
-    )
     # The key blob stays a view into the decode buffer — the last copy
     # on the decode path is gone.  Arena-backed batches alias the slab
     # (valid until cols.release(), same lifetime as the other columns);
     # the plain-allocation branch aliases the freshly-built buffer the
     # columns already own.
     cols = ReqColumns(
-        blob[: off[n]], off, hits, limit, duration,
+        blob[: summary[4]], off, hits, limit, duration,
         algorithm, behavior, created, burst, name_len=name_len,
-        lease=lease,
+        lease=lease, algo_hist=summary[5:],
     )
-    return cols, errors, special
+    return cols, errors, summary[3] != 0
 
 
 def parse_resp(data: bytes) -> Optional[Tuple[np.ndarray, np.ndarray]]:
@@ -255,41 +300,38 @@ def encode_req(cols: ReqColumns, tag_peer: bool = False) -> Optional[bytes]:
 
 
 @hot_path
-def encode_resp(mat: np.ndarray) -> bytes:
-    """(5, n) response matrix → serialized ``GetRateLimitsResp`` bytes.
-    Native when available, else the vectorized numpy encoder
+def encode_resp(mat: np.ndarray) -> Tuple[bytes, int]:
+    """(5, n) response matrix → (serialized ``GetRateLimitsResp`` bytes,
+    the sum of row 4: the call's over-limit count, error lanes and all —
+    a caller with per-item errors does not encode).  Native when
+    available, else the vectorized numpy encoder
     (:func:`transport.wire.encode_get_rate_limits_resp`) — identical
-    bytes either way."""
+    bytes either way.
+
+    One native call reads the matrix where it lies: the tick loop hands
+    out column slices of one int64 matrix, whose rows are contiguous
+    and one row stride apart.  Anything else is copied into that shape
+    first."""
+    n = mat.shape[1]
+    if n == 0:
+        return b"", 0
     lib = load()
     if lib is None:
         from gubernator_tpu.transport.wire import encode_get_rate_limits_resp
 
-        return encode_get_rate_limits_resp(mat)
-    n = mat.shape[1]
-    if n == 0:
-        return b""
-    rows = [np.ascontiguousarray(mat[r], np.int64) for r in range(4)]
+        return encode_get_rate_limits_resp(mat), int(mat[4].sum())
+    if mat.dtype != np.int64 or mat.strides[1] != 8:
+        mat = np.ascontiguousarray(mat, np.int64)
     # Worst case per item: 44 B payload (4 fields x (1 tag + 10 B
     # varint)) + 2 B item header (1 B tag + 1 B length varint, since
-    # payload <= 44 < 128) = 46 B.  The old 44 B/item budget under-sized
-    # adversarial matrices (four 10-byte-varint fields) and leaned on
-    # the retry below.
-    cap = 8 + 46 * n
-    out = np.empty(cap, np.uint8)
-    wrote = lib.guber_encode_resp(rows[0], rows[1], rows[2], rows[3],
-                                  n, out, cap)
-    if wrote < 0:  # cap math above cannot under-size; belt and braces
-        cap = -wrote if wrote < -1 else cap * 2
-        out = np.empty(cap, np.uint8)
-        wrote = lib.guber_encode_resp(rows[0], rows[1], rows[2], rows[3],
-                                      n, out, cap)
-        if wrote < 0:
-            from gubernator_tpu.transport.wire import (
-                encode_get_rate_limits_resp,
-            )
-
-            return encode_get_rate_limits_resp(mat)
-    return out[:wrote].tobytes()
+    # payload <= 44 < 128) = 46 B, so the buffer cannot be too small.
+    scratch = _scratch
+    if scratch.cap < 46 * n:
+        scratch.grow(46 * n)
+    wrote = lib.guber_encode_resp_mat(
+        mat.__array_interface__["data"][0], mat.strides[0] // 8, n,
+        scratch.addr, scratch.cap, scratch.over_ref)
+    return ctypes.string_at(scratch.addr, wrote), scratch.over.value
 
 
 # ----------------------------------------------------------------------

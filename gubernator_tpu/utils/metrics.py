@@ -731,6 +731,16 @@ class Metrics:
             "GUBER_INGEST_FALLBACK_LIMIT, shed beyond the cap.",
             registry=reg,
         )
+        self.edge_calls = Counter(
+            "gubernator_tpu_edge_calls",
+            "Calls through the raw-bytes rate-limit edges, by path: "
+            "native (one native decode and one native encode answered "
+            "the call) or fallback (codec library missing, malformed "
+            "frame, per-item errors, GLOBAL / MULTI_REGION / metadata "
+            "items, clustered routing, a shed or refused call).",
+            ["path"],
+            registry=reg,
+        )
         self.admission_shed = Counter(
             "gubernator_tpu_admission_shed",
             "Requests shed by the admission plane, by reason: expired "

@@ -523,17 +523,17 @@ def test_arena_fallback_budget_is_per_window(rows):
     from gubernator_tpu.ops.reqcols import ColumnArena
 
     arena = ColumnArena(max_batch=8, slabs=1, fallback_limit=2)
-    lease = arena.lease(4, 64)
+    lease = arena.lease()
     assert lease is not None
     # Slab busy: fits-but-unleasable → budgeted fallbacks, then shed.
     assert arena.fits(4, 64)
-    assert arena.lease(4, 64) is None
+    assert arena.lease() is None
     frames = 16 // rows
     for _ in range(frames):
         assert arena.try_fallback(rows)
     assert not arena.try_fallback(rows)  # budget spent
     assert arena.metric_fallbacks == frames
     lease.release()  # window completed: budget resets
-    lease2 = arena.lease(4, 64)
+    lease2 = arena.lease()
     assert arena.try_fallback(rows)
     lease2.release()

@@ -192,7 +192,7 @@ def test_encode_resp_byte_parity():
         )
         for i in range(n)
     ]).SerializeToString()
-    assert fastwire.encode_resp(mat) == ref
+    assert fastwire.encode_resp(mat) == (ref, 0)
     # and the numpy fallback agrees too
     from gubernator_tpu.transport.wire import encode_get_rate_limits_resp
 
@@ -212,14 +212,14 @@ def test_encode_resp_worst_case_cap():
         pb.RateLimitResp(status=-1, limit=-1, remaining=-1, reset_time=-1)
         for _ in range(n)
     ]).SerializeToString()
-    assert fastwire.encode_resp(mat) == ref
+    assert fastwire.encode_resp(mat) == (ref, 0)
 
 
 def test_parse_resp_roundtrip_and_special():
     mat = np.array(
         [[0, 1], [10, 20], [5, -2], [111, 222], [0, 1]], np.int64
     )
-    m, special = fastwire.parse_resp(fastwire.encode_resp(mat))
+    m, special = fastwire.parse_resp(fastwire.encode_resp(mat)[0])
     np.testing.assert_array_equal(m, mat[:4])
     assert not special.any()
     raw = pb.GetRateLimitsResp(responses=[
@@ -234,7 +234,7 @@ def test_parse_resp_roundtrip_and_special():
 def test_empty_batches():
     cols, errors, special = fastwire.parse_req(b"")
     assert len(cols) == 0 and not errors and not special
-    assert fastwire.encode_resp(np.zeros((5, 0), np.int64)) == b""
+    assert fastwire.encode_resp(np.zeros((5, 0), np.int64)) == (b"", 0)
     m, sp = fastwire.parse_resp(b"")
     assert m.shape == (4, 0) and len(sp) == 0
 
@@ -465,3 +465,227 @@ def test_arena_slab_reuse_does_not_alias_live_columns():
     c1.release()
     c3 = fastwire.parse_req(d2, arena)[0]       # reuses c1's slab
     np.testing.assert_array_equal(c3.hits, c2.hits)
+
+
+# ----------------------------------------------------------------------
+# One native crossing each way (docs/edge.md): the decode that counts,
+# parses, checks and summarises in one call, against the decode it
+# replaced; the encode that reads the matrix where it lies
+# ----------------------------------------------------------------------
+def _reference_parse_req(data):
+    """``parse_req`` as it stood before the one-call decode, kept here
+    as the reference: count, allocate, ``guber_parse_req``, then the
+    numpy passes over the columns.  Adds the histogram ``_count_algorithms``
+    would make."""
+    import ctypes
+
+    from gubernator_tpu.algos import algorithm_error, invalid_algorithm_mask
+    from gubernator_tpu.types import ALGORITHM_MAX
+
+    lib = fastwire.load()
+    i64 = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    u8 = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
+    fn = lib.guber_parse_req
+    fn.restype = ctypes.c_int64
+    fn.argtypes = [ctypes.c_char_p, ctypes.c_int64, u8, ctypes.c_int64,
+                   i64, i64, i64, i64, i64, i64, i64, i64, i64, u8]
+    ln = len(data)
+    n = lib.guber_wire_count(data, ln)
+    if n < 0:
+        return None
+    if n == 0:
+        return (), {}, False, None
+    blob = np.empty(ln + n, np.uint8)
+    ints = np.zeros((9, n + 1), np.int64)
+    flags = np.zeros(n, np.uint8)
+    off = ints[8, : n + 1]
+    name_len, hits, limit, duration, algorithm, behavior, burst, created = (
+        ints[i, :n] for i in range(8))
+    if fn(data, ln, blob, len(blob), off, name_len, hits, limit, duration,
+          algorithm, behavior, burst, created, flags) != n:
+        return None
+    created[created == 0] = CREATED_UNSET
+    errors = {}
+    for i in np.flatnonzero(flags & 3):
+        errors[int(i)] = (
+            "field 'unique_key' cannot be empty" if flags[i] & 2
+            else "field 'namespace' cannot be empty")
+    for i in np.flatnonzero(invalid_algorithm_mask(algorithm)):
+        errors.setdefault(int(i), algorithm_error(algorithm[i]))
+    special = bool((flags & 4).any()) or bool((behavior & (2 | 16)).any())
+    ok = (algorithm >= 0) & (algorithm <= int(ALGORITHM_MAX))
+    hist = np.bincount(algorithm[ok], minlength=int(ALGORITHM_MAX) + 1)
+    cols = (bytes(blob[: off[n]]), off, hits, limit, duration, algorithm,
+            behavior, created, burst, name_len)
+    return cols, errors, special, hist
+
+
+def _seeded(n):
+    return lambda: _rand_reqs(np.random.default_rng(1000 + n), n)
+
+
+def _with(**kw):
+    """Seven plain items with one odd one in the middle."""
+    def build():
+        reqs = _rand_reqs(np.random.default_rng(77), 7)
+        fields = {"name": "svc", "unique_key": "odd", "hits": 1, "limit": 9,
+                  **kw}
+        metadata = fields.pop("metadata", None)
+        reqs[3] = pb.RateLimitReq(**fields)
+        if metadata:
+            reqs[3].metadata["trace"] = metadata
+        return reqs
+    return build
+
+
+_DECODE_FRAMES = {
+    "n0": _seeded(0), "n1": _seeded(1), "n7": _seeded(7),
+    "n1000": _seeded(1000), "n4097": _seeded(4097),
+    "empty_name": _with(name=""), "empty_key": _with(unique_key=""),
+    "algorithm_5": _with(algorithm=5), "algorithm_neg1": _with(algorithm=-1),
+    "metadata": _with(metadata="abc"),
+    "global": _with(behavior=2), "multi_region": _with(behavior=16),
+    "created_0": _with(created_at=0), "created_absent": _with(),
+    "truncated": None,
+}
+
+
+@pytest.mark.parametrize("arena_mode", ["absent", "leased", "exhausted"])
+@pytest.mark.parametrize("frame", list(_DECODE_FRAMES))
+def test_decode_parity_with_the_reference(frame, arena_mode):
+    """Same columns, errors and special as the decode it replaced, and a
+    histogram equal to numpy's count, whatever the arena does."""
+    from gubernator_tpu.ops.reqcols import ColumnArena
+
+    build = _DECODE_FRAMES[frame]
+    if build is None:
+        data = _req_bytes(_seeded(7)())[:-3]
+    else:
+        data = _req_bytes(build())
+    arena = None
+    if arena_mode != "absent":
+        arena = ColumnArena(4096, slabs=2)
+    held = []
+    if arena_mode == "exhausted":
+        held = [arena.lease(), arena.lease()]
+        assert arena.lease() is None
+    in_use = arena.in_use() if arena is not None else 0
+    want = _reference_parse_req(data)
+    got = fastwire.parse_req(data, arena)
+    if want is None:
+        assert got is None
+        assert arena is None or arena.in_use() == in_use
+        return
+    cols, errors, special = got
+    ref_cols, ref_errors, ref_special, ref_hist = want
+    assert errors == ref_errors
+    assert special is ref_special
+    n = len(cols)
+    if not ref_cols:
+        assert n == 0 and cols.lease is None
+        assert arena is None or arena.in_use() == in_use
+        return
+    leased = arena_mode == "leased" and n <= 4096
+    assert (cols.lease is not None) is leased
+    assert bytes(cols.key_blob) == ref_cols[0]
+    for name, ref in zip(
+            ("key_offsets", "hits", "limit", "duration", "algorithm",
+             "behavior", "created_at", "burst", "name_len"), ref_cols[1:]):
+        np.testing.assert_array_equal(getattr(cols, name), ref, err_msg=name)
+    assert list(cols.algo_hist) == ref_hist.tolist()
+    if arena is not None and not leased and arena_mode == "leased":
+        # too wide for the slab: counted as the size miss it is
+        assert arena.metric_misses == 1 and arena.metric_leases == 0
+    cols.release()
+    for lease in held:
+        lease.release()
+    assert arena is None or arena.in_use() == 0
+
+
+def _wide_mat(rng, n, width, off):
+    """A column slice of a wider matrix, as the tick loop hands out."""
+    whole = rng.integers(-(2 ** 62), 2 ** 62, (5, width))
+    whole[0] = rng.integers(0, 2, width)
+    whole[4] = rng.integers(0, 2, width)
+    whole[:, ::7] = 0  # proto3 omits zeros
+    return whole[:, off : off + n]
+
+
+@pytest.mark.parametrize("shape", [
+    "whole", "column_offset", "four_10_byte_varints", "int32", "transposed",
+])
+def test_encode_parity_and_over_limit(shape):
+    """Bytes identical to the numpy encoder (itself proven against
+    protobuf above) and the over-limit count beside them, from the
+    matrix where it lies or from a copy of any other layout."""
+    from gubernator_tpu.ops.engine import masked_over_limit
+    from gubernator_tpu.transport.wire import encode_get_rate_limits_resp
+
+    rng = np.random.default_rng(31)
+    if shape == "whole":
+        mat = _wide_mat(rng, 1000, 1000, 0)
+    elif shape == "column_offset":
+        mat = _wide_mat(rng, 1000, 4096, 1861)
+    elif shape == "four_10_byte_varints":
+        mat = np.full((5, 300), -1, np.int64)[:, 17:217]
+    elif shape == "int32":
+        mat = rng.integers(0, 2 ** 31, (5, 64)).astype(np.int32)
+    else:
+        mat = np.ascontiguousarray(_wide_mat(rng, 64, 64, 0).T).T
+        assert mat.strides[1] != 8
+    out, over = fastwire.encode_resp(mat)
+    assert out == encode_get_rate_limits_resp(mat)
+    assert over == masked_over_limit(mat, {}) == int(mat[4].sum())
+    back = pb.GetRateLimitsResp.FromString(out)
+    assert len(back.responses) == mat.shape[1]
+    assert back.responses[5].remaining == int(mat[2, 5])
+
+
+class _CountingLib:
+    """Stands in for the codec library: every native entry counts."""
+
+    def __init__(self, lib):
+        self._lib = lib
+        self.calls = []
+
+    def __getattr__(self, name):
+        fn = getattr(self._lib, name)
+
+        def counted(*args):
+            self.calls.append(name)
+            return fn(*args)
+
+        return counted
+
+
+def test_one_native_crossing_each_way(monkeypatch):
+    """A plain call decodes in one native call and encodes in one, with
+    nothing converted by ``numpy.ctypeslib`` on the way."""
+    from gubernator_tpu.ops.reqcols import ColumnArena
+
+    def refuse(cls, obj):
+        raise AssertionError("numpy.ctypeslib from_param on the serving path")
+
+    lib = _CountingLib(fastwire.load())
+    monkeypatch.setattr(fastwire, "_lib", lib)
+    monkeypatch.setattr(np.ctypeslib._ndptr, "from_param", classmethod(refuse))
+    arena = ColumnArena(4096, slabs=2)
+    data = _req_bytes([
+        pb.RateLimitReq(name="svc", unique_key=f"k{i}", hits=1, limit=100,
+                        duration=60_000, algorithm=i % 5)
+        for i in range(1000)
+    ])
+    for use in (arena, None):
+        lib.calls.clear()
+        cols, errors, special = fastwire.parse_req(data, use)
+        assert not errors and not special and len(cols) == 1000
+        assert list(cols.algo_hist) == [200] * 5
+        # no arena: one more call, to count before it allocates
+        assert lib.calls == (
+            ["guber_decode_req"] if use is not None
+            else ["guber_wire_count", "guber_decode_req"])
+    lib.calls.clear()
+    mat = _wide_mat(np.random.default_rng(3), 1000, 4096, 24)
+    out, over = fastwire.encode_resp(mat)
+    assert lib.calls == ["guber_encode_resp_mat"]
+    assert len(pb.GetRateLimitsResp.FromString(out).responses) == 1000

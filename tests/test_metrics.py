@@ -103,6 +103,72 @@ async def test_service_request_populates_catalog_families():
         await d.close()
 
 
+async def _edge_traffic(native: bool, monkeypatch):
+    """Five calls of mixed algorithms over the gRPC edge, the last with
+    an empty key; returns the instance and its metrics after them."""
+    from gubernator_tpu.config import BehaviorConfig, Config
+    from gubernator_tpu.transport import fastwire
+    from gubernator_tpu.transport.daemon import DaemonClient, spawn_daemon
+    from gubernator_tpu.types import RateLimitRequest
+
+    if not native:
+        monkeypatch.setattr(fastwire, "load", lambda: None)
+    conf = DaemonConfig(
+        grpc_listen_address="127.0.0.1:0",
+        http_listen_address="",
+        peer_discovery_type="none",
+    )
+    conf.config = Config(behaviors=BehaviorConfig(), cache_size=256)
+    d = await spawn_daemon(conf)
+    try:
+        client = DaemonClient(d.advertise_address)
+        plain = [
+            RateLimitRequest(name="svc", unique_key=f"k{i}", hits=1,
+                             limit=2, duration=60_000, algorithm=i % 5)
+            for i in range(10)
+        ]
+        shares = []
+        for _ in range(4):  # limit 2: the last two calls are over it
+            await client.get_rate_limits(plain)
+            shares.append(d.instance.metric_edge_native_calls
+                          / d.instance.metric_edge_calls)
+        out = await client.get_rate_limits(
+            plain[:3] + [RateLimitRequest(name="svc", unique_key="", hits=1,
+                                          limit=2, duration=60_000)])
+        assert out[3].error and not out[0].error
+        await client.close()
+        return d.instance, d.metrics, shares
+    finally:
+        await d.close()
+
+
+async def test_edge_native_share_and_equal_totals_on_both_paths(monkeypatch):
+    """The two-crossing edge answers every plain call (share 1.0) and
+    none with a per-item error; the algorithm and over-limit families
+    read what the protobuf fallback reads for the same traffic."""
+    totals = {}
+    for native in (True, False):
+        inst, m, shares = await _edge_traffic(native, monkeypatch)
+        assert inst.metric_edge_calls == 5
+        assert shares == [1.0 if native else 0.0] * 4
+        assert inst.metric_edge_native_calls == (4 if native else 0)
+        assert m.sample("gubernator_tpu_edge_calls_total",
+                        {"path": "native"}) == inst.metric_edge_native_calls
+        assert m.sample("gubernator_tpu_edge_calls_total",
+                        {"path": "fallback"}) == 5 - inst.metric_edge_native_calls
+        totals[native] = {
+            a: m.sample("gubernator_tpu_algorithm_requests_total",
+                        {"algorithm": a})
+            for a in ("token_bucket", "leaky_bucket", "sliding_window",
+                      "gcra", "concurrency")
+        }
+        totals[native]["over"] = m.sample("gubernator_over_limit_counter_total")
+        totals[native]["local"] = m.sample(
+            "gubernator_getratelimit_counter_total", {"calltype": "local"})
+    assert totals[True] == totals[False]
+    assert totals[True]["over"] > 0 and totals[True]["token_bucket"] >= 8
+
+
 async def test_daemon_exposes_flag_collectors():
     """GUBER_METRIC_FLAGS surfaces through the daemon's /metrics page."""
     import aiohttp
