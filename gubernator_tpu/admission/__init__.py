@@ -53,8 +53,8 @@ SHED_POLICIES = (POLICY_FAIL_OPEN, POLICY_FAIL_CLOSED)
 
 # Retriable shed messages: transported as per-item errors so callers can
 # distinguish "shed, retry elsewhere / with a fresh budget" from a real
-# rate-limit verdict.  Kept as prefix constants so tests and the bench
-# rung can classify responses without string-matching free text.
+# rate-limit verdict.  Kept as prefix constants so callers and tests
+# can classify responses without string-matching free text.
 SHED_EXPIRED_MSG = (
     "request shed: deadline expired before processing; retry with a "
     "fresh deadline"

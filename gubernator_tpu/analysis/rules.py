@@ -83,10 +83,10 @@ def functions(tree: ast.AST):
 # The per-tick serving path (dispatch threads: TickLoop._run/_flush,
 # TickEngine submit/_build_cols, the mesh twin) must queue device work
 # and NEVER materialize it — per-request D2H is the exact regression the
-# fused-tick architecture exists to avoid (BASELINE.md; bench gates the
-# dispatch counts, this rule gates the source).  Functions opt in with
-# @hot_path (gubernator_tpu/utils/hotpath.py); the decorator is the
-# documented contract, the rule is its enforcement.
+# fused-tick architecture exists to avoid (BASELINE.md; the one-dispatch
+# tests of tier-1 hold the counts, this rule gates the source).
+# Functions opt in with @hot_path (gubernator_tpu/utils/hotpath.py);
+# the decorator is the documented contract, the rule is its enforcement.
 
 _G001_CALLS = {
     "jax.device_get": "jax.device_get",

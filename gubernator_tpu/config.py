@@ -124,8 +124,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_MEMBERLIST_ADDRESS": "member-list discovery: bind address",
     "GUBER_MEMBERLIST_ADVERTISE_ADDRESS": "member-list: advertise address",
     "GUBER_MEMBERLIST_KNOWN_NODES": "member-list: seed nodes (comma list)",
-    "GUBER_MESH_LOCAL_WIDTH": "DEPRECATED routed-path width (warns; no-op)",
-    "GUBER_MESH_ROUTING": "sharded-table key routing: auto/device",
     "GUBER_METRIC_FLAGS": "optional collectors: os,golang",
     "GUBER_PEER_DISCOVERY_TYPE": "discovery pool: member-list/etcd/dns/k8s/none",
     "GUBER_PEER_PICKER": "peer picker implementation",
@@ -164,8 +162,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_TLS_KEY": "TLS server key file",
     "GUBER_TLS_MIN_VERSION": "minimum TLS version",
     "GUBER_TPU_BG_RECLAIM": "background reclaim: auto/on/off",
-    "GUBER_TPU_DMA_RING": "row-kernel DMA ring slots (pow2)",
-    "GUBER_TPU_DMA_UNROLL": "row-kernel DMA issue unroll (pow2)",
     "GUBER_TPU_FUSED_TICK": "force fused Pallas tick on/off (default: auto)",
     "GUBER_TPU_GLOBAL_MESH_CAPACITY": "GLOBAL mesh slot capacity",
     "GUBER_TPU_GLOBAL_MESH_NODE": "this node's mesh index (-1 = auto)",
@@ -173,7 +169,6 @@ ENV_REGISTRY: Dict[str, str] = {
     "GUBER_TPU_MAX_BATCH": "request columns per device tick",
     "GUBER_TPU_MESH_SHARDS": "table shards on the device mesh",
     "GUBER_TPU_PLATFORM": "jax platform; the CPU must be named, never a fallback",
-    "GUBER_TPU_SORTED32": "0 = x64 oracle tick for duplicate batches",
     "GUBER_TPU_TABLE_LAYOUT": "bucket-table layout: auto/columns/row",
 }
 
@@ -265,16 +260,6 @@ class Config:
     # --- TPU engine knobs (new surface; no reference analog) ---
     tpu_max_batch: int = 4096        # request columns per device tick
     tpu_mesh_shards: int = 0         # 0 = single-chip TickEngine; N = mesh
-    # Sharded-table key routing (parallel/mesh_engine.py): "device" (the
-    # "auto" default) ships one flat slot-sorted batch plus ragged
-    # extent offsets and each shard walks only its own extent on
-    # device.  The legacy "host" blocked packer is retired (the ragged
-    # path has no per-shard width to overflow).  GUBER_MESH_ROUTING
-    mesh_routing: str = "auto"
-    # DEPRECATED: per-shard lanes of the retired device-routed local
-    # block.  The ragged dispatch has no width knob; a non-zero value
-    # only emits a one-time deprecation warning.  GUBER_MESH_LOCAL_WIDTH
-    mesh_local_width: int = 0
     tpu_platform: str = ""           # force jax platform ("cpu" for tests)
     # Bucket-table storage: "auto" picks the Pallas row layout on TPU for
     # tables it fits (ops/rowtable.py), "columns"/"row" force one.
@@ -621,10 +606,8 @@ def setup_daemon_config(
     # Re-apply the compile-cache knob: a config file loads into the
     # environment after the import-time default was chosen.
     from gubernator_tpu import configure_compile_cache
-    from gubernator_tpu.ops.rowtable import refresh_dma_tuning
 
     configure_compile_cache(env)
-    refresh_dma_tuning(env)
     r = EnvReader(env)
 
     behaviors = BehaviorConfig(
@@ -709,8 +692,6 @@ def setup_daemon_config(
         tpu_table_layout=r.str_("GUBER_TPU_TABLE_LAYOUT", "auto"),
         tpu_bg_reclaim=r.str_("GUBER_TPU_BG_RECLAIM", "auto"),
         tpu_mesh_shards=r.int_("GUBER_TPU_MESH_SHARDS", 0),
-        mesh_routing=r.str_("GUBER_MESH_ROUTING", "auto"),
-        mesh_local_width=r.int_("GUBER_MESH_LOCAL_WIDTH", 0),
         tpu_platform=r.str_("GUBER_TPU_PLATFORM"),
         tpu_global_mesh_nodes=r.int_("GUBER_TPU_GLOBAL_MESH_NODES", 0),
         tpu_global_mesh_node=r.int_("GUBER_TPU_GLOBAL_MESH_NODE", -1),
@@ -724,16 +705,6 @@ def setup_daemon_config(
         raise ValueError(
             f"GUBER_TPU_BG_RECLAIM must be auto, on, or off; "
             f"got {conf.tpu_bg_reclaim!r}"
-        )
-    if conf.mesh_routing not in ("auto", "device"):
-        raise ValueError(
-            f"GUBER_MESH_ROUTING must be auto or device (the legacy "
-            f"'host' blocked path is retired); got {conf.mesh_routing!r}"
-        )
-    if conf.mesh_local_width < 0:
-        raise ValueError(
-            f"GUBER_MESH_LOCAL_WIDTH must be >= 0; "
-            f"got {conf.mesh_local_width}"
         )
     if conf.cold_cache_size < 0:
         raise ValueError(

@@ -1,14 +1,13 @@
 """Multi-process streaming edge: shared-memory slab ingest plane.
 
-The device ticks ~50M decisions/s but wire decode/encode is Python under
-the GIL — single-threaded, it caps *served* throughput two orders of
-magnitude below the kernel (docs/tpu-performance.md).  This package is
-the scaling seam: N edge worker **processes** decode fastwire streams
-into columnar REQ32 slabs living in ``multiprocessing.shared_memory``;
-the device-owner process drains the published slab windows straight into
-the existing tick loop (the flat slot-sorted matrix already supports
-multi-producer concat) and fans the response matrices back through
-per-worker shm response rings.  No pickling, no sockets between decode
+Wire decode/encode on one event-loop thread caps *served* throughput
+far below what the device ticks (PERF.md, "Where the time goes").  This
+package is the scaling seam: N edge worker **processes** decode fastwire
+streams into columnar REQ32 slabs living in
+``multiprocessing.shared_memory``; the device-owner process drains the
+published slab windows straight into the existing tick loop (the flat
+slot-sorted matrix already supports multi-producer concat) and fans the
+response matrices back through per-worker shm response rings.  No pickling, no sockets between decode
 and device — the only cross-process traffic is the slab handoff.
 
 Layout and lifecycle live in :mod:`gubernator_tpu.edge.shmring`; the

@@ -68,7 +68,7 @@ class EdgeConfig:
     slabs: int = 8            # request slabs per worker (GUBER_EDGE_SHM_SLABS)
     ring_depth: int = 16      # response slots per worker (GUBER_EDGE_RING_DEPTH)
     max_batch: int = 1000
-    mode: str = "socket"      # "socket" (daemon ingest) | "drive" (bench/chaos)
+    mode: str = "socket"      # "socket" (daemon ingest) | "drive" (tests)
     socket_dir: Optional[str] = None
     drive: dict = field(default_factory=dict)
     timeout_s: float = 30.0
@@ -404,7 +404,8 @@ class EdgePlane:
         return np.array(self.workers[wid].seg.counters)
 
     def totals(self) -> Dict[str, float]:
-        """Aggregate worker counters (bench invariants, /debug/state)."""
+        """Aggregate worker counters (/debug/state, and the exact-work
+        invariants of tests/test_edge.py)."""
         agg = np.zeros(N_COUNTERS, np.float64)
         for w in self.workers:
             if hasattr(w.seg, "counters"):
@@ -444,7 +445,7 @@ class EdgePlane:
             "totals": self.totals(),
         }
 
-    # -- drive-mode helpers (bench / chaos) ------------------------------
+    # -- drive-mode helpers (tests/test_edge.py, test_chaos.py) ----------
     def wait_ready(self, timeout: float = 30.0) -> bool:
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:

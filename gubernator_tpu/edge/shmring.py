@@ -52,7 +52,7 @@ CTRL_GENERATION = 5
 CTRL_STOP = 6
 CTRL_WORKER_PID = 7
 CTRL_READY = 8    # worker: attached + warmed, waiting for GO
-CTRL_GO = 9       # owner: start the drive clock (bench start barrier)
+CTRL_GO = 9       # owner: start the drive clock (drive-mode start barrier)
 CTRL_REQ_AT = 10  # respawn handoff: where the next publish must land
 CTRL_RESP_AT = 11  # respawn handoff: where the next response will land
 CTRL_WORDS = 16

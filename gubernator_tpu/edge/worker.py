@@ -13,8 +13,8 @@ Two modes share the decode/publish/ack core:
   serialized ``GetRateLimitsReq``; responses mirror the framing with
   ``GetRateLimitsResp`` bytes).  Many clients per worker; responses are
   routed back by publish order.
-* ``drive`` — a self-generating loopback load source for bench.py's
-  ``serve_multiproc`` rung and the chaos tests: pre-encodes frames once,
+* ``drive`` — a self-generating loopback load source for
+  tests/test_edge.py and the chaos tests: pre-encodes frames once,
   then decode→publish→ack as fast as the rings allow, accounting every
   window through the shm counter block so the owner can check the
   exact-work invariants (parity / double-serve / dropped-ack) without

@@ -120,8 +120,8 @@ class LeaseManager:
     """Mints, renews, reconciles, and revokes quota leases.
 
     ``tick_loop=None`` runs decisions synchronously through
-    ``engine.process`` (grant_local/sync_local — benches and
-    ManualClock tests); with a tick loop, grants/syncs ride the
+    ``engine.process`` (grant_local/sync_local — the ManualClock
+    tests); with a tick loop, grants/syncs ride the
     ordinary admission queue (syncs in the peer class).
     """
 
@@ -189,7 +189,7 @@ class LeaseManager:
         return self._commit_syncs(plan, responses)
 
     # ------------------------------------------------------------------
-    # Synchronous surface (engine-only: benches, virtual-clock tests)
+    # Synchronous surface (engine-only: virtual-clock tests)
     # ------------------------------------------------------------------
     def grant_local(
         self, specs: Sequence[LeaseSpec], now_ms: Optional[int] = None

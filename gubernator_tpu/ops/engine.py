@@ -398,7 +398,7 @@ def sort_packed_by_slot(m32: np.ndarray, n: int, capacity: int):
 
 class StagingRing:
     """Reusable host staging slabs for async H2D request uploads — the
-    double-buffered pipeline contract (docs/tpu-performance.md round 6)
+    double-buffered pipeline contract (docs/architecture.md)
     factored out of ``TickEngine`` so the sharded mesh engine shares one
     implementation: a slab recycles only once the tick handle that
     consumed it has resolved (until then jax may still read the host
@@ -2311,10 +2311,8 @@ class TickEngine:
         # Mixed/ineligible duplicate batches run the parts-native chained
         # unit-round program (tick32.make_sorted_tick32_rows_fn): exact
         # per-slot order, ceil(units/8) gather+scatter rounds, no XLA
-        # 64-bit emulation.  GUBER_TPU_SORTED32=0 falls back to the x64
-        # oracle program (engine.make_tick_fn), which stays the parity
-        # reference in tests.  Registry read, once per engine — never
-        # per tick.
+        # 64-bit emulation.  The x64 program (engine.make_tick_fn) stays
+        # the parity reference in tests and the mesh engine's walker.
         from gubernator_tpu.config import env_knob
 
         from gubernator_tpu.ops.tick32 import (
@@ -2323,12 +2321,7 @@ class TickEngine:
             jitted_tick32,
         )
 
-        if env_knob("GUBER_TPU_SORTED32") == "0":
-            self._tick = _jitted_tick(self.capacity, self.layout,
-                                      sorted_input=True, compact_resp=True,
-                                      compact_req=True)
-        else:
-            self._tick = jitted_sorted_tick32(self.capacity, self.layout)
+        self._tick = jitted_sorted_tick32(self.capacity, self.layout)
 
         # Note on request-buffer donation: the (19, B) request matrix
         # has no same-shape program output, and XLA's input-output
@@ -2358,7 +2351,7 @@ class TickEngine:
         self._install = _jitted_install(self.layout)
         self._restore = _jitted_restore(self.layout)
         self._readback = _jitted_readback(self.layout)
-        # Double-buffered H2D staging (docs/tpu-performance.md): the
+        # Double-buffered H2D staging (docs/architecture.md): the
         # packed request matrix for each window is built in a reusable
         # host slab and uploaded with an *async* host→device copy, so window
         # N+1's transfer rides the link while window N's tick still
@@ -2379,9 +2372,9 @@ class TickEngine:
         )
         # H2D overlap telemetry: a window counts as overlapped when its
         # upload was dispatched while at least one earlier window was
-        # still unresolved — the pipelined steady state.  The bench
-        # ladder exports overlapped/windows as h2d_overlap_ratio and
-        # the CI gate holds it (scripts/check_bench_regression.py).
+        # still unresolved — the pipelined steady state.  /debug/state
+        # and the gubernator_tpu_h2d_overlap_ratio gauge read
+        # overlapped/windows (h2d_overlap_ratio below).
         self._inflight = 0
         self.metric_h2d_windows = 0
         self.metric_h2d_overlapped = 0
@@ -2451,11 +2444,11 @@ class TickEngine:
         self.metric_evict_reclaims = 0
         self.metric_shed_requests = 0
         # SSD-tier exact-work telemetry: lookups counts take_batch
-        # calls (≤ 1 per tick that still had misses after the cold hop
-        # — their ratio is the bench's ssd_promote_batches_per_miss_tick
-        # gate), and tick_path_reads is the structural proof that no
+        # calls (exactly 1 per tick that still had misses after the
+        # cold hop), and tick_path_reads is the structural proof that no
         # SSD read ever lands inside the tick-dispatch block (must stay
-        # 0; scripts/check_bench_regression.py pins it).
+        # 0).  tests/test_ssd.py::test_three_tier_churn_keeps_consumed_budget
+        # holds both.
         self.metric_ssd_hits = 0
         self.metric_ssd_lookups = 0
         self.metric_ssd_miss_ticks = 0
@@ -2507,9 +2500,8 @@ class TickEngine:
                 # The sequential chained-unit program only serves
                 # adversarial duplicate shapes; like the layered warmup
                 # below, eager-compiling it is a serving chip's live-
-                # deadline concern — on the CPU backend (tests, the fast
-                # CI gate) most engines never tick it and lazy is the
-                # right trade.
+                # deadline concern — on the CPU backend (the tests) most
+                # engines never tick it and lazy is the right trade.
                 self.state, resp = self._tick(
                     self.state, jnp.asarray(m), jnp.int64(0)
                 )
@@ -2547,8 +2539,8 @@ class TickEngine:
             # 50k-slot tables and rarely see mixed-duplicate traffic —
             # their first such batch compiles then).  TPU-only: the
             # live-deadline concern is a serving chip's; on the CPU
-            # backend (tests, the fast CI gate) the same compile costs
-            # minutes per engine and lazy is the right trade.
+            # backend (the tests) the same compile costs minutes per
+            # engine and lazy is the right trade.
             from gubernator_tpu.ops.tick32 import jitted_layered_pipeline
 
             w = self._widths[0]
@@ -3055,9 +3047,9 @@ class TickEngine:
 
         With an SSD tier attached, keys that also miss cold take one
         more hop — ONE batched ``take_batch`` against the slab store per
-        tick (never per key; the bench gates the ratio) — and its hits
-        merge into the same scatter, so the promote dispatch count is
-        unchanged by the third tier.  The SSD read seconds are recorded
+        tick (never per key; tests/test_ssd.py holds the ratio) — and its
+        hits merge into the same scatter, so the promote dispatch count
+        is unchanged by the third tier.  The SSD read seconds are recorded
         as the flight recorder's "ssd" stage and subtracted from "pack"
         (which brackets all of _build_cols), keeping the tick/pack
         stages clean of SSD I/O by construction."""
@@ -3201,7 +3193,7 @@ class TickEngine:
             # the tick-dispatch block below runs would land in this
             # delta.  _build_cols (the only legitimate lookup site) has
             # already returned, so the counter stays 0 by construction —
-            # and the bench gate keeps it that way.
+            # and tests/test_ssd.py keeps it that way.
             ssd_reads0 = (
                 self.ssd.metric_lookup_calls if self.ssd is not None else 0
             )
@@ -3827,5 +3819,5 @@ class TickEngine:
         an earlier window's tick was still unresolved — 0.0 for fully
         serial submission, →1.0 when the pipeline keeps the H2D of
         window N+1 riding under window N's device tick (the
-        double-buffered steady state the bench ladder gates)."""
+        double-buffered steady state)."""
         return self.metric_h2d_overlapped / max(1, self.metric_h2d_windows)

@@ -1,13 +1,12 @@
 """The fused tick: gather-DMA → in-register transition → scatter-DMA in
 ONE Pallas kernel.
 
-Round 3's tick was three serialized passes over HBM (row gather ~750 us,
-XLA middle ~690 us of extracts + emulated-64-bit transition, scatter
-~410 us at 32K: docs/tpu-performance.md).  This kernel streams the batch
-through VMEM in double-buffered chunks so the transition and the write
-stream hide under the read stream, which is the hardware floor (~23 ns
-per random 512 B row read on v5e, flat across ring depth / unroll /
-semaphore-array count — scripts/gather_microbench*.py):
+The unfused tick is three serialized passes over HBM (row gather, an
+XLA middle of extracts + emulated-64-bit transition, scatter).  This
+kernel streams the batch through VMEM in double-buffered chunks so the
+transition and the write stream hide under the read stream, which is the
+hardware floor (random 512 B row reads are bound by the descriptor
+rate, not by bandwidth; PERF.md has what has been measured):
 
   reads(chunk c+2) ──┐ issued while
   compute(chunk c)   ├─ writes(chunk c-1..c) drain

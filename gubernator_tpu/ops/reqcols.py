@@ -307,7 +307,8 @@ class ColumnArena:
     plain allocation — the arena is a fast path, never a correctness
     constraint.  ``slabs`` should cover
     the tick pipeline depth plus decode concurrency
-    (GUBER_INGEST_ARENA_SLABS; see docs/tpu-performance.md).
+    (GUBER_INGEST_ARENA_SLABS; see docs/architecture.md, "The serving
+    edge").
     """
 
     # Key-blob staging bytes per request row.  parse_req needs

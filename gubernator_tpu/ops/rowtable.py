@@ -43,8 +43,6 @@ semantically identical) so the row engine is testable on the CPU mesh.
 from __future__ import annotations
 
 import functools
-import logging
-import os
 from typing import NamedTuple
 
 import gubernator_tpu.jaxinit  # noqa: F401  (x64 + compile cache before jax use)
@@ -63,60 +61,12 @@ from gubernator_tpu.ops.buckets import (
 )
 
 ROW_W = 128     # int32 words per row (Mosaic lane-alignment minimum)
-# DMA pipeline shape (env-overridable for per-platform tuning): ring
-# depth bounds outstanding copies — gathers are HBM-read-latency bound,
-# so deeper rings hide more latency — and the unroll sets how many
-# copies each scalar-loop step issues (the scalar loop is the issue-rate
-# limiter).
-def _env_pow2(env, name: str, default: int, lo: int, hi: int) -> int:
-    """Clamped power-of-two env knob: a malformed or out-of-range value
-    falls back to the default with a warning (a 0-deep ring would
-    deadlock the first tick waiting on DMAs that were never started)."""
-    raw = env.get(name, "")
-    if raw == "":
-        return default
-    try:
-        v = int(raw)
-    except ValueError:
-        v = -1
-    if v < lo or v > hi or v & (v - 1):
-        logging.getLogger("gubernator_tpu").warning(
-            "%s=%r is not a power of two in [%d, %d]; using %d",
-            name, raw, lo, hi, default,
-        )
-        return default
-    return v
-
-
-def refresh_dma_tuning(environ=None) -> None:
-    """(Re-)read the DMA pipeline knobs.  Runs at import AND again from
-    ``setup_daemon_config`` so the knobs also work from a ``-config``
-    file, which loads into the env copy after import (the
-    configure_compile_cache pattern, gubernator_tpu/__init__.py).
-
-    The kernels bake DMA_RING/DMA_UNROLL in at trace time and the jitted
-    wrappers are cached by (capacity, layout) only — once any kernel has
-    been traced, a change here could not take effect for those programs
-    and two engines in one process would silently disagree.  So a
-    post-trace change is *refused* (loudly): refresh must precede the
-    first engine construction."""
-    global DMA_RING, DMA_UNROLL
-    env = os.environ if environ is None else environ
-    ring = _env_pow2(env, "GUBER_TPU_DMA_RING", 32, 8, 256)
-    unroll = _env_pow2(env, "GUBER_TPU_DMA_UNROLL", 4, 1, 16)
-    if _KERNELS_TRACED and (ring, unroll) != (DMA_RING, DMA_UNROLL):
-        logging.getLogger("gubernator_tpu").warning(
-            "DMA tuning change (ring %d->%d, unroll %d->%d) ignored: row "
-            "kernels were already traced with the old values; set "
-            "GUBER_TPU_DMA_* before the first engine is constructed",
-            DMA_RING, ring, DMA_UNROLL, unroll,
-        )
-        return
-    DMA_RING, DMA_UNROLL = ring, unroll
-
-
-_KERNELS_TRACED = False
-refresh_dma_tuning()
+# DMA pipeline shape: ring depth bounds outstanding copies — gathers
+# are HBM-read-latency bound, so deeper rings hide more latency — and
+# the unroll sets how many copies each scalar-loop step issues (the
+# scalar loop is the issue-rate limiter).  Powers of two.
+DMA_RING = 32
+DMA_UNROLL = 4
 
 # The kernels stage the whole (B, ROW_W) batch block in VMEM; Mosaic's
 # default scoped-vmem budget rejects a 64k-row tick (gather out-block +
@@ -227,8 +177,6 @@ def scatter_rows(table: jnp.ndarray, slots: jnp.ndarray,
     rows, install/restore/evict dedup'd slots); duplicates of the guard
     row ``capacity`` are harmless (its content is never read as data).
     """
-    global _KERNELS_TRACED
-    _KERNELS_TRACED = True
     b, w = rows.shape
     cap1 = table.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -254,8 +202,6 @@ def scatter_rows(table: jnp.ndarray, slots: jnp.ndarray,
 
 def gather_rows(table: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
     """Read ``table[slots[j]]`` into a (B, ROW_W) matrix (row DMAs)."""
-    global _KERNELS_TRACED
-    _KERNELS_TRACED = True
     b = slots.shape[0]
     w = table.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(

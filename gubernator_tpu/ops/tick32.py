@@ -4,7 +4,7 @@ responses out, no 64-bit ops anywhere in the hot path.
 This is the unique-slot fast program: the host sorts every batch by slot
 (engine._build_cols) and knows whether duplicates exist; batches with at
 most one request per slot — the overwhelming production shape and the
-bench worst case — dispatch here, duplicate-bearing batches take the
+memory-traffic worst case — dispatch here, duplicate-bearing batches take the
 merge-capable program (engine.make_tick_fn).  Keeping the two as
 separate host-dispatched programs (instead of a traced lax.cond) lets
 this one stay pure int32/float32, which is what allows it to run inside
@@ -140,7 +140,7 @@ def make_tick32_fn(capacity: int, layout: str = "columns",
     matrix; rows past the live count are unspecified.
 
     This single-program form is for callers that need one traceable
-    function (bench chains it inside a fori_loop on TPU).  Engines should
+    function (tests/test_fusedtick.py jits it whole).  Engines should
     use :func:`jitted_tick32`, which splits the response stack into a
     second program — see make_tick32_rows_fn for why.
     """

@@ -902,9 +902,9 @@ class MeshGlobalEngine:
         self.metric_dense_fallbacks = 0
         # Mesh programs launched by reconcile steps: 1 per fused sparse
         # or dense step, 2 when an overflowing step runs the dense
-        # fallback after the fused probe.  dispatches/reconciles near
-        # 1.0 is the fusion's observable; the bench ladder exports it
-        # and scripts/check_bench_regression.py gates on it.
+        # fallback after the fused probe.  dispatches/reconciles of
+        # exactly 1.0 is the fusion's observable
+        # (tests/test_global_mesh.py::test_reconcile_dispatch_counter).
         self.metric_reconcile_dispatches = 0
         self._evict = jax.jit(
             make_global_evict_fn(self.mesh), donate_argnums=(0, 1, 2)
@@ -1175,7 +1175,7 @@ class MeshGlobalEngine:
     def cache_size(self) -> int:
         return len(self.slots)
 
-    # Introspection used by tests/benchmarks: per-node view of one key.
+    # Introspection used by the tests: per-node view of one key.
     def peek(self, key: str) -> Optional[List[dict]]:
         slot = self.slots.get(key)
         if slot is None:

@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import threading
 import time
-import warnings
 import zlib
 from typing import Dict, List, Optional, Sequence
 
@@ -115,27 +114,6 @@ def make_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """1-D device mesh over the 'shard' axis (the slot-partition axis)."""
     devices = list(devices if devices is not None else jax.devices())
     return Mesh(np.array(devices), ("shard",))
-
-
-_LOCAL_WIDTH_WARNED = False
-
-
-def _warn_local_width_deprecated() -> None:
-    """One-time (per process) deprecation warning for the dead
-    ``local_width`` / ``GUBER_MESH_LOCAL_WIDTH`` knob: the ragged
-    extent walk has no per-shard width to bound, so the value is
-    ignored.  The ENV_REGISTRY entry stays until removal (G004)."""
-    global _LOCAL_WIDTH_WARNED
-    if _LOCAL_WIDTH_WARNED:
-        return
-    _LOCAL_WIDTH_WARNED = True
-    warnings.warn(
-        "GUBER_MESH_LOCAL_WIDTH / MeshTickEngine(local_width=...) is "
-        "deprecated and ignored: the ragged tick dispatch walks each "
-        "shard's extent directly and has no per-shard width limit",
-        DeprecationWarning,
-        stacklevel=3,
-    )
 
 
 class ShardedOps:
@@ -429,11 +407,7 @@ class MeshTickEngine:
     the host resolve and the on-device extent walker
     (partition.RaggedExtents / ops.raggedtick).
 
-    Every tick ships the ragged flat wire format (module docstring);
-    ``routing`` survives as a knob accepting ``"auto"``/``"device"``
-    only — the legacy ``"host"`` blocked path is gone.  ``local_width``
-    is dead (the ragged walk has no per-shard width to bound): a
-    non-zero value warns once and is otherwise ignored.
+    Every tick ships the ragged flat wire format (module docstring).
     """
 
     def __init__(
@@ -443,8 +417,6 @@ class MeshTickEngine:
         max_batch: int = 1024,
         store=None,
         table_layout: str = "auto",
-        routing: str = "auto",
-        local_width: int = 0,
     ):
         from gubernator_tpu.config import env_knob
         from gubernator_tpu.ops.engine import make_slot_map
@@ -461,18 +433,10 @@ class MeshTickEngine:
             )
         self.max_batch = int(max_batch)
         self.store = store
-        if routing not in ("auto", "device"):
-            raise ValueError(
-                f"unknown mesh routing {routing!r} (the legacy 'host' "
-                "blocked path was removed; the ragged device dispatch "
-                "serves every window)")
-        self.routing = "device"
         # As-configured layout knob, kept verbatim so reshard() can
         # re-derive the auto choice (layout fit) for the new shard
         # count instead of freezing this build's resolution.
         self._table_layout_conf = table_layout
-        if int(local_width):
-            _warn_local_width_deprecated()
         self.layout = make_layout_choice(
             table_layout, self.local_capacity,
             self.mesh.devices.flat[0], self.max_batch,
@@ -540,9 +504,9 @@ class MeshTickEngine:
         if jax.default_backend() == "tpu":
             # Eager tick compiles are a serving chip's live-deadline
             # concern (see TickEngine._warmup): on the CPU backend
-            # (tests, the fast CI gate) each shard_map trace costs
-            # seconds per engine and most tests tick only one of the
-            # two programs — lazy is the right trade.
+            # (the tests) each shard_map trace costs seconds per engine
+            # and most tests tick only one of the two programs — lazy
+            # is the right trade.
             m = np.zeros((REQ32_ROWS, self.max_batch), np.int32)
             m[REQ32_INDEX["slot"]] = self.capacity
             offs = self.ragged.offsets(np.zeros(self.n_shards, np.int64))
@@ -1287,7 +1251,7 @@ class MeshTickEngine:
         # The ragged extent spec IS the new layout's dispatch geometry:
         # post-cutover windows derive their offsets against cap_to's
         # ownership from this object — nothing width-shaped survives to
-        # re-derive (the old routed path's local_width knob is dead).
+        # re-derive.
         return SimpleNamespace(
             mesh=mesh, n_shards=tr.n_to, local_capacity=tr.cap_to,
             capacity=tr.capacity_to, layout=layout,
@@ -1387,9 +1351,10 @@ class MeshTickEngine:
         double-serve; on zero shards after serving, a drop), and its
         global slot must derive back to the owning shard — the exact
         invariant the device router applies (``slot // local_capacity``).
-        Returns the number of keys violating any of these; the bench
-        mesh rungs export it as ``mesh_routing_parity_errors`` and CI
-        gates it at exactly 0."""
+        Returns the number of keys violating any of these: the reshard
+        coordinator's verify phase counts it, and
+        tests/test_mesh_engine.py::test_routing_parity_fuzz_vs_host_ring
+        holds it at exactly 0."""
         from gubernator_tpu.native import crc32_batch
 
         enc = [k.encode() for k in keys]

@@ -140,8 +140,8 @@ class RaggedExtents:
     The spec is layout-bearing state: ``MeshTickEngine`` swaps it
     atomically in ``_cutover`` alongside the mesh/ops/slotmaps, so a
     reshard recomputes every subsequent window's offsets against the
-    NEW ``cap_to``-derived ownership — there is no residual width knob
-    to re-derive (the old routed path's ``local_width``)."""
+    NEW ``cap_to``-derived ownership — there is no width to
+    re-derive."""
 
     n_shards: int
     local_capacity: int
@@ -169,8 +169,8 @@ class RaggedExtents:
 # Layout transitions (elastic resharding; docs/resharding.md).  THE one
 # n→m transition spec: both the on-device all-to-all re-layout program
 # and every host-side remap audit derive ownership from this dataclass,
-# so the engine, the bench verifier, and the unit tests can never drift
-# on where a live slot lands after a reshard.
+# so the engine, the coordinator's audit, and the unit tests can never
+# drift on where a live slot lands after a reshard.
 # ----------------------------------------------------------------------
 @dataclass(frozen=True)
 class LayoutTransition:

@@ -20,8 +20,9 @@ The coordinator sequences a drain-then-cutover protocol
 ``VERIFY``
     The post-cutover table is audited: every row live at relayout time
     is present exactly once (``reshard_state_loss`` /
-    ``reshard_double_served``, both gated at ABSOLUTE_ZERO by the
-    reshard_live bench rung) and the routed path agrees with the ring
+    ``reshard_double_served``, both held at zero on a live mesh by
+    tests/test_reshard.py::test_reshard_ragged_zipf_round_trip_zero_loss)
+    and the routed path agrees with the ring
     (``routing_parity_errors == 0``).
 
 Every failure mode lands in a defined state: peer death surfaces as an
@@ -89,7 +90,7 @@ class ReshardCoordinator:
     """Drives one transition at a time over an engine + tick loop.
 
     All hooks are optional so the coordinator composes with partial
-    stacks (tests, bench, single-chip engines):
+    stacks (tests, single-chip engines):
 
     * ``tick_loop`` — freeze/quiesce/unfreeze admission around the
       cutover; without one, the caller owns traffic exclusion.

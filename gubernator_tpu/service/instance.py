@@ -83,8 +83,6 @@ class InstanceConfig:
     replicas: int = 512
     tpu_max_batch: int = 4096
     tpu_mesh_shards: int = 0             # 0 = single-chip engine
-    mesh_routing: str = "auto"           # sharded key routing: auto/device
-    mesh_local_width: int = 0            # DEPRECATED (ragged path; warns)
     tpu_platform: str = ""               # force jax platform ("cpu" for tests)
     tpu_table_layout: str = "auto"       # bucket-table storage (engine.py)
     tpu_bg_reclaim: str = "auto"         # background reclamation (engine.py)
@@ -160,8 +158,6 @@ class InstanceConfig:
             replicas=conf.replicas,
             tpu_max_batch=conf.tpu_max_batch,
             tpu_mesh_shards=conf.tpu_mesh_shards,
-            mesh_routing=conf.mesh_routing,
-            mesh_local_width=conf.mesh_local_width,
             tpu_platform=conf.tpu_platform,
             tpu_table_layout=conf.tpu_table_layout,
             tpu_bg_reclaim=conf.tpu_bg_reclaim,
@@ -251,8 +247,6 @@ def _make_engine(conf: InstanceConfig):
             max_batch=conf.tpu_max_batch,
             store=conf.store,
             table_layout=conf.tpu_table_layout,
-            routing=conf.mesh_routing,
-            local_width=conf.mesh_local_width,
         )
     from gubernator_tpu.ops.engine import TickEngine
 
@@ -311,7 +305,7 @@ class V1Instance:
             batch_limit=window_limit,
             metrics=self.metrics,
         )
-        # Zero-copy ingest (docs/tpu-performance.md): the transport's
+        # Zero-copy ingest (docs/architecture.md): the transport's
         # wire→columns decode lands in these preallocated slabs instead
         # of fresh per-batch allocations; the tick loop releases each
         # slab once the engine has packed it.  Sized to the public API

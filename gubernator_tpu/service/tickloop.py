@@ -340,9 +340,10 @@ class TickLoop:
         col_parts: List = []
         col_items: List[tuple] = []
         for it in batch:
-            # Invariant counter for the overload_shed gate: an expired
-            # item reaching the pack stage means the partition above
-            # regressed.  Counted (and exported), never silently served.
+            # Invariant counter (tests/test_admission.py holds it at
+            # 0): an expired item reaching the pack stage means the
+            # partition above regressed.  Counted (and exported), never
+            # silently served.
             if it.expired(now):
                 self.metric_expired_served += it.n
             if it.kind == "cols":

@@ -1,15 +1,14 @@
 """Native wire codec bindings: serialized pb ⇄ columns with no message
 objects.
 
-The serving path's CPU cost is per-request Python object churn
-(~3-4.7 ms per 1000-item batch measured through protobuf message
-objects, bench.py service rung); the C++ codec
-(:file:`native/wirecodec.cc`) parses ``GetRateLimitsReq`` bytes straight
-into :class:`~gubernator_tpu.ops.reqcols.ReqColumns` and emits
-``GetRateLimitsResp`` bytes straight from the engine's (5, n) response
-matrix — tens of microseconds per batch.  Every entry point degrades
-gracefully: ``None`` (or the numpy fallback) when the shared library is
-unavailable or the input needs the object path.
+The serving path's CPU cost is per-request Python object churn through
+protobuf message objects (PERF.md, ``serve_cpu_us_per_decision``); the
+C++ codec (:file:`native/wirecodec.cc`) parses ``GetRateLimitsReq``
+bytes straight into :class:`~gubernator_tpu.ops.reqcols.ReqColumns` and
+emits ``GetRateLimitsResp`` bytes straight from the engine's (5, n)
+response matrix.  Every entry point degrades gracefully: ``None`` (or
+the numpy fallback) when the shared library is unavailable or the input
+needs the object path.
 
 Request-side semantics match :func:`transport.convert.columns_from_pb`
 exactly (empty-name/key per-item errors, metadata/GLOBAL → special,

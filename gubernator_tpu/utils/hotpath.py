@@ -9,8 +9,8 @@ primitives (``np.asarray`` on device values, ``.item()``,
 ``block_until_ready``, ``jax.device_get``, ``float()``/``bool()``
 scalar materialization) inside marked functions, because one per-tick
 host/device round trip is the exact regression the fused-tick
-architecture exists to avoid (BASELINE.md; the bench ladder gates the
-dispatch *counts*, G001 gates the *source*).
+architecture exists to avoid (BASELINE.md; the one-dispatch tests of
+tier-1 hold the dispatch *counts*, G001 gates the *source*).
 
 Syncs belong on the resolver side — ``TickHandle.result`` /
 ``resolve_ticks`` — where many windows amortize one D2H.  Nested

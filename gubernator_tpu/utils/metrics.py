@@ -371,7 +371,7 @@ class Metrics:
         # flat tick is the ONE serving format — each shard walks its
         # own extent of the slot-sorted batch, so there is no per-shard
         # width to overflow.  The overflow counter survives as a
-        # pinned-zero canary (check_bench_regression gates it at 0).
+        # pinned-zero canary (tests/test_mesh_engine.py holds it at 0).
         self.mesh_routed_windows = Counter(
             "gubernator_tpu_mesh_routed_windows",
             "Serving windows dispatched through the ragged flat tick "
@@ -575,7 +575,7 @@ class Metrics:
         )
         # Elastic live resharding (docs/resharding.md): transition
         # outcomes, the running transition's phase/size, verification
-        # counters gated at zero by the reshard_live bench rung, and the
+        # counters held at zero by tests/test_reshard.py, and the
         # transition wall time.
         self.reshard_transitions = Counter(
             "gubernator_tpu_reshard_transitions",
@@ -603,15 +603,14 @@ class Metrics:
         self.reshard_state_loss = Counter(
             "gubernator_tpu_reshard_state_loss",
             "Bucket rows live before a transition but missing from the "
-            "post-cutover table (verify phase). Must stay 0; gated at "
-            "ABSOLUTE_ZERO by the reshard_live bench rung.",
+            "post-cutover table (verify phase). Must stay 0.",
             registry=reg,
         )
         self.reshard_double_served = Counter(
             "gubernator_tpu_reshard_double_served",
             "Keys resident on more than one shard after a cutover "
             "(verify phase) — each is a potential double-serve. Must "
-            "stay 0; gated at ABSOLUTE_ZERO by the reshard_live rung.",
+            "stay 0.",
             registry=reg,
         )
         self.reshard_duration = Summary(
@@ -766,7 +765,7 @@ class Metrics:
             "gubernator_tpu_admission_expired_served",
             "Invariant violations: requests whose deadline had already "
             "expired at pack time but that reached the engine anyway. "
-            "Must stay 0; gated by the overload_shed bench rung.",
+            "Must stay 0.",
             registry=reg,
         )
 

@@ -281,7 +281,7 @@ def test_tickloop_sheds_expired_before_pack():
         assert all(r.error == SHED_EXPIRED_MSG for r in out)
         assert eng.batches == []  # never reached the device
         assert loop.metric_shed_admission["expired"] == 3
-        assert loop.metric_expired_served == 0  # the gated invariant
+        assert loop.metric_expired_served == 0  # the invariant
     finally:
         loop.close()
 

@@ -362,7 +362,7 @@ async def test_chaos_region_isolation_degrades_heals_zero_loss():
     healthy exchange first, then a full WAN partition (directional
     schedules — intra-region links stay up), bounded degraded serving
     on both sides, then heal.  After the heal both regions converge on
-    the union of all hits: ABSOLUTE_ZERO hit loss, no double-counts."""
+    the union of all hits: zero hit loss, no double-counts."""
     behaviors, resilience = fast_chaos_conf()
     inj = FaultInjector(seed=13)
     c = await Cluster.start(
@@ -410,6 +410,26 @@ async def test_chaos_region_isolation_degrades_heals_zero_loss():
         await drive(us_owner, 3)
         assert c.metric_value(
             ui, "gubernator_tpu_federation_degraded_answers_total") >= 1
+        # A contended key while the regions cannot talk: each side stops
+        # at its own limit, so the split admits at most one limit more
+        # than a healthy cluster would (an over-admission ratio of 1.0,
+        # the staleness budget of docs/federation.md), never more.
+        small = 5
+        admitted = 0
+        for region in ("us", "eu"):
+            client = c.find_owning_daemon_in_region(
+                name, "over", region).client()
+            for _ in range(2 * small):
+                out = await client.get_rate_limits([RateLimitRequest(
+                    name=name, unique_key="over", hits=1, limit=small,
+                    duration=3_600_000, behavior=Behavior.MULTI_REGION,
+                )], timeout=30.0)
+                assert out[0].error == ""
+                if out[0].status == Status.OVER_LIMIT:
+                    break
+                admitted += 1
+            await client.close()
+        assert small <= admitted <= 2 * small
         # Degraded, never down: each region still answers from local
         # state — drift is bounded by staleness × local rate, which the
         # staleness gauge now exports.

@@ -84,6 +84,34 @@ def test_duration_suffixes():
     assert parse_duration("0.25") == pytest.approx(0.25)
 
 
+REMOVED_KNOBS = {
+    "GUBER_TPU_SORTED32": "0",
+    "GUBER_TPU_DMA_RING": "64",
+    "GUBER_TPU_DMA_UNROLL": "8",
+    "GUBER_MESH_LOCAL_WIDTH": "128",
+    "GUBER_MESH_ROUTING": "host",
+}
+
+
+@pytest.mark.parametrize("source", ["environment", "config_file"])
+def test_removed_knobs_still_named_change_nothing(source, tmp_path):
+    """A deployment whose environment or -config file still names a knob
+    that has gone starts exactly as one that does not: a name that is
+    only set is never read, so it can neither fail the start nor change
+    a field (env_knob refuses an unregistered *read*, not a set)."""
+    from gubernator_tpu.config import ENV_REGISTRY
+
+    assert not set(REMOVED_KNOBS) & set(ENV_REGISTRY)
+    base = {"GUBER_INSTANCE_ID": "same"}
+    if source == "environment":
+        got = conf_from({**base, **REMOVED_KNOBS})
+    else:
+        p = tmp_path / "old.conf"
+        p.write_text("".join(f"{k}={v}\n" for k, v in REMOVED_KNOBS.items()))
+        got = conf_from(dict(base), config_file=str(p))
+    assert got == conf_from(dict(base))
+
+
 @pytest.mark.parametrize("env", [
     {"GUBER_PEER_PICKER_HASH": "md5"},
     {"GUBER_PEER_PICKER": "consistent-hash"},

@@ -463,9 +463,8 @@ def test_fused_sparse_step_parity_fuzz():
 
 def test_reconcile_dispatch_counter():
     """One mesh program per non-overflowing sparse step (the fused
-    probe), two for an overflowing step (fused probe + dense fallback) —
-    the counter the bench ladder exports and the regression gate
-    checks."""
+    probe), two for an overflowing step (fused probe + dense fallback):
+    the exact count the reconcile-dispatch Prometheus counter reads."""
     import numpy as np
 
     eng = MeshGlobalEngine(

@@ -532,6 +532,8 @@ def test_lifecycle_breaker_open_extends_time_not_budget(sim):
 def test_close_flushes_unsynced_through_sync_path(sim):
     cache, mgr, clk, calls = _wired(sim, want_budget=10)
     spec = _spec("cl")
+    eng = sim.engine
+    d0, w0 = eng.metric_lease_dispatches, eng.metric_lease_windows
     for _ in range(4):
         assert cache.admit(spec) is True
     # close() drains via the normal sync path: the release round credits
@@ -541,6 +543,11 @@ def test_close_flushes_unsynced_through_sync_path(sim):
     assert cache.metric_sync_lost == 0
     assert _remaining(sim, "cl") == 1_000 - 4
     assert mgr.outstanding("lease_t", "cl") == 0
+    # The manager's accounting for the grant and the release round is
+    # exactly one column scatter per window, never one per key.
+    wins = eng.metric_lease_windows - w0
+    assert wins >= 2
+    assert eng.metric_lease_dispatches - d0 == wins
     # Idempotent; the cache refuses new admissions once closed.
     assert cache.close() == 0
     with pytest.raises(RuntimeError):

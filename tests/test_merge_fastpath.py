@@ -274,8 +274,8 @@ def test_randomized_parity(seed):
 
 
 def test_herd_4096_one_key_matches_and_is_single_round():
-    # The benchmark_test.go:122-147 scenario at full batch width: correctness
-    # here, speed in bench.py.
+    # The benchmark_test.go:122-147 scenario at full batch width:
+    # correctness here, speed in benchmarks/ (the Zipf cells).
     n = 4096
     m = packed(uniform_rows(n, hits=1, limit=100), b=n)
     f, s = run_both(m)

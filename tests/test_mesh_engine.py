@@ -213,8 +213,7 @@ def test_routing_parity_fuzz_vs_host_ring(engine):
     """Device-derived ownership must agree with the host hash ring for
     every served key: the vectorized CRC-32 route, the scalar
     ``_shard_of`` ring, slotmap residency (exactly one shard), and the
-    global-slot derivation (``slot // local_capacity``) — the invariant
-    the bench mesh rungs export as ``mesh_routing_parity_errors``."""
+    global-slot derivation (``slot // local_capacity``)."""
     rng = np.random.default_rng(11)
     keys = [
         f"parity-{int(rng.integers(0, 1 << 30))}-{'x' * int(rng.integers(0, 40))}"
@@ -299,30 +298,6 @@ def test_ragged_skew_window_no_fallback(engine):
     assert engine.metric_routed_overflows == over0 == 0
 
 
-def test_local_width_knob_warns_deprecated():
-    """GUBER_MESH_LOCAL_WIDTH / local_width= is dead — the ragged
-    dispatch has no per-shard width.  A non-zero value must emit the
-    one-time DeprecationWarning and change nothing else."""
-    import gubernator_tpu.parallel.mesh_engine as me
-
-    me._LOCAL_WIDTH_WARNED = False
-    mesh = make_mesh(jax.devices()[:1])
-    with pytest.warns(DeprecationWarning, match="LOCAL_WIDTH"):
-        eng = MeshTickEngine(
-            mesh=mesh, local_capacity=16, max_batch=8, local_width=4
-        )
-    assert not hasattr(eng, "local_width")
-    out = eng.process([req("lw", limit=9)], now=NOW)
-    assert out[0].remaining == 8
-    # One-time: the latch keeps a second deprecated build quiet.
-    import warnings
-
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        me._warn_local_width_deprecated()
-    assert not caught
-
-
 def test_ragged_extent_math_shard_counts():
     """Pure-host extent math at every interesting shard count —
     including 1, odd, prime, and >8 (no engine builds): counts sum to
@@ -384,12 +359,26 @@ def test_ragged_parity_fuzz_vs_single_chip(engine):
                          duration=60_000)
         for k in hot
     ])
-    for t, reqs in enumerate(windows):
-        a = engine.process(reqs, now=NOW + t * 500)
-        b = s_eng.process(reqs, now=NOW + t * 500)
-        for x, y in zip(a, b):
-            assert (x.status, x.remaining, x.reset_time, x.error) == (
-                y.status, y.remaining, y.reset_time, y.error)
+    def sweep(base):
+        for t, reqs in enumerate(windows):
+            a = engine.process(reqs, now=base + t * 500)
+            b = s_eng.process(reqs, now=base + t * 500)
+            for x, y in zip(a, b):
+                assert (x.status, x.remaining, x.reset_time, x.error) == (
+                    y.status, y.remaining, y.reset_time, y.error)
+
+    sweep(NOW)
+    # The same skewed windows again, now that every program they need
+    # has run once: serving reuses them (no retrace, whatever the
+    # extents), and every decision issued resolves exactly once —
+    # hits + misses neither short of the rows sent (a dropped key) nor
+    # over them (one served twice).
+    traces = dict(engine.ops.trace_counts)
+    resolved0 = engine.metric_hits + engine.metric_misses
+    sweep(NOW + 10_000)
+    assert dict(engine.ops.trace_counts) == traces
+    assert engine.metric_hits + engine.metric_misses - resolved0 == sum(
+        len(w) for w in windows)
     assert engine.metric_routed_overflows == over0 == 0
 
 

@@ -3,12 +3,12 @@ state machine (commit / abort / rollback), and the crash journal.
 
 Nearly everything here runs against stub engines or the single-chip
 TickLoop — the mesh-engine relayout itself is covered by
-test_mesh_engine.py and the reshard_live bench rung.  The ONE mesh
-build in this module is the reshard × ragged composition case at the
-bottom (a deliberately tiny 8→3→8 engine), because what it pins is the
-coordinator-visible outcome: extent offsets recomputed against the new
-``cap_to`` keep ``state_loss`` / ``double_served`` at zero under
-Zipf-skewed ragged dispatch.
+test_mesh_engine.py.  The ONE mesh build in this module is the
+reshard × ragged composition case at the bottom (a deliberately tiny
+8→3→6 engine, the second leg actuated by the autoscaler), because what
+it pins is the coordinator-visible outcome: extent offsets recomputed
+against the new ``cap_to`` keep ``state_loss`` / ``double_served`` /
+``parity_errors`` at zero under Zipf-skewed ragged dispatch.
 """
 
 import threading
@@ -274,7 +274,8 @@ def test_coordinator_rejects_concurrent_and_bad_target():
 
 def test_coordinator_verify_counts_damage():
     """A lossy/double-resident post-cutover table is counted, never
-    silent (the bench rung gates both at ABSOLUTE_ZERO)."""
+    silent (test_reshard_ragged_zipf_round_trip_zero_loss holds both
+    at zero on a real mesh)."""
 
     class _DamagedEngine(_StubEngine):
         n_shards = 2
@@ -363,17 +364,23 @@ def test_interrupted_detection_counts_metric():
 # the module docstring)
 # ---------------------------------------------------------------------------
 def test_reshard_ragged_zipf_round_trip_zero_loss():
-    """8→3→8 through the full coordinator protocol with Zipf-skewed
+    """8→3→6 through the full coordinator protocol with Zipf-skewed
     traffic served by the ragged dispatch on every layout: the extent
     offsets are recomputed against each layout's ``cap_to``, so
     ``state_loss`` / ``double_served`` / ``parity_errors`` stay 0 and
     decisions keep matching a single-chip replay across both cutovers.
     The overflow canary must never move — skew has no fallback."""
+    import asyncio
+
     import jax
     import numpy as np
 
+    from gubernator_tpu.autoscale import (
+        Autoscaler, AutoscalePolicy, PolicyConfig, SignalSnapshot)
+    from gubernator_tpu.autoscale.controller import ACT
     from gubernator_tpu.ops.engine import TickEngine
     from gubernator_tpu.parallel.mesh_engine import MeshTickEngine, make_mesh
+    from gubernator_tpu.resilience import ManualClock
     from gubernator_tpu.utils import timeutil
 
     # Wall-clock base: the coordinator's cutover stamps load_items with
@@ -403,10 +410,35 @@ def test_reshard_ragged_zipf_round_trip_zero_loss():
         assert [(r.status, r.remaining, r.error) for r in a] == \
                [(r.status, r.remaining, r.error) for r in b]
 
+    # The second leg is autonomous: the autoscaler's own step, under
+    # sustained pressure, actuates it through the executor the service
+    # wires in (coord.try_reshard), doubling 3 to 6.  Its audit is held
+    # to the same zeros as the operator's leg.
+    auto = []
+
+    def executor(target):
+        auto.append(coord.try_reshard(target))
+        return auto[-1]
+
+    clock = ManualClock()
+    scaler = Autoscaler(
+        lambda: SignalSnapshot(p99_ms=50.0, queue_depth=0,
+                               hot_occupancy=0.9, shards=eng.n_shards),
+        executor,
+        policy=AutoscalePolicy(PolicyConfig(
+            windows=1, target_p99_ms=5.0, min_shards=3, max_shards=8)),
+        dry_run=False, clock=clock, sleep=clock.sleep,
+    )
+
+    def autonomous(target):
+        assert asyncio.run(scaler.step()).action == ACT
+        return auto[-1]
+
     for t in range(2):
         serve_and_compare(t)
-    for leg, (target, t0) in enumerate([(3, 100), (8, 200)]):
-        res = coord.reshard(target)
+    for target, t0, actuate in [(3, 100, coord.reshard),
+                                (6, 200, autonomous)]:
+        res = actuate(target)
         assert res["outcome"] == "committed", res
         assert res["to_shards"] == target == eng.n_shards
         assert res["state_loss"] == 0 and res["double_served"] == 0

@@ -3,7 +3,7 @@ row-vs-column engine parity.
 
 The row layout (ops/rowtable.py) is the TPU production path; on the CPU
 test backend its kernels run in Pallas interpret mode, so everything here
-checks semantics, and the TPU bench checks speed.
+checks semantics, and benchmarks/ on the chip checks speed.
 """
 
 import numpy as np
