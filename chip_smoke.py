@@ -430,7 +430,8 @@ async def start_daemon(report: Report, env: dict):
 
 
 def print_counters(eng) -> None:
-    names = ("_tick_count", "metric_h2d_windows", "metric_h2d_overlapped",
+    names = ("_tick_count", "metric_h2d_windows", "metric_h2d_uploads",
+             "metric_h2d_overlapped",
              "metric_layered_ticks", "metric_hits", "metric_misses",
              "metric_over_limit", "metric_unexpired_evictions")
     print("engine counters: " + " ".join(

@@ -328,7 +328,9 @@ class NativeSlotMap:
         the slab is untouched.  Otherwise the slab is packed and sorted
         and ``inv`` maps request order to sorted lanes; for PACK_GROUPED
         ``plan`` is ``build_group_plan``'s ``(mhead, count, uidx, rank,
-        u)``, else None."""
+        u, buf)``, else None: ``buf`` is the scratch the pass wrote, cut
+        to the plan's own words, with ``now`` as its (lo, hi) pair
+        behind them — the window's one upload (``engine.plan_views``)."""
         n = len(cols)
         columns = [
             np.ascontiguousarray(c, np.int64) for c in (
@@ -346,9 +348,11 @@ class NativeSlotMap:
         known = np.empty(n, np.uint8)
         inv = np.empty(n, np.int64)
         # The plan's arrays, laid out uidx[b] rank[b] count[upad]
-        # mhead[19][upad].  Fresh every window: they are uploaded
-        # asynchronously, and jax may read them until the copy is done.
-        scratch = np.empty(2 * b + (rows + 1) * upad_cap, np.int32)
+        # mhead[19][upad], and two words more for ``now``.  Fresh every
+        # window: it is uploaded asynchronously, and jax may read it
+        # until the copy is done.
+        plan_cap = 2 * b + (rows + 1) * upad_cap
+        scratch = np.empty(plan_cap + 2, np.int32)
         info = np.zeros(4, np.int64)
         call = (
             self._lib.guber_slotmap_pack_window
@@ -357,15 +361,20 @@ class NativeSlotMap:
         status = call(
             self._h, as_char_p(cols.key_blob), columns[0], n, *columns[1:],
             now, stop_on_miss, m32, b, slots, known, inv,
-            last_access, tick, dirty, scratch, len(scratch), info,
+            last_access, tick, dirty, scratch, plan_cap, info,
         )
         n_miss, u, upad, n_leaky = info.tolist()
         plan = None
         if status == self.PACK_GROUPED:
             at = 2 * b + upad
+            end = at + rows * upad
+            lo = now & 0xFFFFFFFF
+            scratch[end] = lo - ((lo & 0x80000000) << 1)
+            scratch[end + 1] = now >> 32
             plan = (
-                scratch[at:at + rows * upad].reshape(rows, upad),
+                scratch[at:end].reshape(rows, upad),
                 scratch[2 * b:at], scratch[:b], scratch[b:2 * b], u,
+                scratch[:end + 2],
             )
         return status, slots, known, inv, n_miss, plan, n_leaky
 

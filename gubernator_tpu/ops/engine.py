@@ -269,6 +269,13 @@ REQ32_INDEX = {name: i for i, name in enumerate(REQ32_NARROW)}
 for _j, _name in enumerate(REQ32_WIDE):
     REQ32_INDEX[_name] = len(REQ32_NARROW) + 2 * _j  # the lo row; hi = +1
 REQ32_ROWS = len(REQ32_NARROW) + 2 * len(REQ32_WIDE)  # 19
+# One window, one upload (TickEngine.submit_columns): the tick's ``now``
+# crosses to the device inside the buffer that is uploaded anyway, as
+# the wide encoding's (lo, hi) pair (:func:`stamp_now`).  A unique or
+# sequential window uploads its staging slab, the REQ32 rows and one row
+# more whose first two words are ``now``; a grouped window uploads its
+# plan, with the two words at the end (:func:`plan_views`).
+SLAB_ROWS = REQ32_ROWS + 1
 
 
 def split_i64(v: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -279,6 +286,15 @@ def split_i64(v: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         (v & 0xFFFFFFFF).astype(np.uint32).view(np.int32),
         (v >> 32).astype(np.int32),
     )
+
+
+@hot_path
+def stamp_now(words: np.ndarray, now: int) -> None:
+    """Write the tick's ``now`` into ``words[0:2]`` as :func:`split_i64`
+    would (lo, hi), in plain int arithmetic: two stores a window."""
+    lo = now & 0xFFFFFFFF
+    words[0] = lo - ((lo & 0x80000000) << 1)
+    words[1] = now >> 32
 
 
 @hot_path
@@ -624,6 +640,30 @@ def grouped_warm_shapes(widths: tuple, deep: bool) -> list:
     return shapes
 
 
+def plan_views(buf, b: int):
+    """A grouped window's one upload, cut into its parts: ``(mhead (19,
+    upad), count (upad,), uidx (b,), rank (b,), now (2,))`` as views of
+    the flat int32 ``buf`` laid out ``uidx[b] rank[b] count[upad]
+    mhead[19][upad] now[2]`` — the native window pass's scratch
+    (native/slotmap.cc guber_slotmap_pack_window) with ``now`` behind
+    it.  THE definition of the layout for the numpy plan
+    (:func:`build_group_plan`) and, on a traced ``buf``, for the device
+    program (tick32.jitted_merged_pipeline); ``upad`` follows from the
+    length, which :func:`plan_words` gives."""
+    upad = (buf.shape[0] - 2 - 2 * b) // (REQ32_ROWS + 1)
+    at = 2 * b + upad
+    end = at + REQ32_ROWS * upad
+    return (
+        buf[at:end].reshape(REQ32_ROWS, upad), buf[2 * b:at],
+        buf[:b], buf[b:2 * b], buf[end:],
+    )
+
+
+def plan_words(b: int, upad: int) -> int:
+    """int32 words of a grouped window's upload (:func:`plan_views`)."""
+    return 2 * b + (REQ32_ROWS + 1) * upad + 2
+
+
 def _param_rows_equal_prev(m: np.ndarray, nl: int) -> np.ndarray:
     """(nl,) bool: row i carries identical request parameters to row
     i-1 (the 17 REQ32 parameter rows both duplicate planners fold on —
@@ -655,7 +695,9 @@ def build_group_plan(m: np.ndarray, n: int, capacity: int, now: int,
     under a head that provably comes out alive — the same eligibility the
     device-side fold uses (:func:`_apply_merged_followers` ``ok``).
     Returns ``(mhead (19, Upad), count (Upad,), uidx (B,), rank (B,),
-    u)`` — ``u`` the live head count — or None when any group is
+    u, buf)`` — ``u`` the live head count, ``buf`` the one flat buffer
+    the four arrays are views of and the window uploads, ``now`` in its
+    last two words (:func:`plan_views`) — or None when any group is
     ineligible (those batches keep the sequential rank-round program,
     whose per-unit rounds handle mixed groups) or when fewer than
     ``min_dup_frac`` of the live rows are followers: a near-unique batch
@@ -718,18 +760,21 @@ def build_group_plan(m: np.ndarray, n: int, capacity: int, now: int,
 
     u = len(starts)
     upad = group_upad(b, u)
-    mhead = np.empty((REQ32_ROWS, upad), np.int32)
-    mhead[:, :u] = m[:, starts]
+    # Fresh every window: it is uploaded asynchronously, and jax may
+    # read it until the copy is done.
+    buf = np.empty(plan_words(b, upad), np.int32)
+    mhead, count, uidx, rank_b, now_words = plan_views(buf, b)
+    mhead[:, :u] = m[:REQ32_ROWS, starts]
     mhead[:, u:] = 0
     mhead[R["slot"], u:] = capacity  # padding heads aim at the guard row
-    count = np.ones(upad, np.int32)
-    sizes = np.diff(np.append(starts, n)).astype(np.int32)
-    count[:u] = sizes
-    uidx = np.full(b, upad - 1, np.int32)
+    count[:u] = np.diff(np.append(starts, n))
+    count[u:] = 1
     uidx[:n] = gid
-    rank_b = np.zeros(b, np.int32)
+    uidx[n:] = upad - 1
     rank_b[:n] = rank
-    return mhead, count, uidx, rank_b, u
+    rank_b[n:] = 0
+    stamp_now(now_words, now)
+    return mhead, count, uidx, rank_b, u, buf
 
 
 def build_layer_plan(m: np.ndarray, n: int, capacity: int, now: int,
@@ -2323,7 +2368,7 @@ class TickEngine:
 
         self._tick = jitted_sorted_tick32(self.capacity, self.layout)
 
-        # Note on request-buffer donation: the (19, B) request matrix
+        # Note on request-buffer donation: the request slab
         # has no same-shape program output, and XLA's input-output
         # aliasing is exact-shape, so donating it buys nothing (jax
         # warns "donated buffers were not usable").  The double-buffered
@@ -2368,7 +2413,7 @@ class TickEngine:
             _depth = 4
         self._stage_depth = 2 * _depth + 1
         self._staging = StagingRing(
-            REQ32_ROWS, self.capacity, self._stage_depth
+            SLAB_ROWS, self.capacity, self._stage_depth
         )
         # H2D overlap telemetry: a window counts as overlapped when its
         # upload was dispatched while at least one earlier window was
@@ -2378,6 +2423,11 @@ class TickEngine:
         self._inflight = 0
         self.metric_h2d_windows = 0
         self.metric_h2d_overlapped = 0
+        # Host→device uploads submit_columns issued: over
+        # metric_h2d_windows it reads 1.0 where every window is one
+        # buffer (grouped, unique, sequential; a layered window's plan
+        # is still seven).
+        self.metric_h2d_uploads = 0
         self.slots = make_slot_map(self.capacity)
         # The host pack in one native call (_build_cols): there with the
         # native slot map, and then taken by every window it can answer.
@@ -2494,7 +2544,9 @@ class TickEngine:
         and trigger forward retries that double-count hits."""
         on_chip = jax.default_backend() == "tpu"
         for w in self._widths:
-            m = np.zeros((REQ32_ROWS, w), np.int32)
+            # A window's upload as submit_columns makes it: the slab, all
+            # padding, ``now`` (0) in its last row.
+            m = np.zeros((SLAB_ROWS, w), np.int32)
             m[REQ32_INDEX["slot"]] = self.capacity
             if on_chip:
                 # The sequential chained-unit program only serves
@@ -2502,13 +2554,9 @@ class TickEngine:
                 # below, eager-compiling it is a serving chip's live-
                 # deadline concern — on the CPU backend (the tests) most
                 # engines never tick it and lazy is the right trade.
-                self.state, resp = self._tick(
-                    self.state, jnp.asarray(m), jnp.int64(0)
-                )
+                self.state, resp = self._tick(self.state, jnp.asarray(m))
                 np.asarray(resp)
-            self.state, resp = self._tick32(
-                self.state, jnp.asarray(m), jnp.int64(0)
-            )
+            self.state, resp = self._tick32(self.state, jnp.asarray(m))
             np.asarray(resp)
         # Warm the grouped (scatter-add) pipeline at each width's floor
         # head shape (group_upad — the shape every sub-quantum hot-key
@@ -2520,15 +2568,15 @@ class TickEngine:
         # compiles.
         if self.capacity >= (1 << 14):
             for w, upad in grouped_warm_shapes(self._widths, on_chip):
-                mh = np.zeros((REQ32_ROWS, upad), np.int32)
+                # The plan of an all-padding window, in the one buffer
+                # a grouped window uploads (plan_views).
+                buf = np.zeros(plan_words(w, upad), np.int32)
+                mh, count, uidx, _, _ = plan_views(buf, w)
                 mh[REQ32_INDEX["slot"]] = self.capacity
+                count[:] = 1
+                uidx[:] = upad - 1
                 self.state, resp = self._tick32m(
-                    self.state, jnp.asarray(mh),
-                    jnp.ones(upad, np.int32),
-                    jnp.full(w, upad - 1, np.int32),
-                    jnp.zeros(w, np.int32),
-                    jnp.int64(0),
-                )
+                    self.state, jnp.asarray(buf), w)
                 np.asarray(resp)
         if self.capacity >= (1 << 16) and on_chip:
             # Warm the layered pipeline's most common shape (w0 at the
@@ -2549,14 +2597,14 @@ class TickEngine:
             mh0[REQ32_INDEX["slot"]] = self.capacity
             mhk = np.zeros((1, REQ32_ROWS, 512), np.int32)
             mhk[:, REQ32_INDEX["slot"], :] = self.capacity
-            m32 = np.zeros((REQ32_ROWS, w), np.int32)
+            m32 = np.zeros((SLAB_ROWS, w), np.int32)
             m32[REQ32_INDEX["slot"]] = self.capacity
             fn = jitted_layered_pipeline(self.capacity, self.layout, w0, 2)
             self.state, resp = fn(
                 self.state, jnp.asarray(mh0), jnp.ones(w0, np.int32),
                 jnp.asarray(mhk), jnp.ones((1, 512), np.int32),
                 jnp.asarray(m32), jnp.zeros(w, np.int32),
-                jnp.zeros(w, np.int32), jnp.int64(0),
+                jnp.zeros(w, np.int32),
             )
             np.asarray(resp)
         cols = np.zeros((8, 1), np.int64)  # valid=0 row: install is a no-op
@@ -2804,12 +2852,12 @@ class TickEngine:
 
     @hot_path
     def _lease_matrix(self, b: int) -> np.ndarray:
-        """A (REQ32_ROWS, b) staging slab from the per-width ring — see
+        """A (SLAB_ROWS, b) staging slab from the per-width ring — see
         :class:`StagingRing` for the recycle contract.  Zeroed, its slot
         row at the padding sentinel, unless the native window pass is
-        there to do that (it cleans the slab it packs; the numpy pack
-        cleans one the pass hands back).  Called under the engine lock
-        (ring state is unsynchronized)."""
+        there to do that (it cleans the REQ32 rows it packs; the numpy
+        pack cleans those the pass hands back).  Called under the engine
+        lock (ring state is unsynchronized)."""
         fr = flightrec.get()
         t0 = time.perf_counter() if fr is not None else 0.0
         m = self._staging.lease(b, clean=not self._native_pack)
@@ -2821,8 +2869,10 @@ class TickEngine:
     def _build_cols(self, cols: ReqColumns, now: int):
         """One window's host pack: keys to slots, the padded (19, B)
         request matrix in slot order, the dirty marks, and the grouped
-        plan where duplicates qualify.  Returns ``(m, n, errors, inv,
-        has_dups, plan)``.
+        plan where duplicates qualify.  Returns ``(slab, n, errors, inv,
+        has_dups, plan)``: ``slab`` is the (SLAB_ROWS, B) staging slab,
+        the request matrix in its REQ32 rows and ``now`` stamped in its
+        last one — what a window without a grouped plan uploads.
 
         The window the served path sees all day takes ONE native call
         (native/slotmap.cc guber_slotmap_pack_window; for a wide window
@@ -2847,7 +2897,9 @@ class TickEngine:
         # instead of paying for max_batch lanes of padding.  Both widths
         # are compiled at warmup.
         b = next(w for w in self._widths if w >= n)
-        m = self._lease_matrix(b)
+        slab = self._lease_matrix(b)
+        stamp_now(slab[REQ32_ROWS], now)
+        m = slab[:REQ32_ROWS]
         resolved = None
         if self._native_pack:
             sm = self.slots
@@ -2867,22 +2919,24 @@ class TickEngine:
                 self.metric_misses += n_miss
                 self.metric_leaky_rows += n_leaky
                 self.metric_native_pack_windows += 1
-                return m, n, {}, inv, status != sm.PACK_UNIQUE, plan
+                return slab, n, {}, inv, status != sm.PACK_UNIQUE, plan
             if status == sm.PACK_RESOLVED_ONLY:
                 resolved = slots, known
-            self._staging.clean(m)  # the pass left the slab as leased
-        return self._build_cols_numpy(cols, now, m, resolved)
+            self._staging.clean(m)  # the pass left the rows as leased
+        return self._build_cols_numpy(cols, now, slab, resolved)
 
     @hot_path
-    def _build_cols_numpy(self, cols: ReqColumns, now: int, m: np.ndarray,
+    def _build_cols_numpy(self, cols: ReqColumns, now: int, slab: np.ndarray,
                           resolved=None):
         """:meth:`_build_cols` in numpy, for the windows the native pass
         leaves (and for the pure-Python slot map): one blob resolve
         (``resolved``: the native pass's ``(slots, known)`` where it has
         them already, so no key is resolved twice) + a dozen vectorized
-        numpy writes + one argsort + the plan."""
+        numpy writes + one argsort + the plan, into the REQ32 rows of
+        ``slab``."""
         n = len(cols)
         R = REQ32_INDEX
+        m = slab[:REQ32_ROWS]
         errors: Dict[int, str] = {}
 
         # Gregorian resolution (host-side calendar math) — only requests
@@ -2909,7 +2963,7 @@ class TickEngine:
             # guber: allow-G001(builds a host index list, never device)
             sel = np.array([i for i in range(n) if i not in errors], np.int64)
             if len(sel) == 0:
-                return m, n, errors, np.arange(n, dtype=np.int64), False, None
+                return slab, n, errors, np.arange(n, dtype=np.int64), False, None
             slots, known = self.slots.resolve_batch(
                 [cols.key_bytes(int(i)) for i in sel]
             )
@@ -2961,7 +3015,7 @@ class TickEngine:
                 slots = slots[keep]
                 known = known[keep]
                 if len(slots) == 0:
-                    return m, n, errors, np.arange(n, dtype=np.int64), False, None
+                    return slab, n, errors, np.arange(n, dtype=np.int64), False, None
         self._last_access[slots] = self._tick_count
         miss = known == 0
         self._pending.update(slots[miss].tolist())
@@ -3027,7 +3081,7 @@ class TickEngine:
         plan = (
             build_group_plan(m, n, self.capacity, now) if has_dups else None
         )
-        return m, n, errors, inv, has_dups, plan
+        return slab, n, errors, inv, has_dups, plan
 
     @hot_path
     def _promote_misses(
@@ -3188,6 +3242,7 @@ class TickEngine:
             if fr is not None:
                 fr.note(fr.active(), "pack", time.perf_counter() - t_pack)
             dev_m = None
+            uploads = 1
             t_h2d = time.perf_counter() if fr is not None else 0.0
             # Structural tick-path evidence: any SSD lookup issued while
             # the tick-dispatch block below runs would land in this
@@ -3197,6 +3252,22 @@ class TickEngine:
             ssd_reads0 = (
                 self.ssd.metric_lookup_calls if self.ssd is not None else 0
             )
+            # One upload and one program call a window.  Each crossing
+            # into the runtime drops the GIL and waits to take it back
+            # from the event loop or the resolver, so what the pack
+            # returned reaches the device as ONE host buffer, ``now``
+            # inside it (stamp_now), and one jitted program cuts it up:
+            # the plan where the pack made one, else the slab.  The
+            # upload is an ASYNC host→device copy (jnp.asarray of a
+            # numpy buffer queues the transfer and returns; jax may
+            # read the buffer until it completes: a plan's is fresh
+            # every window, and the staging ring keeps a slab stable
+            # until its tick resolves), so this window's H2D overlaps
+            # the previous window's still-running tick.  Deliberately
+            # asarray, not a committed device_put: a committed sharding
+            # is a new jit signature and re-traces every warmed program
+            # once per width (measured ~0.6 s each on the CPU suite).
+            #
             # Named range in XProf captures (utils/tracing.py): device
             # tick vs host packing shows up separated in the profile.
             with tracing.profile_annotation("guber.tick"):
@@ -3204,12 +3275,10 @@ class TickEngine:
                     # Grouped tick: unique heads through the parts
                     # program (fold on device), member responses from
                     # the elementwise expansion — a k-deep hot key costs
-                    # one row of HBM traffic, not k.
-                    mhead, count, uidx, rank, _ = plan
+                    # one row of HBM traffic, not k.  The slab is not
+                    # uploaded: the plan holds its head columns.
                     self.state, resp = self._tick32m(
-                        self.state, jnp.asarray(mhead),
-                        jnp.asarray(count), jnp.asarray(uidx),
-                        jnp.asarray(rank), jnp.int64(now),
+                        self.state, jnp.asarray(plan[5]), packed.shape[1]
                     )
                 elif has_dups:
                     # Layered dispatch is gated to serving-scale engines
@@ -3219,15 +3288,19 @@ class TickEngine:
                     # a compile storm for batches the sequential program
                     # already handles in a round or two.
                     lplan = (
-                        build_layer_plan(packed, n, self.capacity, now)
+                        build_layer_plan(
+                            packed[:REQ32_ROWS], n, self.capacity, now)
                         if self.capacity >= (1 << 14) else None
                     )
+                    dev_m = jnp.asarray(packed)
                     if lplan is not None:
                         # Mixed groups with a host layer plan: one
                         # narrow merged tick per unit layer, chained
                         # through the table (tick32.
                         # jitted_layered_pipeline) — K narrow ticks
-                        # instead of one full round per unit.
+                        # instead of one full round per unit.  No cell
+                        # meets it: its plan still crosses array by
+                        # array, beside the slab.
                         from gubernator_tpu.ops.tick32 import (
                             jitted_layered_pipeline,
                         )
@@ -3237,39 +3310,23 @@ class TickEngine:
                         fn = jitted_layered_pipeline(
                             self.capacity, self.layout, mh0.shape[1], kpad
                         )
-                        dev_m = jnp.asarray(packed)
+                        uploads = 7   # the slab and the plan's six arrays
                         self.state, resp = fn(
                             self.state, jnp.asarray(mh0),
                             jnp.asarray(cnt0), jnp.asarray(mhk),
                             jnp.asarray(cntk), dev_m,
                             jnp.asarray(uidx), jnp.asarray(rank),
-                            jnp.int64(now),
                         )
                     else:
                         # Adversarial shapes (over-deep/over-wide unit
                         # structure, unprovable head liveness): the
                         # sequential chained-unit program is always
                         # correct.
-                        dev_m = jnp.asarray(packed)
-                        self.state, resp = self._tick(
-                            self.state, dev_m, jnp.int64(now)
-                        )
+                        self.state, resp = self._tick(self.state, dev_m)
                 else:
-                    # The common serving shape: the upload is an ASYNC
-                    # host→device copy (jnp.asarray of a numpy buffer
-                    # queues the transfer and returns; jax may read the
-                    # host slab until it completes — the staging ring
-                    # above guarantees it stays stable), so this
-                    # window's H2D overlaps the previous window's
-                    # still-running tick.  Deliberately asarray, not a
-                    # committed device_put: a committed sharding is a
-                    # new jit signature and re-traces every warmed
-                    # program once per width (measured ~0.6 s each on
-                    # the CPU suite).
+                    # The common serving shape.
                     dev_m = jnp.asarray(packed)
-                    self.state, resp = self._tick32(
-                        self.state, dev_m, jnp.int64(now)
-                    )
+                    self.state, resp = self._tick32(self.state, dev_m)
             if fr is not None:
                 fr.note(fr.active(), "h2d", time.perf_counter() - t_h2d)
             if self.ssd is not None:
@@ -3290,6 +3347,7 @@ class TickEngine:
             # was dispatched while `_inflight` earlier windows were
             # still unresolved (their ticks run while our bytes move).
             self.metric_h2d_windows += 1
+            self.metric_h2d_uploads += uploads
             if self._inflight > 0:
                 self.metric_h2d_overlapped += 1
             self._inflight += 1

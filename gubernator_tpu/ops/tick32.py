@@ -42,9 +42,14 @@ from gubernator_tpu.ops.transition32 import (
 I32 = jnp.int32
 
 
-def now_to_pair(now: jnp.ndarray) -> p64.I64:
-    """Scalar int64 ``now`` → (lo, hi) i32 pair (scalar arithmetic only —
-    this toolchain's X64 rewriter has no 64-bit bitcasts)."""
+def now_to_pair(now) -> p64.I64:
+    """``now`` as the (lo, hi) i32 pair.  A scalar int64 is split (scalar
+    arithmetic only — this toolchain's X64 rewriter has no 64-bit
+    bitcasts); a pair passes through: the engine's entries read the two
+    words out of the window's one upload (:func:`split_slab`), and no
+    64-bit value then exists on the device at all."""
+    if isinstance(now, p64.I64):
+        return now
     hi = (now >> 32).astype(I32)
     lo_u = now & jnp.int64(0xFFFFFFFF)
     lo = jnp.where(
@@ -70,6 +75,29 @@ def _resolve_fused(fused: bool | None) -> bool:
     if env is not None:
         return env != "0"
     return jax.default_backend() == "tpu"
+
+
+def split_slab(slab):
+    """A unique or sequential window's upload (engine.SLAB_ROWS, B) →
+    (the (19, B) REQ32 matrix, ``now`` as its i32 pair): the tick's clock
+    rides in the first two words of the slab's last row
+    (engine.stamp_now), so a window is one host→device copy."""
+    from gubernator_tpu.ops.engine import REQ32_ROWS
+
+    return slab[:REQ32_ROWS], p64.I64(slab[REQ32_ROWS, 0], slab[REQ32_ROWS, 1])
+
+
+def stack6(rows) -> jnp.ndarray:
+    """Six (B,) response rows → the (6, B) compact matrix, written row by
+    row into a zeroed block, NOT ``jnp.stack``: XLA:CPU's emitters walk
+    a concatenate-rooted fusion's whole operand graph once per output
+    element (make_tick32_rows_fn), while a row written by
+    dynamic-update-slice is one memoized loop.  That is what lets tick
+    and stack be ONE program on every backend."""
+    out = jnp.zeros((len(rows),) + rows[0].shape, I32)
+    for k, row in enumerate(rows):
+        out = jax.lax.dynamic_update_slice(out, row[None], (k, 0))
+    return out
 
 
 def _resp_rows(resp) -> tuple:
@@ -98,7 +126,8 @@ def make_tick32_rows_fn(capacity: int, layout: str = "columns"):
     took 12 s on the 8-device test mesh.  Returning the rows as separate
     program outputs keeps every fusion root single-output, which XLA
     emits as one memoized loop.  TPU's emitter doesn't have the
-    pathology, but the two-program composition costs only a dispatch.
+    pathology.  Callers that want the (6, B) matrix from the same
+    program assemble it with :func:`stack6`, which no backend minds.
     """
 
     if layout == "row":
@@ -139,10 +168,9 @@ def make_tick32_fn(capacity: int, layout: str = "columns",
     request per real slot.  ``resp6`` is the (6, B) compact response
     matrix; rows past the live count are unspecified.
 
-    This single-program form is for callers that need one traceable
-    function (tests/test_fusedtick.py jits it whole).  Engines should
-    use :func:`jitted_tick32`, which splits the response stack into a
-    second program — see make_tick32_rows_fn for why.
+    One traceable function (tests/test_fusedtick.py jits it whole);
+    :func:`jitted_tick32` is the engine's entry, the same program behind
+    the window's one upload.
     """
 
     if layout == "row" and _resolve_fused(fused):
@@ -154,37 +182,29 @@ def make_tick32_fn(capacity: int, layout: str = "columns",
 
     def tick(state, m32, now):
         state, rows = rows_fn(state, m32, now)
-        return state, jnp.stack(rows)
+        return state, stack6(rows)
 
     return tick
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted_stack6():
-    return jax.jit(lambda rows: jnp.stack(rows))
+def _jit_on_slab(tick):
+    """(state, m32, now) tick → the engine's ONE jitted program of
+    ``(state, slab)``, state donated: the REQ32 rows and ``now`` both
+    come out of the window's one upload (:func:`split_slab`)."""
+
+    def run(state, slab):
+        return tick(state, *split_slab(slab))
+
+    return jax.jit(run, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
 def jitted_tick32(capacity: int, layout: str = "columns",
                   fused: bool | None = None):
-    """Engine entry: two-program composition (tick rows + stack) so the
-    CPU backend never sees a concatenate-rooted mega-fusion (see
-    make_tick32_rows_fn).  The fused Pallas row kernel packs its response
-    in-kernel and stays a single program."""
-    if layout == "row" and _resolve_fused(fused):
-        from gubernator_tpu.ops.fusedtick import make_fused_tick_fn
-
-        return jax.jit(make_fused_tick_fn(capacity), donate_argnums=(0,))
-
-    inner = jax.jit(
-        make_tick32_rows_fn(capacity, layout), donate_argnums=(0,))
-    stack = _jitted_stack6()
-
-    def tick(state, m32, now):
-        state, rows = inner(state, m32, now)
-        return state, stack(rows)
-
-    return tick
+    """Engine entry for unique-slot windows: (state, slab (SLAB_ROWS, B))
+    → (state, (6, B) compact responses), one program call on one upload
+    — the fused Pallas row kernel, or the XLA rows with their stack."""
+    return _jit_on_slab(make_tick32_fn(capacity, layout, fused))
 
 
 # ----------------------------------------------------------------------
@@ -280,8 +300,10 @@ def jitted_layered_pipeline(capacity: int, layout: str, w0: int,
                             k_layers: int, layer_width: int = 512,
                             fused: bool | None = None):
     """Engine entry for mixed-duplicate batches with a host layer plan
-    (engine.build_layer_plan): (state, mh0, cnt0, mhk, cntk, m32, uidx,
-    rank, now) → (state, (6, B) compact responses).
+    (engine.build_layer_plan): (state, mh0, cnt0, mhk, cntk, slab, uidx,
+    rank) → (state, (6, B) compact responses); ``slab`` is the window's
+    staging slab as the unique program takes it, ``now`` in its last row
+    (:func:`split_slab`).
 
     Layer 0 (every segment's first unit, up to ``w0`` heads) and then
     ``k_layers - 1`` narrow layers each run the merged tick — gather,
@@ -301,7 +323,8 @@ def jitted_layered_pipeline(capacity: int, layout: str, w0: int,
         tickk = make_fused_merged_tick_fn(
             capacity, chunk=min(2048, layer_width))
 
-        def run_inner(state, mh0, cnt0, mhk, cntk, m32, uidx, rank, now):
+        def run_inner(state, mh0, cnt0, mhk, cntk, slab, uidx, rank):
+            _, now = split_slab(slab)
             state, r24_0 = tick0(state, mh0, cnt0, now)   # (W0, 24)
 
             def layer(k, carry):
@@ -322,7 +345,8 @@ def jitted_layered_pipeline(capacity: int, layout: str, w0: int,
 
     core = make_merged_tick32_rows_fn(capacity, layout)
 
-    def run_inner(state, mh0, cnt0, mhk, cntk, m32, uidx, rank, now):
+    def run_inner(state, mh0, cnt0, mhk, cntk, slab, uidx, rank):
+        m32, now = split_slab(slab)
         state, rows0 = core(state, mh0, cnt0, now)
 
         def layer(k, carry):
@@ -557,53 +581,58 @@ def make_sorted_tick32_rows_fn(capacity: int, layout: str = "columns",
 @functools.lru_cache(maxsize=None)
 def jitted_sorted_tick32(capacity: int, layout: str = "columns",
                          unit_unroll: int = 8):
-    """Engine entry for mixed-duplicate batches: two-program composition
-    (rows + stack), like jitted_tick32."""
-    inner = jax.jit(
-        make_sorted_tick32_rows_fn(capacity, layout, unit_unroll),
-        donate_argnums=(0,))
-    stack = _jitted_stack6()
+    """Engine entry for mixed-duplicate windows: (state, slab) → (state,
+    (6, B) compact responses), one program like jitted_tick32."""
+    rows_fn = make_sorted_tick32_rows_fn(capacity, layout, unit_unroll)
 
     def tick(state, m32, now):
-        state, rows = inner(state, m32, now)
-        return state, stack(rows)
+        state, rows = rows_fn(state, m32, now)
+        return state, stack6(rows)
 
-    return tick
+    return _jit_on_slab(tick)
 
 
 @functools.lru_cache(maxsize=None)
 def jitted_merged_pipeline(capacity: int, layout: str = "columns",
                            fused: bool | None = None):
-    """Engine entry for grouped batches: (state, mhead, count, uidx,
-    rank, now) → (state, (6, B) compact responses).  Composes the merged
-    tick with the member expansion, hiding the format split: the fused
-    Pallas kernel emits the row-major (U, 24) block (one whole-row
+    """Engine entry for grouped windows: ONE jitted function of (state,
+    buf, b) → (state, (6, B) compact responses), state donated, ``b``
+    (the batch width) static.  ``buf`` is the grouped plan as the host
+    pack laid it out, uploaded once (engine.plan_views: ``uidx[b]
+    rank[b] count[upad] mhead[19][upad]`` and the two words of
+    ``now``); the program cuts it by static offsets, so its compiled
+    shapes are the (b, upad) pairs.  The merged tick and the member
+    expansion run inside the same program, hiding the format split: the
+    fused Pallas kernel emits the row-major (U, 24) block (one whole-row
     gather per member — the TPU-fast layout), the XLA fallback emits
     unstacked rows (the CPU-safe layout)."""
     if layout == "row" and _resolve_fused(fused):
         from gubernator_tpu.ops.fusedtick import make_fused_merged_tick_fn
         from gubernator_tpu.ops.transition32 import expand32_rowmajor
 
-        tick = jax.jit(
-            make_fused_merged_tick_fn(capacity), donate_argnums=(0,))
-        expand = jax.jit(lambda r24, uidx, rank: jnp.stack(
-            expand32_rowmajor(r24, uidx, rank)))
+        # Not a jit of its own inside the one program, though its trace
+        # (seconds of Python) would then be shared by the batch widths
+        # that share a head width: a program with a nested jit missed
+        # the persistent compile cache at every start on the chip (warm
+        # set-up 71 -> 157 s, PERF.md section 6, PR 33).
+        tick = make_fused_merged_tick_fn(capacity)
 
-        def run(state, mhead, count, uidx, rank, now):
-            state, r24 = tick(state, mhead, count, now)
-            return state, expand(r24, uidx, rank)
+        def expand(r24, mhead, uidx, rank):
+            return jnp.stack(expand32_rowmajor(r24, uidx, rank))
 
-        return run
+    else:
+        from gubernator_tpu.ops.transition32 import expand32_rows
 
-    from gubernator_tpu.ops.transition32 import expand32_rows
+        tick = make_merged_tick32_rows_fn(capacity, layout)
 
-    inner = jax.jit(
-        make_merged_tick32_rows_fn(capacity, layout), donate_argnums=(0,))
-    expand = jax.jit(expand32_rows)
-    stack = _jitted_stack6()
+        def expand(rows, mhead, uidx, rank):
+            return stack6(expand32_rows(tuple(rows), mhead, uidx, rank))
 
-    def run(state, mhead, count, uidx, rank, now):
-        state, rows = inner(state, mhead, count, now)
-        return state, stack(expand(tuple(rows), mhead, uidx, rank))
+    def run(state, buf, b):
+        from gubernator_tpu.ops.engine import plan_views
 
-    return run
+        mhead, count, uidx, rank, now = plan_views(buf, b)
+        state, heads = tick(state, mhead, count, p64.I64(now[0], now[1]))
+        return state, expand(heads, mhead, uidx, rank)
+
+    return jax.jit(run, donate_argnums=(0,), static_argnums=(2,))

@@ -834,6 +834,11 @@ class Daemon:
         engine_tel: dict = {}
         if hasattr(eng, "h2d_overlap_ratio"):
             engine_tel["h2d_windows"] = eng.metric_h2d_windows
+            if hasattr(eng, "metric_h2d_uploads"):
+                # over h2d_windows: host→device uploads a window, 1.0
+                # where every window is one buffer
+                # (TickEngine.submit_columns)
+                engine_tel["h2d_uploads"] = eng.metric_h2d_uploads
             engine_tel["h2d_overlap_ratio"] = round(
                 eng.h2d_overlap_ratio(), 4)
         if hasattr(eng, "metric_native_pack_windows"):

@@ -89,18 +89,18 @@ def pytest_configure(config):
 # runs after these, in its usual place.
 LONGEST_FIRST = (
     "test_chip_compile",    # 283
+    "test_group_plan",      # 190 (39 before PR 33's one-buffer programs)
     "test_fusedtick",       # 143
     "test_mesh_engine",     # 135
     "test_layered",         # 68
     "test_engine",          # 91
-    "test_rowtable",        # 70
     "test_limit",           # 2: the seventh is handed to the first
                             # worker, behind test_chip_compile
+    "test_rowtable",        # 70
     "test_chaos",           # 90
     "test_global_mesh",     # 70
     "test_tiering",         # 54
     "test_merge_fastpath",  # 56
-    "test_group_plan",      # 39
     "test_fuzz_parity",     # 53
     "test_reshard",         # 50
     "test_unit_merge",      # 43

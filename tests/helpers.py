@@ -16,9 +16,24 @@ from typing import List
 
 import pytest
 
+import numpy as np
+
+from gubernator_tpu.ops import engine as _engine
 from gubernator_tpu.ops.engine import TickEngine
 from gubernator_tpu.types import (
     Behavior, RateLimitRequest, RateLimitResponse)
+
+
+def slab_of(m32, now: int) -> np.ndarray:
+    """A window's one upload as ``TickEngine._build_cols`` leaves it,
+    from a (19, B) REQ32 matrix: the matrix in the slab's REQ32 rows and
+    ``now`` stamped in the row behind them (what ``tick32.jitted_tick32``
+    and ``jitted_sorted_tick32`` take)."""
+    m32 = np.asarray(m32)
+    slab = np.zeros((_engine.SLAB_ROWS, m32.shape[1]), np.int32)
+    slab[:_engine.REQ32_ROWS] = m32
+    _engine.stamp_now(slab[_engine.REQ32_ROWS], now)
+    return slab
 
 
 class Sim:

@@ -20,7 +20,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from gubernator_tpu.ops import fusedtick, raggedtick, rowtable
-from gubernator_tpu.ops.engine import REQ32_ROWS, group_upad
+from gubernator_tpu.ops.engine import (
+    REQ32_ROWS, SLAB_ROWS, group_upad, grouped_warm_shapes, plan_words)
 
 CAP = 10_000_000        # BASELINE.json config 3: 5.12 GB of rows in HBM
 SHARDS = 4
@@ -91,34 +92,53 @@ def test_row_gather_and_scatter(mosaic, one_chip):
     assert has_kernel(g) and has_kernel(s)
 
 
+# The engine's entries (TickEngine.submit_columns): ONE program on the
+# window's one upload, ``now`` inside it as two int32 words.
+def no_int64(compiled) -> bool:
+    """``now`` stays a pair: nothing in the program is 64 bits wide,
+    which XLA:TPU would emulate."""
+    return "s64[" not in compiled.as_text()
+
+
 def test_fused_tick(mosaic, one_chip):
-    fn = jax.jit(fusedtick.make_fused_tick_fn(CAP), donate_argnums=(0,))
+    """A unique window: the staging slab in, the fused row kernel."""
+    from gubernator_tpu.ops.tick32 import jitted_tick32
+
+    fn = jitted_tick32(CAP, "row", fused=True)
     c = fn.lower(
-        row_state(CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
-        sds((), jnp.int64, one_chip),
+        row_state(CAP, one_chip), sds((SLAB_ROWS, B), I32, one_chip),
     ).compile()
-    assert has_kernel(c)
+    assert has_kernel(c) and no_int64(c)
 
 
-def test_fused_merged_tick(mosaic, one_chip):
-    fn = jax.jit(
-        fusedtick.make_fused_merged_tick_fn(CAP), donate_argnums=(0,))
+# At the default GUBER_TPU_MAX_BATCH the batch widths are 1024 and 4096:
+# each at its floor head width, and the widest pair (``_warmup`` compiles
+# every ``grouped_warm_shapes`` pair through this entry).
+@pytest.mark.parametrize("shape", [(1024, 256), (B, 1024), (B, B)])
+def test_fused_merged_tick(mosaic, one_chip, shape):
+    """A grouped window: the plan's buffer is cut, the merged kernel
+    runs and the members expand, in one program."""
+    from gubernator_tpu.ops.tick32 import jitted_merged_pipeline
+
+    b, upad = shape
+    assert shape in grouped_warm_shapes((max(1024, B // 4), B), True)
+    fn = jitted_merged_pipeline(CAP, "row", fused=True)
     c = fn.lower(
-        row_state(CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
-        sds((B,), I32, one_chip), sds((), jnp.int64, one_chip),
+        row_state(CAP, one_chip), sds((plan_words(b, upad),), I32, one_chip),
+        b,
     ).compile()
-    assert has_kernel(c)
+    assert has_kernel(c) and no_int64(c)
 
 
 def test_sorted_tick32_on_rows(mosaic, one_chip):
-    from gubernator_tpu.ops.tick32 import make_sorted_tick32_rows_fn
+    """A sequential window: the slab in, rounds and stack one program."""
+    from gubernator_tpu.ops.tick32 import jitted_sorted_tick32
 
-    fn = jax.jit(make_sorted_tick32_rows_fn(CAP, "row"), donate_argnums=(0,))
+    fn = jitted_sorted_tick32(CAP, "row")
     c = fn.lower(
-        row_state(CAP, one_chip), sds((REQ32_ROWS, B), I32, one_chip),
-        sds((), jnp.int64, one_chip),
+        row_state(CAP, one_chip), sds((SLAB_ROWS, B), I32, one_chip),
     ).compile()
-    assert has_kernel(c)
+    assert has_kernel(c) and no_int64(c)
 
 
 def test_layered_pipeline_at_warmup_shape(mosaic, one_chip):
@@ -134,9 +154,8 @@ def test_layered_pipeline_at_warmup_shape(mosaic, one_chip):
         sds((REQ32_ROWS, w0), I32, one_chip), sds((w0,), I32, one_chip),
         sds((1, REQ32_ROWS, 512), I32, one_chip),
         sds((1, 512), I32, one_chip),
-        sds((REQ32_ROWS, w), I32, one_chip),
+        sds((SLAB_ROWS, w), I32, one_chip),
         sds((w,), I32, one_chip), sds((w,), I32, one_chip),
-        sds((), jnp.int64, one_chip),
     ).compile()
     assert has_kernel(c)
 

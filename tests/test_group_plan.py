@@ -58,12 +58,21 @@ def run_pair(a, b, batches):
     assert a.export_items() == b.export_items()
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_randomized_grouped_vs_rank_rounds(seed):
+# Both words of ``now`` cross to the device inside the window's upload
+# (engine.stamp_now).  Both values are above 2**32; NOW's low word has
+# bit 31 set, half a low word later it is clear and the high word is one
+# more.
+NOWS = {"lo31set": NOW, "lo31clear": NOW + (1 << 31)}
+assert NOW >> 32 and NOW & (1 << 31) and not NOWS["lo31clear"] & (1 << 31)
+
+
+@pytest.mark.parametrize("seed,clock", [
+    (0, "lo31set"), (1, "lo31set"), (2, "lo31set"), (0, "lo31clear")])
+def test_randomized_grouped_vs_rank_rounds(seed, clock):
     rng = np.random.default_rng(seed)
     a, b = mk_engines()
     batches = []
-    now = NOW
+    now = NOWS[clock]
     for t in range(8):
         reqs = []
         for _ in range(rng.integers(20, 120)):
@@ -307,6 +316,18 @@ def test_native_pack_window_equals_numpy_chain(kind, n):
                 assert got.flags.c_contiguous
                 np.testing.assert_array_equal(got, exp)
             assert plan_n[4] == plan_ref[4]
+            # ... and both are views of the ONE buffer the window
+            # uploads, ``now`` behind the plan's own words.
+            for plan in (plan_n, plan_ref):
+                buf = plan[5]
+                assert buf.dtype == np.int32 and buf.flags.c_contiguous
+                assert buf.shape == (E.plan_words(b, plan[0].shape[1]),)
+                parts = E.plan_views(buf, b)
+                for part, arr in zip(parts, plan[:4]):
+                    assert np.shares_memory(part, arr)
+                    np.testing.assert_array_equal(part, arr)
+                assert E.join_i32_pair(*parts[4]) == NOW
+            np.testing.assert_array_equal(plan_n[5], plan_ref[5])
     # ... and the slot map is left in the same state.
     every = np.arange(cap)
     assert len(nat) == len(ref)
@@ -417,3 +438,196 @@ def test_grouped_warm_shapes(widths, deep, want):
         assert warmed <= plans and E.group_upad(b) in warmed
         if deep:
             assert warmed == plans
+
+
+# ----------------------------------------------------------------------
+# One upload and one program call a window (TickEngine.submit_columns):
+# the programs behind the buffer against the x64 reference, the counter,
+# and the shapes _warmup compiles.
+# ----------------------------------------------------------------------
+ONE_B, ONE_CAP = 1024, 2048
+ONE_KINDS = {          # kind -> (unique slots, live rows) of its windows
+    "grouped-256": (200, 700), "grouped-512": (400, 900),
+    "grouped-1024": (600, 1000), "unique": (700, 700),
+    "sequential": (300, 700),
+}
+
+
+def _one_window(kind, rng, slots, now, fresh):
+    """A slot-sorted (19, ONE_B) REQ32 matrix over ``slots``: every group
+    uniform (what the grouped plan folds), but in a sequential window,
+    where the hottest slot's second row asks for one hit more."""
+    u, n = ONE_KINDS[kind]
+    lanes = np.sort(np.concatenate(
+        [np.arange(u), rng.integers(0, u, n - u)]))
+    head = np.concatenate([[True], lanes[1:] != lanes[:-1]])
+    per = {                      # drawn per slot: uniform within a group
+        "hits": rng.integers(1, 4, u), "burst": rng.choice([0, 5], u),
+        "limit": rng.choice([5, 20, 1000, 1 << 33], u),
+        # every seventh slot's bucket is over by the second tick, as
+        # the device's ``now`` sees it
+        "duration": np.where(slots % 7 == 0, 1000, 60_000),
+        "created_at": now + rng.integers(0, 50, u),
+    }
+    cols = {k: v[lanes].astype(np.int64) for k, v in per.items()}
+    if kind == "sequential":
+        cols["hits"][np.flatnonzero(~head)[0]] += 1
+    m = np.zeros((E.REQ32_ROWS, ONE_B), np.int32)
+    R = E.REQ32_INDEX
+    m[R["slot"]] = ONE_CAP
+    m[R["slot"], :n] = slots[lanes]
+    m[R["known"], :n] = ~(head & fresh)
+    m[R["algorithm"], :n] = (slots[lanes] % 2)        # token and leaky
+    m[R["behavior"], :n] = np.where(
+        slots[lanes] % 5 == 0, int(Behavior.DRAIN_OVER_LIMIT), 0)
+    m[R["valid"], :n] = 1
+    for name, v in cols.items():
+        E.pack_wide_rows(m, name, v, slice(0, n))
+    return m, n
+
+
+@pytest.mark.parametrize("clock", sorted(NOWS))
+@pytest.mark.parametrize("kind", sorted(ONE_KINDS))
+def test_one_buffer_programs_equal_the_x64_reference(kind, clock):
+    """What ``submit_columns`` dispatches for a grouped window at each
+    head width ``grouped_warm_shapes`` gives its batch width, for a
+    unique and for a sequential one — ONE jitted program on ONE buffer,
+    ``now`` inside it — answers and leaves the table exactly as the x64
+    merge-capable program does, over two ticks of the same keys."""
+    import jax
+    import jax.numpy as jnp
+
+    from gubernator_tpu.ops.buckets import BucketState
+    from gubernator_tpu.ops.tick32 import (
+        jitted_merged_pipeline, jitted_sorted_tick32, jitted_tick32)
+    from tests.helpers import slab_of
+
+    assert [up for w, up in E.grouped_warm_shapes((ONE_B,), True)] == [
+        256, 512, 1024]
+    oracle = E._jitted_tick(ONE_CAP, "columns", sorted_input=True,
+                            compact_resp=True, compact_req=True)
+    rng = np.random.default_rng(sorted(ONE_KINDS).index(kind))
+    slots = np.sort(rng.choice(ONE_CAP, ONE_KINDS[kind][0], replace=False))
+    s_ref = jax.tree.map(jnp.asarray, BucketState.zeros(ONE_CAP))
+    s_got = jax.tree.map(jnp.asarray, BucketState.zeros(ONE_CAP))
+    now = NOWS[clock]
+    for t in range(2):
+        m, n = _one_window(kind, rng, slots, now, fresh=t == 0)
+        plan = E.build_group_plan(m, n, ONE_CAP, now)
+        if kind.startswith("grouped"):
+            assert plan[0].shape[1] == int(kind.split("-")[1])
+            s_got, got = jitted_merged_pipeline(ONE_CAP, "columns")(
+                s_got, jnp.asarray(plan[5]), ONE_B)
+        else:
+            assert plan is None
+            entry = jitted_tick32 if kind == "unique" else jitted_sorted_tick32
+            s_got, got = entry(ONE_CAP, "columns")(
+                s_got, jnp.asarray(slab_of(m, now)))
+        s_ref, ref = oracle(s_ref, jnp.asarray(m), jnp.int64(now))
+        np.testing.assert_array_equal(
+            np.asarray(got)[:, :n], np.asarray(ref)[:, :n])
+        for a, b in zip(jax.tree.leaves(s_got), jax.tree.leaves(s_ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        now += 1500
+
+
+def test_fused_row_branch_takes_the_same_buffer():
+    """``jitted_merged_pipeline``'s other branch, the fused row kernel
+    (interpreted here; what a chip runs), takes the same buffer and
+    answers and leaves the row table as its XLA rows do, over two ticks."""
+    import jax
+    import jax.numpy as jnp
+
+    from gubernator_tpu.ops.rowtable import RowState
+    from gubernator_tpu.ops.tick32 import jitted_merged_pipeline
+
+    kind, now = "grouped-256", NOWS["lo31set"]
+    rng = np.random.default_rng(7)
+    slots = np.sort(rng.choice(ONE_CAP, ONE_KINDS[kind][0], replace=False))
+    states = [jax.tree.map(jnp.asarray, RowState.zeros(ONE_CAP))
+              for _ in range(2)]
+    for t in range(2):
+        m, n = _one_window(kind, rng, slots, now, fresh=t == 0)
+        buf = E.build_group_plan(m, n, ONE_CAP, now)[5]
+        got = []
+        for i, fused in enumerate((True, False)):
+            states[i], resp = jitted_merged_pipeline(
+                ONE_CAP, "row", fused=fused)(
+                    states[i], jnp.asarray(buf), ONE_B)
+            got.append(np.asarray(resp)[:, :n])
+        np.testing.assert_array_equal(got[0], got[1])
+        # the guard row collects padding lanes' scatters on both paths
+        np.testing.assert_array_equal(
+            np.asarray(states[0].table)[:ONE_CAP],
+            np.asarray(states[1].table)[:ONE_CAP])
+        now += 1500
+
+
+ENTRY_OF = {"grouped": "_tick32m", "unique": "_tick32", "sequential": "_tick"}
+
+
+def _kind_window(kind, tag):
+    if kind == "grouped":
+        return [req(f"one-{tag}-{i % 5}", limit=1000) for i in range(20)]
+    if kind == "unique":
+        return [req(f"one-{tag}-{i}", limit=1000) for i in range(8)]
+    return [req(f"one-{tag}", hits=2, limit=1000),
+            req(f"one-{tag}", hits=3, limit=1000)]
+
+
+@pytest.mark.parametrize("kind", sorted(ENTRY_OF))
+def test_one_upload_and_one_program_call_a_window(eng, kind, monkeypatch):
+    """After k windows of a dispatch kind ``metric_h2d_uploads`` and
+    ``metric_h2d_windows`` have both risen by k, ``submit_columns``
+    called ``jnp.asarray`` k times and the kind's jitted entry k times,
+    and no other."""
+    k = 3
+    # Compiles here, and makes the keys known: the shared engine's table
+    # may be full by now, and a new key's reclaim uploads its victims.
+    eng.process(_kind_window(kind, "k"), now=NOW)
+    calls = []
+    for name in ENTRY_OF.values():
+        def counted(*a, _fn=getattr(eng, name), _name=name):
+            calls.append(_name)
+            return _fn(*a)
+        monkeypatch.setattr(eng, name, counted)
+    real = E.jnp.asarray
+
+    def upload(x, *a, **kw):
+        calls.append(type(x).__name__)
+        return real(x, *a, **kw)
+
+    monkeypatch.setattr(E.jnp, "asarray", upload)
+    before = eng.metric_h2d_uploads, eng.metric_h2d_windows
+    for i in range(k):
+        rs = eng.process(_kind_window(kind, "k"), now=NOW + i)
+        assert all(r.error == "" for r in rs)
+    assert eng.metric_h2d_uploads - before[0] == k
+    assert eng.metric_h2d_windows - before[1] == k
+    assert calls == ["ndarray", ENTRY_OF[kind]] * k
+
+
+def test_warmup_meets_every_grouped_shape():
+    """After ``_warmup``, a window of each dispatch kind it compiled
+    (the unique program; the grouped one at every ``grouped_warm_shapes``
+    pair) adds no entry to its jitted function's cache: what ``_warmup``
+    uploads has the served window's shape and type, so no shape is first
+    met under traffic.  An engine of test_layered.py's shape: grouped
+    programs are warmed from 2**14 rows up."""
+    import jax
+
+    e = E.TickEngine(capacity=1 << 14, max_batch=64)
+    shapes = E.grouped_warm_shapes(
+        e._widths, jax.default_backend() == "tpu")
+    assert shapes == [(64, 256)]
+    sizes = e._tick32m._cache_size(), e._tick32._cache_size()
+    assert sizes[0] >= len(shapes) and sizes[1] >= 1
+    e.process(_kind_window("unique", "w"), now=NOW)
+    for w, upad in shapes:
+        reqs = _kind_window("grouped", f"w{upad}")
+        cols = E.ReqColumns.from_requests(reqs)
+        plan = e._build_cols(cols, NOW)[5]
+        assert plan[5].shape == (E.plan_words(w, upad),)
+        e.process(reqs, now=NOW)
+    assert (e._tick32m._cache_size(), e._tick32._cache_size()) == sizes
+    assert e.metric_h2d_uploads == e.metric_h2d_windows == 1 + len(shapes)

@@ -24,6 +24,7 @@ from gubernator_tpu.ops.engine import (
 )
 from gubernator_tpu.ops.tick32 import jitted_layered_pipeline
 from gubernator_tpu.types import Behavior
+from tests.helpers import slab_of
 
 CAP = 1 << 10
 B = 256
@@ -53,8 +54,8 @@ def _layered(state, packed, plan, now):
     fn = jitted_layered_pipeline(CAP, "columns", group_upad(B), KPAD)
     return fn(
         state, jnp.asarray(mh0), jnp.asarray(cnt0), jnp.asarray(mhk),
-        jnp.asarray(cntk), packed, jnp.asarray(uidx), jnp.asarray(rank),
-        jnp.int64(now),
+        jnp.asarray(cntk), jnp.asarray(slab_of(packed, now)),
+        jnp.asarray(uidx), jnp.asarray(rank),
     )
 
 

@@ -356,6 +356,9 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
         assert "breakers" in state and "redelivery" in state
         eng_tel = state["engine"]
         assert 0 < eng_tel["h2d_windows"]
+        # metric_h2d_uploads: one upload a window, whatever its kind
+        assert eng_tel["h2d_uploads"] == eng_tel["h2d_windows"]
+        assert eng_tel["h2d_uploads"] == d.instance.engine.metric_h2d_uploads
         if d.instance.engine.describe()["native_pack"]:
             assert eng_tel["native_pack_windows"] == eng_tel["h2d_windows"]
         # metric_leaky_rows: on the engine, in /debug/state, in Prometheus

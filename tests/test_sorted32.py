@@ -23,6 +23,7 @@ from gubernator_tpu.ops.engine import (
 )
 from gubernator_tpu.ops.tick32 import jitted_sorted_tick32
 from gubernator_tpu.types import Behavior
+from tests.helpers import slab_of
 
 CAP = 1 << 10
 B = 256
@@ -79,7 +80,7 @@ def test_sorted32_matches_oracle(seed):
         s1 = jax.tree.map(jnp.asarray, BucketState.zeros(CAP))
         s2 = jax.tree.map(jnp.asarray, BucketState.zeros(CAP))
         s1, r1 = ORACLE(s1, packed, jnp.int64(NOW))
-        s2, r2 = SORTED32(s2, packed, jnp.int64(NOW))
+        s2, r2 = SORTED32(s2, jnp.asarray(slab_of(packed, NOW)))
         np.testing.assert_array_equal(
             np.asarray(r1)[:, :n], np.asarray(r2)[:, :n])
         for a, b, name in zip(
@@ -100,7 +101,8 @@ def test_sorted32_chains_across_ticks():
     for t in range(3):
         packed, n = _random_batch(rng)
         s1, r1 = ORACLE(s1, packed, jnp.int64(NOW + t * 1000))
-        s2, r2 = SORTED32(s2, packed, jnp.int64(NOW + t * 1000))
+        s2, r2 = SORTED32(
+            s2, jnp.asarray(slab_of(packed, NOW + t * 1000)))
         np.testing.assert_array_equal(
             np.asarray(r1)[:, :n], np.asarray(r2)[:, :n])
     for a, b in zip(jax.tree.leaves(s1), jax.tree.leaves(s2)):

@@ -23,6 +23,7 @@ from gubernator_tpu.ops.buckets import (
 from gubernator_tpu.ops.transition32 import (
     PReq, PResp, PState, transition32)
 from gubernator_tpu.types import Algorithm, Behavior
+from tests.helpers import slab_of
 
 NOW = 1_700_000_000_000
 
@@ -318,7 +319,7 @@ def test_leaky_probe_through_the_served_tick(seed):
         E.pack_wide_rows(m, "limit", limit, lanes)
         E.pack_wide_rows(m, "duration", duration, lanes)
         E.pack_wide_rows(m, "created_at", created, lanes)
-        state, resp = tick(state, jnp.asarray(m), jnp.int64(now))
+        state, resp = tick(state, jnp.asarray(slab_of(m, now)))
         resp = np.asarray(resp)
         got = zip(resp[0, lanes].tolist(),
                   _join(resp[2, lanes], resp[3, lanes]).tolist(),
