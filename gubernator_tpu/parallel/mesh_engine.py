@@ -20,10 +20,25 @@ Maintenance operations — evict, install, restore, readback — run as
 per-shard blocked ``shard_map``s: the host builds one block per shard
 (padding rows aim at the shard's local guard/sentinel) and each device
 applies its block to its own slice.  Because the blocks reuse the
-single-chip ops (`make_tick_fn` etc.) per shard, the mesh engine
+single-chip ops (`make_restore_fn` etc.) per shard, the mesh engine
 supports BOTH table layouts: the int32-column SoA and the Pallas
 row-DMA layout (rowtable.py) — the row layout's ~6-8x tick speedup is not
 forfeited by going multi-chip.
+
+**Both tick programs are the one-chip engine's 32-bit programs** (no
+64-bit emulation on the device; a leaky bucket's steps are IEEE
+binary64 on the bit pattern, ops/b64.py): duplicate-free windows take
+``tick32.make_tick32_rows_fn`` (the fused Pallas ragged kernel on a
+chip's row layout), duplicate-bearing windows
+``tick32.make_sorted_tick32_rows_fn`` (``TickEngine._tick``: exact
+per-slot order, closed-form folds), each walked over the shard's
+extent.  The x64 ``make_tick_fn`` is the tests' reference only.
+
+**The fill is columnar**: :meth:`MeshTickEngine.load_columns` takes the
+Loader v2 snapshot (``TickEngine.load_columns``'s contract for the
+fields the mesh restores), routes the keys with the same CRC-32 batch
+the tick path uses, and lands the rows through the blocked restore;
+``load_items`` is its dict-shaped edge.
 
 **Ragged on-device dispatch (the only tick wire format).**  Keys are
 strings, so hashing and the key→slot map stay host-side (SURVEY.md §7
@@ -66,6 +81,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from gubernator_tpu.ops import rowtable
 from gubernator_tpu.ops.buckets import BucketState, slice_field
 from gubernator_tpu.ops.engine import (
+    SNAP_FIELDS,
+    ZOO_SNAP_FIELDS,
     EVICT_CHUNK,
     ITEM_INT_ROWS,
     READBACK_ROWS,
@@ -81,12 +98,12 @@ from gubernator_tpu.ops.engine import (
     make_layout_choice,
     make_readback_fn,
     make_restore_fn,
-    make_tick_fn,
     masked_over_limit,
     pack_cols_req32,
     pack_wide_rows,
     pad_pow2,
     select_reclaim_victims,
+    snapshot_from_items,
     sort_packed_by_slot,
     unpack_resp_compact,
 )
@@ -94,6 +111,13 @@ from gubernator_tpu.ops.raggedtick import (
     choose_tile,
     make_fused_ragged_tick_fn,
     ragged_walk,
+)
+from gubernator_tpu.ops.reqcols import compact_blob
+from gubernator_tpu.ops.tick32 import (
+    _resolve_fused,
+    make_sorted_tick32_rows_fn,
+    make_tick32_rows_fn,
+    stack6,
 )
 from gubernator_tpu.parallel.partition import (
     LayoutTransition,
@@ -121,8 +145,9 @@ class ShardedOps:
     tick/evict/install/restore/readback, each a shard_map of the
     corresponding single-chip op, jitted with state donation.  Ticks use
     ONE wire format — the ragged flat (19, B) + offsets dispatch (module
-    docstring) — in two programs: the merge-capable x64 extent walker
-    for duplicate-bearing windows and the duplicate-free parts program
+    docstring) — in two 32-bit programs: the sorted duplicate program
+    (``tick32.make_sorted_tick32_rows_fn``, the one-chip ``_tick``) for
+    duplicate-bearing windows and the duplicate-free parts program
     (the fused Pallas ragged kernel on the row layout).
 
     ``trace_counts`` increments once per TRACE of each program (the
@@ -162,13 +187,6 @@ class ShardedOps:
         self.block_sharding2 = lay.shardings(mesh, lay.blocked2())
         self.block_sharding3 = lay.shardings(mesh, lay.blocked3())
 
-        # Compact int32 wire formats (engine.REQ32 / pack_resp_compact):
-        # per-shard request blocks cross host->devices at 76 B/request and
-        # responses return at 24 — the same transfer win the single-chip
-        # engine gets, per PCIe lane on real multi-chip hosts.
-        tick = make_tick_fn(
-            local_capacity, layout=layout, compact_req=True, compact_resp=True
-        )
         evict = make_evict_fn(layout)
         install = make_install_fn(layout)
         restore = make_restore_fn(layout)
@@ -188,6 +206,9 @@ class ShardedOps:
         # extent offsets in; each shard walks only its own
         # [offsets[my], offsets[my+1]) extent of the flat matrix
         # (ops.raggedtick) and the responses gather with one psum.
+        # Compact int32 wire formats (engine.REQ32 / pack_resp_compact):
+        # requests cross host->devices at 76 B each and responses return
+        # at 24, the transfer win the single-chip engine gets.
         n_shards = n
 
         def _extent(offsets, my):
@@ -196,17 +217,35 @@ class ShardedOps:
             lo = my.astype(jnp.int32) * local_capacity
             return start, count, lo
 
-        def _tick_ragged(state_blk, m, offsets, now):
-            self.trace_counts["tick_ragged"] += 1
+        def walk_rows(rows_fn, state_blk, m, offsets, now):
+            """One shard's extent of the flat batch through a
+            single-chip 32-bit rows program, tile by tile; the six
+            response rows merge into zeroed flat lanes."""
             my = lax.axis_index("shard")
             start, count, lo = _extent(offsets, my)
-            st, out = ragged_walk(
-                lambda s_, blk: tick(s_, blk, now),
-                state_blk, m, start, count, lo, local_capacity,
-                choose_tile(m.shape[1], n_shards),
-                jnp.zeros((6, m.shape[1]), jnp.int32),
+            b = m.shape[1]
+
+            def tile_tick(s_, blk):
+                s2, rows = rows_fn(s_, blk, now)
+                return s2, tuple(rows)
+
+            return ragged_walk(
+                tile_tick, state_blk, m, start, count, lo,
+                local_capacity, choose_tile(b, n_shards),
+                tuple(jnp.zeros(b, jnp.int32) for _ in range(6)),
             )
-            return st, lax.psum(out, "shard")
+
+        # Duplicate-bearing windows: the one-chip engine's sorted
+        # duplicate program (exact per-slot order, closed-form folds,
+        # IEEE leaky steps; no 64-bit emulation).  A duplicate group
+        # that straddles two tiles is two sequential ticks of its slot,
+        # the state carried between them (raggedtick module doc).
+        sorted_rows = make_sorted_tick32_rows_fn(local_capacity, layout)
+
+        def _tick_ragged(state_blk, m, offsets, now):
+            self.trace_counts["tick_ragged"] += 1
+            st, rows = walk_rows(sorted_rows, state_blk, m, offsets, now)
+            return st, lax.psum(stack6(rows), "shard")
 
         flat_in = (state_spec, lay.flat2(), lay.offsets1(), lay.scalar())
         self.tick_ragged = jax.jit(
@@ -218,17 +257,11 @@ class ShardedOps:
         )
 
         # The parts-native program for duplicate-free windows (the
-        # production common case): host-dispatched as its OWN program —
-        # not a traced lax.cond next to the x64 tick — so the row layout
-        # keeps the fused Mosaic kernel per shard (Mosaic refuses x64
-        # traces; tick32 module doc).  The fused ragged kernel walks the
-        # extent inside the kernel (runtime chunk count); the unfused
-        # variant tiles it with ragged_walk and returns its six response
-        # rows unstacked (CPU concat-fusion pathology), stack6_ragged
-        # reassembling the (6, B) matrix in its own program.
-        from gubernator_tpu.ops.tick32 import (
-            _resolve_fused, make_tick32_rows_fn)
-
+        # production common case): host-dispatched as its OWN program,
+        # so the row layout keeps the fused Mosaic kernel per shard,
+        # which walks the extent inside the kernel (runtime chunk
+        # count); elsewhere the unfused rows program is tiled by
+        # ragged_walk like the duplicate program.
         self._fused32 = layout == "row" and _resolve_fused(None)
         if self._fused32:
             fused_ragged = make_fused_ragged_tick_fn(local_capacity)
@@ -240,48 +273,21 @@ class ShardedOps:
                 st, resp = fused_ragged(
                     state_blk, m, start, count, lo, now)
                 return st, lax.psum(resp, "shard")
-
-            self.tick_unique_ragged = jax.jit(
-                shard_map(
-                    _tick32_ragged, mesh=mesh, in_specs=flat_in,
-                    out_specs=(state_spec, lay.flat2()), check_vma=False,
-                ),
-                donate_argnums=(0,),
-            )
-            self.stack6_ragged = None
         else:
             tick32_rows = make_tick32_rows_fn(local_capacity, layout)
 
             def _tick32_ragged(state_blk, m, offsets, now):
                 self.trace_counts["tick_unique_ragged"] += 1
-                my = lax.axis_index("shard")
-                start, count, lo = _extent(offsets, my)
-                b = m.shape[1]
+                st, rows = walk_rows(tick32_rows, state_blk, m, offsets, now)
+                return st, lax.psum(stack6(rows), "shard")
 
-                def tile_tick(s_, blk):
-                    s2, rows = tick32_rows(s_, blk, now)
-                    return s2, tuple(rows)
-
-                st, rows = ragged_walk(
-                    tile_tick, state_blk, m, start, count, lo,
-                    local_capacity, choose_tile(b, n_shards),
-                    tuple(jnp.zeros(b, jnp.int32) for _ in range(6)),
-                )
-                return st, tuple(lax.psum(r, "shard") for r in rows)
-
-            self.tick_unique_ragged = jax.jit(
-                shard_map(
-                    _tick32_ragged, mesh=mesh, in_specs=flat_in,
-                    out_specs=(
-                        state_spec, tuple(P(None) for _ in range(6))),
-                    check_vma=False,
-                ),
-                donate_argnums=(0,),
-            )
-            # Second-program stack, same as the single-chip engine
-            # (stacking the six rows inside the tick hits the CPU
-            # concat-fusion pathology; see make_tick32_rows_fn).
-            self.stack6_ragged = jax.jit(lambda rows: jnp.stack(rows, axis=0))
+        self.tick_unique_ragged = jax.jit(
+            shard_map(
+                _tick32_ragged, mesh=mesh, in_specs=flat_in,
+                out_specs=(state_spec, lay.flat2()), check_vma=False,
+            ),
+            donate_argnums=(0,),
+        )
 
         def _evict(state_blk, slots_blk):
             return evict(state_blk, slots_blk[0])
@@ -322,19 +328,11 @@ class ShardedOps:
         )
 
     def init_state(self):
-        return jax.tree.map(
-            lambda a, sh: jax.device_put(a, sh),
-            self.zeros_global(),
-            self.state_shardings,
-        )
-
-    def run_tick_ragged_unique(self, state, m_dev, offsets_dev, now):
-        """Dispatch the duplicate-free ragged tick; returns the flat
-        (6, B) response whichever internal format the backend uses."""
-        state, out = self.tick_unique_ragged(state, m_dev, offsets_dev, now)
-        if self.stack6_ragged is not None:
-            out = self.stack6_ragged(out)
-        return state, out
+        """The zeroed table, made sharded: each chip zero-fills its own
+        shard.  (Made whole on one device and then placed, it cost that
+        device two whole tables: 12.8 GB at 12.5M rows.)"""
+        return jax.jit(
+            self.zeros_global, out_shardings=self.state_shardings)()
 
     def put2(self, blk: np.ndarray):
         return jax.device_put(blk, self.block_sharding2)
@@ -472,6 +470,13 @@ class MeshTickEngine:
         self._inflight = 0
         self.metric_h2d_windows = 0
         self.metric_h2d_overlapped = 0
+        # host->device uploads, over h2d_windows: uploads a window (the
+        # matrix, the offsets and ``now`` cross separately today)
+        self.metric_h2d_uploads = 0
+        # windows by the program that answered them; they add up to
+        # metric_h2d_windows
+        self.metric_dup_windows = 0
+        self.metric_unique_windows = 0
         self.metric_routed_windows = 0
         self.metric_routed_overflows = 0
         self.metric_hits = 0
@@ -490,8 +495,8 @@ class MeshTickEngine:
 
     def _warmup(self) -> None:
         """Compile the serving-path programs at startup (see
-        TickEngine._warmup): both ragged ticks — the merge-capable x64
-        extent walker and the duplicate-free parts program — with an
+        TickEngine._warmup): both ragged ticks — the sorted duplicate
+        program and the duplicate-free parts program — with an
         all-sentinel batch and empty extents (offsets all zero: the
         walkers' dynamic trip counts are runtime values, so the empty
         window compiles the same single program serving traffic uses).
@@ -515,7 +520,7 @@ class MeshTickEngine:
             )
             # guber: allow-G001(init-time warmup D2H - deliberately materializes once at engine construction to pre-compile; never inside a serving tick)
             np.asarray(resp)  # warm the response D2H path
-            self.state, resp = self.ops.run_tick_ragged_unique(
+            self.state, resp = self.ops.tick_unique_ragged(
                 self.state, jnp.asarray(m), jnp.asarray(offs), jnp.int64(0)
             )
             # guber: allow-G001(init-time warmup D2H - same as above)
@@ -543,11 +548,16 @@ class MeshTickEngine:
     def _shard_dead_mask(self, shard: int, now: int) -> np.ndarray:
         """Device-dead mask for one shard's slice of the table."""
         if self.layout == "row":
+            # The shard's own buffer, scanned on its own chip.  A slice
+            # of the sharded array would gather the whole table onto one
+            # device first (12.8 GB at 12.5M rows, which a chip holding
+            # its 1.6 GB shard refuses).
             lo = shard * (self.local_capacity + 1)
+            blk = next(
+                sh.data for sh in self.state.table.addressable_shards
+                if (sh.index[0].start or 0) == lo)
             return rowtable.row_device_dead_mask(
-                RowState(table=self.state.table[lo : lo + self.local_capacity + 1]),
-                now, self.local_capacity,
-            )
+                RowState(table=blk), now, self.local_capacity)
         sl = slice(shard * self.local_capacity, (shard + 1) * self.local_capacity)
         return device_dead_mask(
             self.state.in_use[sl], slice_field(self.state.expire_at, sl),
@@ -656,23 +666,28 @@ class MeshTickEngine:
             return self._resolve_columns_locked(cols, now, errors, n)
 
     @hot_path
-    def _resolve_columns_locked(self, cols, now, errors, n):
+    def _group_by_shard(self, blob, offsets):
+        """Route a packed key batch: ``sh`` (n,) the shard of every key
+        (vectorized CRC-32 over the blob, bit-identical to the scalar
+        ``_shard_of``), and the batch regrouped by shard with one
+        byte-gather — ``order`` (the rows, shard by shard),
+        ``grouped_blob`` / ``g_offsets`` (their keys, in that order) and
+        ``starts`` (n_shards + 1,): shard ``s`` owns rows
+        ``order[starts[s]:starts[s + 1]]``.  The tick path's resolve and
+        the Loader's fill route through here alike."""
         from gubernator_tpu.native import crc32_batch
 
-        # Key → shard (vectorized CRC-32 over the packed key blob).
         sh = (
-            crc32_batch(cols.key_blob, cols.key_offsets)
-            % np.uint32(self.n_shards)
+            crc32_batch(blob, offsets) % np.uint32(self.n_shards)
         ).astype(np.int64)
-
         order = np.argsort(sh, kind="stable")
-        # guber: allow-G001(key_offsets is host numpy, never device)
-        offs = np.asarray(cols.key_offsets, np.int64)
+        # guber: allow-G001(key offsets are host numpy, never device)
+        offs = np.asarray(offsets, np.int64)
         lens = np.diff(offs)
         lo = lens[order]
         so = offs[:-1][order]
         cum = np.cumsum(lo)
-        blob_arr = np.frombuffer(cols.key_blob, np.uint8)
+        blob_arr = np.frombuffer(blob, np.uint8)
         if len(blob_arr):
             gather = (
                 np.arange(int(cum[-1]), dtype=np.int64)
@@ -685,8 +700,13 @@ class MeshTickEngine:
         g_offsets = np.concatenate(
             [np.zeros(1, np.int64), cum]
         )
-        shard_sorted = sh[order]
-        starts = np.searchsorted(shard_sorted, np.arange(self.n_shards + 1))
+        starts = np.searchsorted(sh[order], np.arange(self.n_shards + 1))
+        return sh, order, grouped_blob, g_offsets, starts
+
+    @hot_path
+    def _resolve_columns_locked(self, cols, now, errors, n):
+        sh, order, grouped_blob, g_offsets, starts = self._group_by_shard(
+            cols.key_blob, cols.key_offsets)
 
         slots = np.full(n, -1, np.int64)
         known = np.zeros(n, np.uint8)
@@ -770,9 +790,15 @@ class MeshTickEngine:
             now = now if now is not None else timeutil.now_ms()
             self._tick_count += 1
             errors: Dict[int, str] = {}
+            fr = flightrec.get()
+            t0 = time.perf_counter() if fr is not None else 0.0
             greg_e, greg_d = self._gregorian_cols(cols, now, errors)
             sh, slots, known = self._resolve_columns(cols, now, errors)
             self._account_misses(cols, sh, slots, known, now)
+            if fr is not None:
+                # key -> shard -> slot, the layer the sharded table adds
+                # on the host (the pack span starts after it)
+                fr.note(fr.active(), "route", time.perf_counter() - t0)
             ok = slots >= 0
             for i in errors:
                 ok[i] = False
@@ -820,17 +846,20 @@ class MeshTickEngine:
             dev_m = jnp.asarray(m)
             dev_offs = jnp.asarray(offs)
             if has_dups:
+                self.metric_dup_windows += 1
                 self.state, resp = self.ops.tick_ragged(
                     self.state, dev_m, dev_offs, jnp.int64(now)
                 )
             else:
-                self.state, resp = self.ops.run_tick_ragged_unique(
+                self.metric_unique_windows += 1
+                self.state, resp = self.ops.tick_unique_ragged(
                     self.state, dev_m, dev_offs, jnp.int64(now)
                 )
         if fr is not None:
             fr.note(fr.active(), "h2d", time.perf_counter() - t0)
         self._pending.clear()
         self.metric_routed_windows += 1
+        self.metric_h2d_uploads += 3    # dev_m, dev_offs, now
         wt_args = None
         if self.store is not None:
             wt_args = (cols.refs, list(range(n)), ix, sh, slots, now)
@@ -1092,72 +1121,113 @@ class MeshTickEngine:
                     keys.extend(self.slots[d].keys_batch(sel))
             return items_from_columns(keys, st, live)
 
-    def load_items(self, items: Sequence[dict], now: Optional[int] = None) -> None:
-        """Install snapshot items into the sharded table: route each key to
-        its shard, batch-assign per shard, blocked restore scatters."""
+    def load_columns(self, snap: dict, now: Optional[int] = None) -> None:
+        """Bulk restore from a columnar snapshot — the contract of
+        ``TickEngine.load_columns`` for the fields the mesh restores
+        (``SNAP_FIELDS``, the zoo columns as zeros when absent; no lease
+        columns, no cold tier).  Expired rows are dropped with a
+        vectorized blob compaction; keys are routed as the tick path
+        routes them (:meth:`_group_by_shard`); one native blob-assign a
+        shard maps them, reclaiming once on a full shard; duplicate keys
+        dedup to their LAST occurrence (the row layout's
+        one-DMA-per-slot contract); the data lands through the blocked
+        restore in RESTORE_CHUNK-wide chunks.  No Python loop over
+        keys."""
         with self._lock:
             now = now if now is not None else timeutil.now_ms()
             self._tick_count += 1  # unblock LRU reclaim (see install_globals)
-            # Dedup by key (last wins): duplicate keys resolve to one slot
-            # and two restore rows aimed at the same slot are a data race
-            # in the row layout's DMA scatter (see TickEngine.load_columns).
-            live_by_key = {
-                it["key"]: it for it in items if it["expire_at"] >= now
-            }
-            live = list(live_by_key.values())
-            if not live:
+            # guber: allow-G001(a snapshot is host numpy, never device; _cutover reaches this once a reshard, not per tick)
+            offsets = np.asarray(snap["key_offsets"], np.int64)
+            n = len(offsets) - 1
+            if n == 0:
                 return
-            by_shard: List[List[int]] = [[] for _ in range(self.n_shards)]
-            for j, it in enumerate(live):
-                by_shard[self._shard_of(it["key"])].append(j)
-            lslots = np.full(len(live), -1, np.int64)
-            for d, idxs in enumerate(by_shard):
-                if not idxs:
+            # guber: allow-G001(snapshot columns are host numpy, as above)
+            cols = {f: np.asarray(snap[f]) for f in SNAP_FIELDS}
+            # Pre-zoo snapshots lack the zoo columns: zeros, a fresh
+            # window / TAT, the safe reading (engine.ZOO_SNAP_FIELDS).
+            zeros = np.zeros(n, np.int64)
+            # guber: allow-G001(host numpy, as above)
+            cols.update(
+                (f, np.asarray(snap.get(f, zeros))) for f in ZOO_SNAP_FIELDS)
+            blob = snap["key_blob"]
+            keep = cols["expire_at"] >= now
+            if not keep.all():
+                blob, offsets = compact_blob(blob, offsets, keep)
+                cols = {f: c[keep] for f, c in cols.items()}
+                n = int(keep.sum())
+                if n == 0:
+                    return
+            sh, order, grouped_blob, g_offsets, starts = (
+                self._group_by_shard(blob, offsets))
+            lslots = np.full(n, -1, np.int64)
+            pended = []
+            for d in range(self.n_shards):
+                a, z = int(starts[d]), int(starts[d + 1])
+                if a == z:
                     continue
-                lo = d * self.local_capacity
-                ls = self.slots[d].assign_batch(
-                    [live[j]["key"].encode() for j in idxs]
-                )
-                if (ls < 0).any():  # shard full: reclaim once, retry the rest
-                    # Stamp the rows just assigned live first — device state
-                    # is stale for them until the restore scatter runs, and
-                    # an unstamped reclaim would hand their slots to the
-                    # retried keys (same bug class as build_batch's retry).
-                    got = ls[ls >= 0]
-                    self._last_access[lo + got] = self._tick_count
-                    self._pending.update((lo + got).tolist())
+                off_d = g_offsets[a:z + 1] - g_offsets[a]
+                blob_d = grouped_blob[g_offsets[a]:g_offsets[z]]
+                ls = self.slots[d].assign_blob(blob_d, off_d)
+                full = ls < 0
+                if full.any():  # shard full: reclaim once, retry the rest
+                    # Stamp the rows just assigned live first — device
+                    # state is stale for them until the restore scatter
+                    # runs, and an unstamped reclaim would hand their
+                    # slots to the retried keys.
+                    got = d * self.local_capacity + ls[~full]
+                    self._last_access[got] = self._tick_count
+                    self._pending.update(got.tolist())
+                    pended.append(got)
                     self._reclaim(d, now)
-                    retry = np.flatnonzero(ls < 0)
-                    ls[retry] = self.slots[d].assign_batch(
-                        [live[idxs[r]]["key"].encode() for r in retry]
-                    )
-                lslots[idxs] = ls
-            # Blocked restore: chunk by the widest shard.
+                    ls[full] = self.slots[d].assign_blob(
+                        *compact_blob(blob_d, off_d, full))
+                lslots[order[a:z]] = ls
+            sel = np.flatnonzero(lslots >= 0)  # a shard still full: drop
+            if len(sel) == 0:
+                return
+            # Last-wins dedup by global slot (same key -> same slot):
+            # reverse + first-unique keeps each slot's final occurrence,
+            # ascending by slot, so each shard's rows are contiguous.
+            g = sh[sel] * self.local_capacity + lslots[sel]
+            g_uniq, ridx = np.unique(g[::-1], return_index=True)
+            sel = sel[len(g) - 1 - ridx]
+            self._last_access[g_uniq] = self._tick_count
+            bounds = np.searchsorted(
+                g_uniq, np.arange(self.n_shards + 1) * self.local_capacity)
             per_shard = [
-                [j for j in idxs if lslots[j] >= 0]
-                for idxs in by_shard
+                sel[bounds[d]:bounds[d + 1]] for d in range(self.n_shards)
             ]
-            for d, idxs in enumerate(per_shard):
-                if idxs:
-                    g = d * self.local_capacity + lslots[idxs]
-                    self._last_access[g] = self._tick_count
             for start, w in self._blocked_chunks(per_shard):
-                ints = np.zeros((self.n_shards, len(ITEM_INT_ROWS), w), np.int64)
+                ints = np.zeros(
+                    (self.n_shards, len(ITEM_INT_ROWS), w), np.int64)
                 floats = np.zeros((self.n_shards, w), np.float64)
-                for s, idxs in enumerate(per_shard):
-                    part = idxs[start : start + w]
-                    if not part:
-                        continue
+                for d, rows in enumerate(per_shard):
+                    part = rows[start : start + w]
                     k = len(part)
-                    ints[s, 0, :k] = lslots[part]
+                    if k == 0:
+                        continue
+                    ints[d, 0, :k] = lslots[part]
                     for r, name in enumerate(ITEM_INT_ROWS[1:-1], start=1):
-                        # .get: pre-zoo snapshot items lack tat/prev_count.
-                        ints[s, r, :k] = [live[j].get(name, 0) for j in part]
-                    ints[s, -1, :k] = 1
-                    floats[s, :k] = [live[j]["remaining_f"] for j in part]
+                        ints[d, r, :k] = cols[name][part]
+                    ints[d, -1, :k] = 1  # valid
+                    floats[d, :k] = cols["remaining_f"][part]
                 self.state = self.ops.restore(
                     self.state, self.ops.put3(ints), self.ops.put2(floats)
                 )
+            # The restore has written them: the device's view of these
+            # slots is no longer stale, and a later reclaim may judge
+            # them by it.
+            for got in pended:
+                self._pending.difference_update(got.tolist())
+
+    def load_items(self, items: Sequence[dict], now: Optional[int] = None) -> None:
+        """Install snapshot items into the sharded table (the
+        dict-shaped Loader API edge: one pass builds the columnar
+        snapshot, then :meth:`load_columns` does the real work)."""
+        items = list(items)
+        if not items:
+            return
+        self.load_columns(snapshot_from_items(items), now=now)
 
     # ------------------------------------------------------------------
     # Elastic live resharding (docs/resharding.md).  The n→m transition
@@ -1293,6 +1363,7 @@ class MeshTickEngine:
         self._inflight = 0
         try:
             if items:
+                # guber: allow-G001(once a reshard, not per tick: the items become host numpy columns, no device sync)
                 self.load_items(items, now)
             self._warmup()
         except Exception:

@@ -461,7 +461,9 @@ class V1Instance:
             else:
                 items = conf.loader.load()
                 inst.engine.load_items(list(items))
-        if conf.snapshot_dir and hasattr(inst.engine, "load_columns"):
+        # The snapshot writer needs the columnar export and its dirty
+        # deltas, which the sharded engine lacks.
+        if conf.snapshot_dir and hasattr(inst.engine, "export_columns"):
             await inst._start_persistence()
         # Crash-mid-cutover detection (docs/resharding.md): a begin
         # record with no terminal record means the process died inside a
@@ -1407,8 +1409,18 @@ class V1Instance:
                 self.engine, "export_columns"
             ):
                 self.conf.loader.save_columns(self.engine.export_columns())
-            else:
+            elif hasattr(self.conf.loader, "save"):
                 self.conf.loader.save(self.engine.export_items())
+            else:
+                # A columnar-only Loader on the sharded engine, which
+                # has no export_columns: a dict of every row is not the
+                # save it asked for.
+                self.log.warning(
+                    "Loader %s saves columns only and %s exports none: "
+                    "nothing saved at close",
+                    type(self.conf.loader).__name__,
+                    type(self.engine).__name__,
+                )
         if self.edge_plane is not None:
             # The edge plane's in-flight windows are tick-loop futures
             # holding zero-copy shm views; stop it while the loop can

@@ -144,6 +144,10 @@ class TickLoop:
         self._synced_shed = 0
         self._synced_leaky_rows = 0
         self._synced_routed = 0
+        self._synced_mesh = {
+            "metric_dup_windows": 0, "metric_unique_windows": 0,
+            "metric_h2d_uploads": 0,
+        }
         self._synced_routed_overflows = 0
         self._cond = sanitize.condition("TickLoop._cond")
         self._pending_count = 0
@@ -714,6 +718,16 @@ class TickLoop:
         if routed > self._synced_routed:
             m.mesh_routed_windows.inc(routed - self._synced_routed)
             self._synced_routed = routed
+            # The mesh's windows by the program that answered them, and
+            # its uploads (a one-chip engine routes no window).
+            for name, counter in (
+                ("metric_dup_windows", m.mesh_dup_windows),
+                ("metric_unique_windows", m.mesh_unique_windows),
+                ("metric_h2d_uploads", m.mesh_h2d_uploads),
+            ):
+                value = getattr(self.engine, name)
+                counter.inc(value - self._synced_mesh[name])
+                self._synced_mesh[name] = value
         r_over = getattr(self.engine, "metric_routed_overflows", 0)
         if r_over > self._synced_routed_overflows:
             m.mesh_routed_overflows.inc(

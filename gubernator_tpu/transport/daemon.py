@@ -841,6 +841,11 @@ class Daemon:
                 engine_tel["h2d_uploads"] = eng.metric_h2d_uploads
             engine_tel["h2d_overlap_ratio"] = round(
                 eng.h2d_overlap_ratio(), 4)
+        if hasattr(eng, "metric_dup_windows"):
+            # the sharded engine's windows by the program that answered
+            # them; they add up to h2d_windows
+            engine_tel["dup_windows"] = eng.metric_dup_windows
+            engine_tel["unique_windows"] = eng.metric_unique_windows
         if hasattr(eng, "metric_native_pack_windows"):
             # over h2d_windows: the share of windows the native host
             # pack answered (TickEngine._build_cols)

@@ -2,8 +2,9 @@
 
 A preallocated ring of serving-window records: each window that flows
 through ``TickLoop`` → ``TickEngine``/``MeshTickEngine`` gets one row
-holding its per-stage wall time (decode, arena lease, pack, H2D
-dispatch, tick, resolve, encode) plus queue depth and batch width.
+holding its per-stage wall time (decode, shard routing, arena lease,
+pack, H2D dispatch, tick, resolve, encode) plus queue depth and batch
+width.
 
 Gating mirrors ``tracing.enabled()``: recording happens only while a
 recorder is installed (``install()``), so an un-instrumented daemon pays
@@ -18,6 +19,10 @@ Stage semantics:
   via ``edge()``; decode time accumulates and folds into the *next*
   window begun, encode attaches to the most recently finished window
   (a window's decode is the CPU that fed it; its encode trails it).
+- ``route`` is the sharded engine's alone (zero on one chip): keys to
+  shards by CRC-32, the batch regrouped by shard, one native slot
+  resolve a shard, and the hit/miss accounting; the mesh's ``pack``
+  starts after it.
 - ``pack`` includes the arena ``lease`` (also broken out separately);
   ``ssd`` is the miss path's batched slab-store lookup, broken OUT of
   ``pack`` (the engine subtracts it), so a pack regression can't hide
@@ -45,7 +50,8 @@ from gubernator_tpu.utils.hotpath import hot_path
 from gubernator_tpu.utils import sanitize
 
 STAGES = (
-    "decode", "lease", "pack", "ssd", "h2d", "tick", "resolve", "encode",
+    "decode", "route", "lease", "pack", "ssd", "h2d", "tick", "resolve",
+    "encode",
 )
 _IDX = {s: i for i, s in enumerate(STAGES)}
 _DECODE = _IDX["decode"]

@@ -379,6 +379,25 @@ class Metrics:
             "batch on device).",
             registry=reg,
         )
+        self.mesh_dup_windows = Counter(
+            "gubernator_tpu_mesh_dup_windows",
+            "Sharded serving windows with duplicate keys, answered by "
+            "the sorted 32-bit duplicate program.",
+            registry=reg,
+        )
+        self.mesh_unique_windows = Counter(
+            "gubernator_tpu_mesh_unique_windows",
+            "Sharded serving windows without duplicate keys, answered "
+            "by the duplicate-free program.",
+            registry=reg,
+        )
+        self.mesh_h2d_uploads = Counter(
+            "gubernator_tpu_mesh_h2d_uploads",
+            "Host-to-device uploads the sharded engine issued for its "
+            "serving windows (over mesh_routed_windows: uploads a "
+            "window).",
+            registry=reg,
+        )
         self.mesh_routed_overflows = Counter(
             "gubernator_tpu_mesh_routed_overflows",
             "Pinned-zero canary: the retired routed path's skew "

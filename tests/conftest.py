@@ -104,6 +104,7 @@ LONGEST_FIRST = (
     "test_fuzz_parity",     # 53
     "test_reshard",         # 50
     "test_unit_merge",      # 43
+    "test_mesh_reference",  # 41: a four-shard mesh and a one-chip engine
     "test_service",         # 51
     "test_store",           # 50
     "test_fastwire",        # 41

@@ -25,7 +25,9 @@ from gubernator_tpu.ops.engine import (
 
 CAP = 10_000_000        # BASELINE.json config 3: 5.12 GB of rows in HBM
 SHARDS = 4
-LOCAL_CAP = CAP // SHARDS
+# base3-mixed-10m-mesh4: 12.5M rows (10M keys at an 80 % fill) over the
+# four chips, 1.6 GB of rows a shard
+LOCAL_CAP = 12_500_000 // SHARDS
 B = 4096                # default GUBER_TPU_MAX_BATCH
 I32 = jnp.int32
 
@@ -175,9 +177,14 @@ def test_fused_ragged_tick(mosaic, one_chip):
 
 def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
     """Both programs MeshTickEngine._warmup compiles when the daemon
-    starts with GUBER_TPU_MESH_SHARDS=4: the merge-capable x64 extent
-    walker and the fused ragged kernel, on a four-device mesh with the
-    table split 2.5M rows a shard."""
+    starts with GUBER_TPU_MESH_SHARDS=4: the sorted 32-bit duplicate
+    program walked over each shard's extent and the fused ragged kernel,
+    on a four-device mesh with the table split 3,125,000 rows a shard
+    (``base3-mixed-10m-mesh4``).  Neither holds a 64-bit float (on a
+    TPU that is float32-pair emulation, not IEEE), and the only 64-bit
+    integer in either is the scalar ``now`` each splits on entry."""
+    import re
+
     from gubernator_tpu.parallel.mesh_engine import ShardedOps
 
     mesh = Mesh(np.array(four_chips), ("shard",))
@@ -195,7 +202,10 @@ def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
     fused = ops.tick_unique_ragged.lower(*args).compile()
     assert has_kernel(walker) and has_kernel(fused)
     for c in (walker, fused):
-        assert "all-reduce" in c.as_text()      # the one response psum
+        text = c.as_text()
+        assert "all-reduce" in text             # the one response psum
+        assert "f64[" not in text
+        assert set(re.findall(r"[su]64\[[^\]]*\]", text)) <= {"s64[]"}
         # each device holds its quarter of the table, not all of it
         per_dev = c.memory_analysis().argument_size_in_bytes
         assert per_dev < 2 * (LOCAL_CAP + 1) * rowtable.ROW_W * 4

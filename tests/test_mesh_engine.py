@@ -192,10 +192,20 @@ def test_mesh_row_layout_matches_columns(row_engines):
     assert row.layout == "row" and col.layout == "columns"
     for t in range(3):
         reqs = [req(f"rl{i}", hits=1, limit=7) for i in range(24)]
+        # ...and a duplicate-bearing window, token and leaky: the sorted
+        # 32-bit program's row gathers inside the extent walk, which is
+        # what a chip's sharded table runs
+        if t == 2:
+            reqs = [
+                RateLimitRequest(
+                    name="mesh", unique_key=f"rl{i % 5}", hits=1, limit=7,
+                    duration=60_000, algorithm=(i % 5) % 2)
+                for i in range(16)]
         a = row.process(reqs, now=NOW + t)
         b = col.process(reqs, now=NOW + t)
         assert [(r.status, r.remaining, r.reset_time) for r in a] == \
                [(r.status, r.remaining, r.reset_time) for r in b]
+    assert row.metric_dup_windows == col.metric_dup_windows >= 1
 
 
 def test_mesh_row_layout_snapshot_roundtrip(row_engines):
