@@ -431,7 +431,7 @@ async def start_daemon(report: Report, env: dict):
 
 def print_counters(eng) -> None:
     names = ("_tick_count", "metric_h2d_windows", "metric_h2d_uploads",
-             "metric_h2d_overlapped",
+             "metric_h2d_overlapped", "metric_native_pack_windows",
              "metric_layered_ticks", "metric_hits", "metric_misses",
              "metric_over_limit", "metric_unexpired_evictions")
     print("engine counters: " + " ".join(

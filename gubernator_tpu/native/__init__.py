@@ -32,7 +32,7 @@ _SOURCES = {
 # counts as stale too (rebuilt, or refused with the WARNING), so a
 # library is never bound half-way.
 _NEWEST_SYMBOL = {
-    "libguber_slotmap.so": b"guber_slotmap_pack_window",
+    "libguber_slotmap.so": b"guber_slotmap_pack_window_sharded",
     "libguber_wire.so": b"guber_decode_req",
 }
 _lib: Optional[ctypes.CDLL] = None
@@ -145,20 +145,36 @@ def load_library() -> Optional[ctypes.CDLL]:
     # what lets the gRPC thread and the resolver run beside a wide
     # window's pass; taking it back costs a hand-off (0.1-0.3 ms a time
     # on the served path), which a narrow window's few microseconds of
-    # work do not repay (NativeSlotMap.pack_window chooses).
-    lib.pack_window_gil_held = ctypes.PyDLL(so).guber_slotmap_pack_window
+    # work do not repay (NativeSlotMap.pack_window and
+    # ShardedWindowPass.pack_window choose, by PACK_GIL_FREE_ROWS).
+    held = ctypes.PyDLL(so)
+    u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
+    window = [
+        ctypes.c_char_p, i64p, ctypes.c_int64,     # key blob, offsets, n
+        i64p, i64p, i64p, i64p, i64p, i64p, i64p,  # the request columns
+        ctypes.c_int64, ctypes.c_int64,            # now, stop_on_miss
+        i32p, ctypes.c_int64,                      # slab, its width
+    ]
+    lib.pack_window_gil_held = held.guber_slotmap_pack_window
     for fn in (lib.guber_slotmap_pack_window, lib.pack_window_gil_held):
         fn.restype = ctypes.c_int64
         fn.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, i64p, ctypes.c_int64,
-            i64p, i64p, i64p, i64p, i64p, i64p, i64p,  # the request columns
-            ctypes.c_int64, ctypes.c_int64,            # now, stop_on_miss
-            i32p, ctypes.c_int64,                      # slab, its width
-            i64p, np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS"),
-            i64p,                                      # slots, known, inv
+            ctypes.c_void_p, *window,
+            i64p, u8p, i64p,                           # slots, known, inv
             i64p, ctypes.c_int64,                      # last_access, tick
             np.ctypeslib.ndpointer(np.bool_, flags="C_CONTIGUOUS"),
             i32p, ctypes.c_int64, i64p,                # plan scratch, info
+        ]
+    lib.pack_window_sharded_gil_held = held.guber_slotmap_pack_window_sharded
+    for fn in (lib.guber_slotmap_pack_window_sharded,
+               lib.pack_window_sharded_gil_held):
+        fn.restype = ctypes.c_int64
+        fn.argtypes = [
+            ctypes.POINTER(ctypes.c_void_p), ctypes.c_int64,  # maps, how many
+            ctypes.c_int64, *window,                   # local_capacity
+            i64p, i64p, u8p, i64p,                     # sh, slots, known, inv
+            i64p, ctypes.c_int64,                      # last_access, tick
+            i64p, i64p,                                # rows a shard, info
         ]
     lib.guber_slotmap_mapped.argtypes = [
         ctypes.c_void_p,
@@ -231,6 +247,24 @@ def crc32_batch(blob, offsets: np.ndarray) -> np.ndarray:
     return out
 
 
+def _window_columns(cols, m32: np.ndarray) -> list:
+    """A window's key offsets and seven request columns as the native
+    window passes take them (contiguous int64), checked against each
+    other and against the ``(19, b)`` slab they are to fill."""
+    n = len(cols)
+    columns = [
+        np.ascontiguousarray(c, np.int64) for c in (
+            cols.key_offsets, cols.hits, cols.limit, cols.duration,
+            cols.algorithm, cols.behavior, cols.created_at, cols.burst)
+    ]
+    if len(columns[0]) != n + 1 or any(len(c) != n for c in columns[1:]):
+        raise ValueError("request columns disagree on the row count")
+    rows, b = m32.shape
+    if rows != 19 or n > b:
+        raise ValueError(f"a ({rows}, {b}) slab cannot hold {n} REQ32 lanes")
+    return columns
+
+
 class NativeSlotMap:
     """ctypes wrapper mirroring ops.engine.SlotMap, plus batch resolve."""
 
@@ -298,10 +332,10 @@ class NativeSlotMap:
         )
         return slots, known
 
-    # guber_slotmap_pack_window's statuses (slotmap.cc PackStatus).
+    # The window passes' statuses (slotmap.cc PackStatus).
     PACK_UNIQUE, PACK_GROUPED, PACK_DUPS_NO_PLAN = 0, 1, 2
     PACK_RESOLVED_ONLY, PACK_NOT_TAKEN = -1, -2
-    # Rows from which pack_window drops the GIL for its call (numpy's
+    # Rows from which a window pass drops the GIL for its call (numpy's
     # own ufunc loops drop it above 500 elements).
     PACK_GIL_FREE_ROWS = 512
 
@@ -332,16 +366,8 @@ class NativeSlotMap:
         to the plan's own words, with ``now`` as its (lo, hi) pair
         behind them — the window's one upload (``engine.plan_views``)."""
         n = len(cols)
-        columns = [
-            np.ascontiguousarray(c, np.int64) for c in (
-                cols.key_offsets, cols.hits, cols.limit, cols.duration,
-                cols.algorithm, cols.behavior, cols.created_at, cols.burst)
-        ]
-        if len(columns[0]) != n + 1 or any(len(c) != n for c in columns[1:]):
-            raise ValueError("request columns disagree on the row count")
+        columns = _window_columns(cols, m32)
         rows, b = m32.shape
-        if rows != 19 or n > b:
-            raise ValueError(f"a ({rows}, {b}) slab cannot hold {n} REQ32 lanes")
         if len(last_access) != self.capacity or len(dirty) != self.capacity:
             raise ValueError("per-slot arrays must be capacity long")
         slots = np.empty(n, np.int64)
@@ -421,3 +447,63 @@ class NativeSlotMap:
         from gubernator_tpu.ops.reqcols import pack_blob
 
         return self.assign_blob(*pack_blob(keys))
+
+
+class ShardedWindowPass:
+    """The sharded window pass over one slot map a shard
+    (slotmap.cc guber_slotmap_pack_window_sharded;
+    ``MeshTickEngine._pack_window``): keys to shard, local slot and the
+    slot-sorted slab in one native call, the GIL released for all of it
+    from ``NativeSlotMap.PACK_GIL_FREE_ROWS`` rows."""
+
+    def __init__(self, slot_maps, local_capacity: int):
+        self._maps = list(slot_maps)    # their handles live as long as this
+        self._lib = self._maps[0]._lib
+        self.n_shards = len(self._maps)
+        self.local_capacity = int(local_capacity)
+        self._handles = (ctypes.c_void_p * self.n_shards)(
+            *(sm._h for sm in self._maps))
+
+    def pack_window(self, cols, m32: np.ndarray, now: int,
+                    stop_on_miss: bool, last_access: np.ndarray, tick: int):
+        """One window: CRC-32 of every key ``% n_shards`` -> ``sh``, the
+        key resolved in that shard's map -> ``slots`` (LOCAL) and
+        ``known``, then the leased ``(19, b)`` slab ``m32`` cleaned
+        (zeros, the slot row at the sentinel, the GLOBAL capacity) and
+        the REQ32 rows written into it sorted by global slot
+        ``sh * local_capacity + slot``, each one stamped ``tick`` in
+        ``last_access`` (global capacity long).  It equals the numpy
+        chain of ``MeshTickEngine._pack_window_numpy`` array for array.
+
+        Returns ``(status, sh, slots, known, inv, n_miss, counts,
+        route_s)``: ``counts`` the rows a shard (``RaggedExtents.counts``),
+        ``route_s`` the seconds to the end of the resolve.  Statuses as
+        ``NativeSlotMap.pack_window``: PACK_NOT_TAKEN (a Gregorian row;
+        nothing done), PACK_RESOLVED_ONLY (a key found no slot in its
+        shard, or ``stop_on_miss`` and a key was new; ``sh`` / ``slots``
+        / ``known`` stand, the slab and ``last_access`` are untouched),
+        PACK_UNIQUE or PACK_DUPS_NO_PLAN (packed and sorted, ``inv``
+        maps request order to sorted lanes; slots repeat or not)."""
+        n = len(cols)
+        columns = _window_columns(cols, m32)
+        if len(last_access) != self.n_shards * self.local_capacity:
+            raise ValueError("last_access must be the global capacity long")
+        sh = np.empty(n, np.int64)
+        slots = np.empty(n, np.int64)
+        known = np.empty(n, np.uint8)
+        inv = np.empty(n, np.int64)
+        counts = np.empty(self.n_shards, np.int64)
+        info = np.zeros(2, np.int64)
+        call = (
+            self._lib.guber_slotmap_pack_window_sharded
+            if n >= NativeSlotMap.PACK_GIL_FREE_ROWS
+            else self._lib.pack_window_sharded_gil_held
+        )
+        status = call(
+            self._handles, self.n_shards, self.local_capacity,
+            as_char_p(cols.key_blob), columns[0], n, *columns[1:],
+            now, stop_on_miss, m32, m32.shape[1], sh, slots, known, inv,
+            last_access, tick, counts, info,
+        )
+        n_miss, route_ns = info.tolist()
+        return status, sh, slots, known, inv, n_miss, counts, route_ns * 1e-9

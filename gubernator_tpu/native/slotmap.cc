@@ -10,6 +10,7 @@
 // Exposed as a plain C ABI for ctypes (no pybind11 in the image).
 
 #include <algorithm>
+#include <chrono>
 #include <cstdint>
 #include <cstring>
 #include <string>
@@ -27,6 +28,27 @@ inline uint64_t fnv1a(const char* data, int64_t len) {
     h *= 0x100000001b3ULL;
   }
   return h;
+}
+
+// CRC-32 (ISO-HDLC: poly 0xEDB88320, init/xorout 0xFFFFFFFF),
+// bit-identical to Python's zlib.crc32, which the mesh engine's
+// key->shard router is defined by.
+uint32_t crc32_table[256];
+[[maybe_unused]] const bool crc32_init_done = [] {
+  for (uint32_t i = 0; i < 256; ++i) {
+    uint32_t c = i;
+    for (int k = 0; k < 8; ++k) c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
+    crc32_table[i] = c;
+  }
+  return true;
+}();
+
+inline uint32_t crc32(const char* data, int64_t len) {
+  uint32_t c = 0xFFFFFFFFu;
+  for (int64_t i = 0; i < len; ++i) {
+    c = crc32_table[(c ^ static_cast<uint8_t>(data[i])) & 0xFFu] ^ (c >> 8);
+  }
+  return c ^ 0xFFFFFFFFu;
 }
 
 inline uint64_t next_pow2(uint64_t v) {
@@ -195,6 +217,8 @@ void guber_slotmap_resolve_batch(void* p, const char* blob,
 // Row layout of the (19, b) int32 request slab: engine.REQ32_INDEX.
 // Narrow rows, then (lo, hi) pairs of the int64 columns.
 // ---------------------------------------------------------------------
+}  // extern "C"
+
 namespace {
 
 enum Req32Row : int {
@@ -243,12 +267,93 @@ void sort_lanes(std::vector<uint64_t>& keys, int64_t capacity) {
   }
 }
 
+// The seven int64 request columns of a window, n rows each.
+struct WindowCols {
+  const int64_t *hits, *limit, *duration, *algorithm, *behavior, *created_at,
+      *burst;
+};
+
+inline bool any_gregorian(const WindowCols& c, int64_t n) {
+  for (int64_t i = 0; i < n; ++i) {
+    if (c.behavior[i] & kGregorian) return true;
+  }
+  return false;
+}
+
+// Steps 2 + 3 of a window pass, shared by its two entries: the slab
+// cleaned (zeros, every lane aimed at the sentinel, ``capacity``), the
+// lanes sorted by slot and the REQ32 rows written straight into them.
+// order: (slot << 32 | request row) of every row, sorted here; the slots
+// are the one-chip map's own, or GLOBAL ones over the shards' maps.
+// Stamps last_access[slot] = tick for every lane and, for the one-chip
+// entry (kDirty), marks dirty[slot] where the row moves state; out_inv
+// maps request order to sorted lanes.  Returns whether a slot repeats.
+//
+// Every loop walks ONE row of the slab at a time: its rows lie a power
+// of two apart, so a loop that touched all nineteen at one lane would
+// keep evicting its own cache lines.
+template <bool kDirty>
+bool write_sorted_lanes(std::vector<uint64_t>& order, int64_t capacity,
+                        const WindowCols& c, const uint8_t* known,
+                        int64_t now, int32_t* m32, int64_t b,
+                        int64_t* out_inv, int64_t* last_access, int64_t tick,
+                        uint8_t* dirty, int64_t* n_leaky) {
+  const int64_t n = static_cast<int64_t>(order.size());
+  std::fill_n(m32, kReq32Rows * b, 0);
+  std::fill_n(m32 + kSlot * b, b, static_cast<int32_t>(capacity));
+  sort_lanes(order, capacity);
+  // src[j] is the request row that sorted lane j holds.
+  std::vector<int32_t> src(n);
+  int32_t* slot_row = m32 + kSlot * b;
+  bool has_dups = false;
+  for (int64_t j = 0; j < n; ++j) {
+    int32_t i = src[j] = static_cast<int32_t>(order[j] & 0xFFFFFFFFu);
+    int32_t slot = slot_row[j] = static_cast<int32_t>(order[j] >> 32);
+    has_dups |= j > 0 && slot == slot_row[j - 1];
+    out_inv[i] = j;
+    last_access[slot] = tick;
+    if constexpr (kDirty) {
+      if (c.hits[i] != 0 || !known[i] || (c.behavior[i] & kResetRemaining)) {
+        dirty[slot] = 1;
+      }
+    }
+  }
+  auto put_narrow = [&](int row, auto&& value) {
+    int32_t* out = m32 + row * b;
+    for (int64_t j = 0; j < n; ++j) out[j] = static_cast<int32_t>(value(src[j]));
+  };
+  auto put_wide = [&](int row, auto&& value) {
+    int32_t* lo = m32 + row * b;
+    int32_t* hi = lo + b;
+    for (int64_t j = 0; j < n; ++j) {
+      int64_t v = value(src[j]);
+      lo[j] = static_cast<int32_t>(static_cast<uint32_t>(v));
+      hi[j] = static_cast<int32_t>(v >> 32);
+    }
+  };
+  put_narrow(kKnown, [&](int32_t i) { return known[i]; });
+  int64_t leaky = 0;
+  put_narrow(kAlgorithm, [&](int32_t i) {
+    leaky += c.algorithm[i] == kLeaky;
+    return c.algorithm[i];
+  });
+  *n_leaky = leaky;
+  put_narrow(kBehavior, [&](int32_t i) { return c.behavior[i]; });
+  std::fill_n(m32 + kValid * b, n, 1);
+  put_wide(kHits, [&](int32_t i) { return c.hits[i]; });
+  put_wide(kLimit, [&](int32_t i) { return c.limit[i]; });
+  put_wide(kDuration, [&](int32_t i) { return c.duration[i]; });
+  put_wide(kCreatedAt, [&](int32_t i) {
+    return c.created_at[i] != kCreatedUnset ? c.created_at[i] : now;
+  });
+  put_wide(kBurst, [&](int32_t i) { return c.burst[i]; });
+  return has_dups;
+}
+
 }  // namespace
 
-// Every loop below walks ONE row of the slab at a time: its rows lie a
-// power of two apart, so a loop that touched all nineteen at one lane
-// would keep evicting its own cache lines.
-//
+extern "C" {
+
 // cols: the seven int64 request columns of n rows each, in the order
 // hits, limit, duration, algorithm, behavior, created_at, burst.
 // m32: a leased (19, b) slab, in any state: a pass that packs cleans it
@@ -269,9 +374,9 @@ int64_t guber_slotmap_pack_window(
     uint8_t* out_known, int64_t* out_inv, int64_t* last_access, int64_t tick,
     uint8_t* dirty, int32_t* plan, int64_t plan_cap, int64_t* info) {
   auto* m = static_cast<SlotMap*>(p);
-  for (int64_t i = 0; i < n; ++i) {
-    if (behavior[i] & kGregorian) return kPackNotTaken;
-  }
+  const WindowCols c{hits, limit, duration, algorithm, behavior, created_at,
+                     burst};
+  if (any_gregorian(c, n)) return kPackNotTaken;
 
   // 1. keys -> (slot, known), as guber_slotmap_resolve_batch.
   int64_t n_miss = 0;
@@ -285,59 +390,16 @@ int64_t guber_slotmap_pack_window(
   info[0] = n_miss;
   if (unplaced || (stop_on_miss && n_miss)) return kPackResolvedOnly;
 
-  // 2 + 3. The slab cleaned (zeros, every lane aimed at the sentinel),
-  // then the lanes in slot order and the REQ32 rows written straight
-  // into them: src[j] is the request row that sorted lane j holds.
-  std::fill_n(m32, kReq32Rows * b, 0);
-  std::fill_n(m32 + kSlot * b, b, static_cast<int32_t>(m->capacity));
+  // 2 + 3. The slab cleaned, the lanes in slot order, the REQ32 rows.
   std::vector<uint64_t> order(n);
   for (int64_t i = 0; i < n; ++i) {
     order[i] = (static_cast<uint64_t>(out_slots[i]) << 32) |
                static_cast<uint64_t>(i);
   }
-  sort_lanes(order, m->capacity);
-  std::vector<int32_t> src(n);
-  int32_t* slot_row = m32 + kSlot * b;
-  bool has_dups = false;
-  for (int64_t j = 0; j < n; ++j) {
-    int32_t i = src[j] = static_cast<int32_t>(order[j] & 0xFFFFFFFFu);
-    int32_t slot = slot_row[j] = static_cast<int32_t>(order[j] >> 32);
-    has_dups |= j > 0 && slot == slot_row[j - 1];
-    out_inv[i] = j;
-    last_access[slot] = tick;
-    if (hits[i] != 0 || !out_known[i] || (behavior[i] & kResetRemaining)) {
-      dirty[slot] = 1;
-    }
-  }
-  auto put_narrow = [&](int row, auto&& value) {
-    int32_t* out = m32 + row * b;
-    for (int64_t j = 0; j < n; ++j) out[j] = static_cast<int32_t>(value(src[j]));
-  };
-  auto put_wide = [&](int row, auto&& value) {
-    int32_t* lo = m32 + row * b;
-    int32_t* hi = lo + b;
-    for (int64_t j = 0; j < n; ++j) {
-      int64_t v = value(src[j]);
-      lo[j] = static_cast<int32_t>(static_cast<uint32_t>(v));
-      hi[j] = static_cast<int32_t>(v >> 32);
-    }
-  };
-  put_narrow(kKnown, [&](int32_t i) { return out_known[i]; });
-  int64_t n_leaky = 0;
-  put_narrow(kAlgorithm, [&](int32_t i) {
-    n_leaky += algorithm[i] == kLeaky;
-    return algorithm[i];
-  });
-  info[3] = n_leaky;
-  put_narrow(kBehavior, [&](int32_t i) { return behavior[i]; });
-  std::fill_n(m32 + kValid * b, n, 1);
-  put_wide(kHits, [&](int32_t i) { return hits[i]; });
-  put_wide(kLimit, [&](int32_t i) { return limit[i]; });
-  put_wide(kDuration, [&](int32_t i) { return duration[i]; });
-  put_wide(kCreatedAt, [&](int32_t i) {
-    return created_at[i] != kCreatedUnset ? created_at[i] : now;
-  });
-  put_wide(kBurst, [&](int32_t i) { return burst[i]; });
+  const bool has_dups = write_sorted_lanes<true>(
+      order, m->capacity, c, out_known, now, m32, b, out_inv, last_access,
+      tick, dirty, &info[3]);
+  const int32_t* slot_row = m32 + kSlot * b;
   if (!has_dups) return kPackUnique;
 
   // 4. The grouped plan, by engine.build_group_plan's rules: worth it
@@ -398,6 +460,75 @@ int64_t guber_slotmap_pack_window(
   return kPackGrouped;
 }
 
+// The sharded window pass (mesh_engine.MeshTickEngine._pack_window): the
+// sibling of guber_slotmap_pack_window over n_shards slot maps, one a
+// shard of local_capacity slots.  It is the numpy chain crc32_batch ->
+// _group_by_shard -> one resolve_blob a shard -> pack_cols_req32 ->
+// sort_packed_by_slot -> RaggedExtents.counts of parallel/mesh_engine.py,
+// which stays as the fallback and as the reference
+// tests/test_mesh_reference.py holds this pass to, array for array.
+//
+// 1. CRC-32 of every key % n_shards -> out_sh[i]; the key resolved in
+//    THAT shard's map straight from the blob -> out_slots[i] (LOCAL),
+//    out_known[i].  A shard's keys meet its map in arrival order, as the
+//    regrouped batch's did, so new keys get the same slots.
+// 2 + 3. write_sorted_lanes over the GLOBAL slots sh * local_capacity +
+//    local, the sentinel the global capacity; last_access (global
+//    capacity entries) is stamped with tick.
+// No grouped plan and no dirty marks: the sharded engine has neither.
+//
+// maps: n_shards SlotMap handles.  m32, cols, out_inv: as above.
+// out_counts: n_shards entries, the rows a shard (the extents' widths).
+// info: n_miss, and the nanoseconds from entry to the end of step 1 (the
+//   flight recorder's ``route`` stage; the rest of the call is its
+//   ``pack``).
+// Returns kPackUnique or kPackDupsNoPlan (packed; slots repeat or not),
+// kPackResolvedOnly or kPackNotTaken (as above: the slab untouched).
+int64_t guber_slotmap_pack_window_sharded(
+    void* const* maps, int64_t n_shards, int64_t local_capacity,
+    const char* blob, const int64_t* offsets, int64_t n,
+    const int64_t* hits, const int64_t* limit, const int64_t* duration,
+    const int64_t* algorithm, const int64_t* behavior,
+    const int64_t* created_at, const int64_t* burst, int64_t now,
+    int64_t stop_on_miss, int32_t* m32, int64_t b, int64_t* out_sh,
+    int64_t* out_slots, uint8_t* out_known, int64_t* out_inv,
+    int64_t* last_access, int64_t tick, int64_t* out_counts, int64_t* info) {
+  using clock = std::chrono::steady_clock;
+  const auto t0 = clock::now();
+  const WindowCols c{hits, limit, duration, algorithm, behavior, created_at,
+                     burst};
+  if (any_gregorian(c, n)) return kPackNotTaken;
+
+  int64_t n_miss = 0;
+  bool unplaced = false;
+  std::vector<uint64_t> order(n);
+  std::fill_n(out_counts, n_shards, 0);
+  for (int64_t i = 0; i < n; ++i) {
+    const char* key = blob + offsets[i];
+    const int64_t len = offsets[i + 1] - offsets[i];
+    const int64_t shard = crc32(key, len) % static_cast<uint32_t>(n_shards);
+    const int64_t local =
+        static_cast<SlotMap*>(maps[shard])->resolve(key, len, &out_known[i]);
+    out_sh[i] = shard;
+    out_slots[i] = local;
+    ++out_counts[shard];
+    unplaced |= local < 0;
+    n_miss += !out_known[i];
+    order[i] = (static_cast<uint64_t>(shard * local_capacity + local) << 32) |
+               static_cast<uint64_t>(i);
+  }
+  info[0] = n_miss;
+  info[1] = std::chrono::duration_cast<std::chrono::nanoseconds>(
+                clock::now() - t0).count();
+  if (unplaced || (stop_on_miss && n_miss)) return kPackResolvedOnly;
+
+  int64_t n_leaky;
+  const bool has_dups = write_sorted_lanes<false>(
+      order, n_shards * local_capacity, c, out_known, now, m32, b, out_inv,
+      last_access, tick, nullptr, &n_leaky);
+  return has_dups ? kPackDupsNoPlan : kPackUnique;
+}
+
 // Fill out[slot] = 1 for every slot that currently has a key (the engine's
 // reclaim scan wants the live-slot mask as one array).
 void guber_slotmap_mapped(void* p, uint8_t* out) {
@@ -450,28 +581,12 @@ void guber_slotmap_assign_batch(void* p, const char* blob,
   }
 }
 
-// CRC-32 (ISO-HDLC: poly 0xEDB88320, init/xorout 0xFFFFFFFF) over each key
-// of a packed blob — bit-identical to Python's zlib.crc32, which the mesh
-// engine's key->shard router is defined by.  One call replaces a
-// per-key Python loop on the columnar submit path.
-static uint32_t crc32_table[256];
-static bool crc32_init_done = [] {
-  for (uint32_t i = 0; i < 256; ++i) {
-    uint32_t c = i;
-    for (int k = 0; k < 8; ++k) c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
-    crc32_table[i] = c;
-  }
-  return true;
-}();
-
+// CRC-32 over each key of a packed blob (crc32 above).  One call replaces
+// a per-key Python loop on the columnar submit path.
 void guber_crc32_batch(const char* blob, const int64_t* offsets, int64_t n,
                        uint32_t* out) {
   for (int64_t i = 0; i < n; ++i) {
-    uint32_t c = 0xFFFFFFFFu;
-    for (int64_t j = offsets[i]; j < offsets[i + 1]; ++j) {
-      c = crc32_table[(c ^ static_cast<uint8_t>(blob[j])) & 0xFFu] ^ (c >> 8);
-    }
-    out[i] = c ^ 0xFFFFFFFFu;
+    out[i] = crc32(blob + offsets[i], offsets[i + 1] - offsets[i]);
   }
 }
 

@@ -78,6 +78,7 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from gubernator_tpu.native import NativeSlotMap, ShardedWindowPass
 from gubernator_tpu.ops import rowtable
 from gubernator_tpu.ops.buckets import BucketState, slice_field
 from gubernator_tpu.ops.engine import (
@@ -138,6 +139,16 @@ def make_mesh(devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
     """1-D device mesh over the 'shard' axis (the slot-partition axis)."""
     devices = list(devices if devices is not None else jax.devices())
     return Mesh(np.array(devices), ("shard",))
+
+
+def make_window_pass(slots, local_capacity: int):
+    """The native sharded window pass over a shard set's slot maps
+    (``MeshTickEngine._pack_window``), or None where they are the
+    pure-Python maps (``make_slot_map`` without the native library):
+    those windows take the numpy chain."""
+    if all(isinstance(sm, NativeSlotMap) for sm in slots):
+        return ShardedWindowPass(slots, local_capacity)
+    return None
 
 
 class ShardedOps:
@@ -446,6 +457,11 @@ class MeshTickEngine:
         # the mesh analog of the reference's hash-range→worker routing
         # (workers.go:180-184).
         self.slots = [make_slot_map(self.local_capacity) for _ in range(self.n_shards)]
+        # The host side of a window in one native call (_pack_window):
+        # there with the native slot maps, whose library carries the
+        # pass; and the windows it answered, over metric_h2d_windows.
+        self._window_pass = make_window_pass(self.slots, self.local_capacity)
+        self.metric_native_pack_windows = 0
         self._last_access = np.zeros(self.capacity, np.int64)
         # Global slots assigned host-side but not yet written by a device
         # tick; device in_use/expire_at lag for these, so reclamation must
@@ -491,6 +507,7 @@ class MeshTickEngine:
         return describe_engine(
             self.mesh.devices.flat[0], self.n_shards, self.layout,
             self.ops._fused32, self.warmup_seconds,
+            native_pack=self._window_pass is not None,
         )
 
     def _warmup(self) -> None:
@@ -647,13 +664,17 @@ class MeshTickEngine:
         return greg_e, greg_d
 
     @hot_path
-    def _resolve_columns(self, cols, now: int, errors: Dict[int, str]):
-        """The sharded-slotmap resolve: one vectorized CRC-32 batch
-        routes keys to shards (bit-identical to the scalar ``_shard_of``
-        router — and to the ownership the device derives from the
-        resulting global slot), the key blob regroups by shard with one
-        byte-gather, and one native blob resolve per shard assigns local
-        slots, reclaiming on pressure.  Keys whose shard stays full
+    def _resolve_columns(self, cols, now: int, errors: Dict[int, str],
+                         resolved=None):
+        """The sharded-slotmap resolve in numpy: one vectorized CRC-32
+        batch routes keys to shards (bit-identical to the scalar
+        ``_shard_of`` router — and to the ownership the device derives
+        from the resulting global slot), the key blob regroups by shard
+        with one byte-gather, and one native blob resolve per shard
+        assigns local slots (``resolved``: the native window pass's
+        ``(sh, slots, known)`` where it got that far, so no key is
+        resolved twice).  A shard with a key that found no slot is
+        reclaimed and its keys retried; keys whose shard stays full
         after reclaim become per-item errors (the reference's
         error-in-item convention).  Returns ``(sh, slots, known)`` with
         resolved rows stamped live (``_last_access``/``_pending``)."""
@@ -663,7 +684,7 @@ class MeshTickEngine:
         # captures, and traced windows carry the resolve as a child span.
         with tracing.profile_annotation("guber.mesh.resolve"), \
                 tracing.maybe_span("guber.mesh.resolve", {"batch": n}):
-            return self._resolve_columns_locked(cols, now, errors, n)
+            return self._resolve_columns_locked(cols, now, errors, n, resolved)
 
     @hot_path
     def _group_by_shard(self, blob, offsets):
@@ -704,45 +725,53 @@ class MeshTickEngine:
         return sh, order, grouped_blob, g_offsets, starts
 
     @hot_path
-    def _resolve_columns_locked(self, cols, now, errors, n):
-        sh, order, grouped_blob, g_offsets, starts = self._group_by_shard(
-            cols.key_blob, cols.key_offsets)
+    def _resolve_columns_locked(self, cols, now, errors, n, resolved=None):
+        if resolved is not None:
+            sh, slots, known = resolved
+        else:
+            sh, order, grouped_blob, g_offsets, starts = self._group_by_shard(
+                cols.key_blob, cols.key_offsets)
+            slots = np.full(n, -1, np.int64)
+            known = np.zeros(n, np.uint8)
+            for s in range(self.n_shards):
+                a, z = int(starts[s]), int(starts[s + 1])
+                if a == z:
+                    continue
+                rows_s = order[a:z]
+                off_s = g_offsets[a:z + 1] - g_offsets[a]
+                blob_s = grouped_blob[g_offsets[a]:g_offsets[z]]
+                slots[rows_s], known[rows_s] = self.slots[s].resolve_blob(
+                    blob_s, off_s)
 
-        slots = np.full(n, -1, np.int64)
-        known = np.zeros(n, np.uint8)
-        for s in range(self.n_shards):
-            a, z = int(starts[s]), int(starts[s + 1])
-            if a == z:
-                continue
-            rows_s = order[a:z]
-            off_s = g_offsets[a:z + 1] - g_offsets[a]
-            blob_s = grouped_blob[g_offsets[a]:g_offsets[z]]
-            sm = self.slots[s]
-            sl, kn = sm.resolve_blob(blob_s, off_s)
-            if (sl < 0).any():
-                # Stamp already-resolved rows live before reclaiming
-                # (an unstamped reclaim could hand a just-resolved
-                # slot to the retried keys).
-                okm = sl >= 0
-                g = s * self.local_capacity + sl[okm]
-                self._last_access[g] = self._tick_count
-                self._pending.update(g[kn[okm] == 0].tolist())
-                self._reclaim(s, now)
-                retry = np.flatnonzero(sl < 0)
-                s2, k2 = sm.resolve_batch(
-                    [cols.key_bytes(int(rows_s[t])) for t in retry])
-                sl[retry] = s2
-                kn[retry] = k2
-                for t in np.flatnonzero(sl < 0):
-                    errors[int(rows_s[t])] = (
-                        "rate-limit shard full; eviction failed")
+        # A shard with a key that found no slot: reclaim it, retry its
+        # keys.  (Shards share nothing a reclaim reads or frees, so it
+        # does not matter that the others were resolved before it.)
+        for s in np.unique(sh[slots < 0]).tolist():
+            rows_s = np.flatnonzero(sh == s)
+            sl, kn = slots[rows_s], known[rows_s]
+            # Stamp already-resolved rows live before reclaiming
+            # (an unstamped reclaim could hand a just-resolved
+            # slot to the retried keys).
+            okm = sl >= 0
+            g = s * self.local_capacity + sl[okm]
+            self._last_access[g] = self._tick_count
+            self._pending.update(g[kn[okm] == 0].tolist())
+            self._reclaim(s, now)
+            retry = np.flatnonzero(sl < 0)
+            s2, k2 = self.slots[s].resolve_batch(
+                [cols.key_bytes(int(rows_s[t])) for t in retry])
+            sl[retry] = s2
+            kn[retry] = k2
+            for t in np.flatnonzero(sl < 0):
+                errors[int(rows_s[t])] = (
+                    "rate-limit shard full; eviction failed")
             slots[rows_s] = sl
             known[rows_s] = kn
 
-        resolved = slots >= 0
-        g_res = sh[resolved] * self.local_capacity + slots[resolved]
+        placed = slots >= 0
+        g_res = sh[placed] * self.local_capacity + slots[placed]
         self._last_access[g_res] = self._tick_count
-        self._pending.update(g_res[known[resolved] == 0].tolist())
+        self._pending.update(g_res[known[placed] == 0].tolist())
         return sh, slots, known
 
     @hot_path
@@ -790,46 +819,98 @@ class MeshTickEngine:
             now = now if now is not None else timeutil.now_ms()
             self._tick_count += 1
             errors: Dict[int, str] = {}
+            # Flight-recorder stage notes, mirroring the single-chip
+            # TickEngine.submit_columns: "route" is keys -> shard -> slot
+            # and the hit/miss accounting, the layer the sharded table
+            # adds on the host; "pack" the slab rows, the slot sort and
+            # the extent offsets (the lease is broken out beside it).
             fr = flightrec.get()
             t0 = time.perf_counter() if fr is not None else 0.0
-            greg_e, greg_d = self._gregorian_cols(cols, now, errors)
-            sh, slots, known = self._resolve_columns(cols, now, errors)
-            self._account_misses(cols, sh, slots, known, now)
+            # The native window pass cleans the slab it packs.
+            m = self._staging.lease(
+                self.max_batch, clean=self._window_pass is None)
             if fr is not None:
-                # key -> shard -> slot, the layer the sharded table adds
-                # on the host (the pack span starts after it)
-                fr.note(fr.active(), "route", time.perf_counter() - t0)
-            ok = slots >= 0
-            for i in errors:
-                ok[i] = False
+                fr.note(fr.active(), "lease", time.perf_counter() - t0)
+                t0 = time.perf_counter()
+            sh, slots, ix, inv, has_dups, offs, route_s = self._pack_window(
+                cols, now, m, errors)
+            if fr is not None:
+                spent = time.perf_counter() - t0
+                fr.note(fr.active(), "route", route_s)
+                fr.note(fr.active(), "pack", spent - route_s)
             return self._dispatch_ragged(
-                cols, now, sh, slots, known, ok, greg_e, greg_d, errors,
-            )
+                cols, now, m, sh, slots, ix, inv, has_dups, offs, errors)
 
     @hot_path
-    def _dispatch_ragged(
-        self, cols, now, sh, slots, known, ok, greg_e, greg_d, errors
-    ) -> "MeshRaggedTickHandle":
-        """The ragged flat dispatch: pack ONE slot-sorted (19, B)
-        compact matrix carrying GLOBAL slots into a leased staging
-        slab, derive the per-shard extent offsets from the resolve's
-        counts (partition.RaggedExtents — the slot sort groups shards
-        contiguously in ascending order), and upload both with async
-        ``jnp.asarray`` copies (the transfer rides under the previous
-        window's tick; the uncommitted signatures match warmup, so
-        re-dispatch reuses the compiled program).  Each shard walks
-        only its own extent on device — no per-shard host loop, no
-        padded per-shard block, responses gathered with one psum."""
+    def _pack_window(self, cols, now: int, m: np.ndarray,
+                     errors: Dict[int, str]):
+        """One window's host side: keys to shards and slots, and the
+        leased slab ``m`` as ONE slot-sorted (19, B) compact matrix
+        carrying GLOBAL slots.  Returns ``(sh, slots, ix, inv, has_dups,
+        offs, route_s)``: the route and the LOCAL slots in request
+        order, ``ix`` the rows packed (None: all of them), ``inv`` the
+        request -> sorted-lane permutation, ``offs`` the per-shard
+        extent offsets (partition.RaggedExtents — the slot sort groups
+        shards contiguously in ascending order) and ``route_s`` the
+        seconds of the call that went to routing and accounting.
+
+        The window the served path sees all day takes ONE native call
+        (native/slotmap.cc guber_slotmap_pack_window_sharded, the
+        sibling of the one-chip engine's window pass; for a wide window
+        ctypes drops the GIL for all of it, where the numpy chain is
+        some sixty to eighty short calls that each drop it and wait to
+        take it back beside the event loop and the resolver).  What the
+        batch shows decides, no knob: a Gregorian row (host calendar
+        math), a key that finds no slot in its shard (reclaim and
+        retry), or a new key with a Store to ask first takes
+        :meth:`_pack_window_numpy` — from the slots the native pass
+        already resolved, where it got that far.  So do the pure-Python
+        slot maps (no native library)."""
+        wp = self._window_pass
+        resolved = None
+        if wp is not None:
+            n = len(cols)
+            with tracing.profile_annotation("guber.mesh.pack_window"), \
+                    tracing.maybe_span("guber.mesh.pack_window", {"batch": n}):
+                status, sh, slots, known, inv, n_miss, counts, route_s = (
+                    wp.pack_window(
+                        cols, m, now, self.store is not None,
+                        self._last_access, self._tick_count))
+            if status >= 0:
+                t0 = time.perf_counter()
+                if n_miss:
+                    g = sh * self.local_capacity + slots
+                    self._pending.update(g[known == 0].tolist())
+                self.metric_hits += n - n_miss
+                self.metric_misses += n_miss
+                self.metric_native_pack_windows += 1
+                return (sh, slots, None, inv,
+                        status != NativeSlotMap.PACK_UNIQUE,
+                        self.ragged.offsets(counts),
+                        route_s + time.perf_counter() - t0)
+            if status == NativeSlotMap.PACK_RESOLVED_ONLY:
+                resolved = sh, slots, known
+            self._staging.clean(m)   # the pass left the slab as leased
+        return self._pack_window_numpy(cols, now, m, errors, resolved)
+
+    @hot_path
+    def _pack_window_numpy(self, cols, now: int, m: np.ndarray,
+                           errors: Dict[int, str], resolved=None):
+        """:meth:`_pack_window` in numpy, for the windows the native
+        pass leaves (and for the pure-Python slot maps), into a clean
+        slab: Gregorian rows, the resolve (``resolved``: see
+        :meth:`_resolve_columns`), hit/miss accounting and Store
+        read-through, then the REQ32 rows of the rows that found a slot,
+        one argsort and the extents' counts."""
         n = len(cols)
-        b = self.max_batch
-        # Flight-recorder stage notes + named ranges/spans, mirroring the
-        # single-chip TickEngine.submit_columns instrumentation.
-        fr = flightrec.get()
-        t0 = time.perf_counter() if fr is not None else 0.0
-        m = self._staging.lease(b)
-        if fr is not None:
-            fr.note(fr.active(), "lease", time.perf_counter() - t0)
-            t0 = time.perf_counter()
+        t0 = time.perf_counter()
+        greg_e, greg_d = self._gregorian_cols(cols, now, errors)
+        sh, slots, known = self._resolve_columns(cols, now, errors, resolved)
+        self._account_misses(cols, sh, slots, known, now)
+        route_s = time.perf_counter() - t0
+        ok = slots >= 0
+        for i in errors:
+            ok[i] = False
         ix = np.flatnonzero(ok)
         gslot = sh[ix] * self.local_capacity + slots[ix]
         pack_cols_req32(m, cols, gslot, known[ix], now, ix)
@@ -837,9 +918,22 @@ class MeshTickEngine:
         pack_wide_rows(m, "greg_dur", greg_d[ix], ix)
         inv, has_dups = sort_packed_by_slot(m, n, self.capacity)
         offs = self.ragged.offsets(self.ragged.counts(sh, ok))
-        if fr is not None:
-            fr.note(fr.active(), "pack", time.perf_counter() - t0)
-            t0 = time.perf_counter()
+        return sh, slots, ix, inv, has_dups, offs, route_s
+
+    @hot_path
+    def _dispatch_ragged(
+        self, cols, now, m, sh, slots, ix, inv, has_dups, offs, errors
+    ) -> "MeshRaggedTickHandle":
+        """The ragged flat dispatch of a packed window
+        (:meth:`_pack_window`): the slab and the extent offsets go up
+        with async ``jnp.asarray`` copies (the transfer rides under the
+        previous window's tick; the uncommitted signatures match warmup,
+        so re-dispatch reuses the compiled program).  Each shard walks
+        only its own extent on device — no per-shard host loop, no
+        padded per-shard block, responses gathered with one psum."""
+        n = len(cols)
+        fr = flightrec.get()
+        t0 = time.perf_counter() if fr is not None else 0.0
         with tracing.profile_annotation("guber.mesh.tick"), \
                 tracing.maybe_span("guber.mesh.dispatch_ragged",
                                    {"batch": n}):
@@ -862,6 +956,8 @@ class MeshTickEngine:
         self.metric_h2d_uploads += 3    # dev_m, dev_offs, now
         wt_args = None
         if self.store is not None:
+            if ix is None:
+                ix = np.arange(n)
             wt_args = (cols.refs, list(range(n)), ix, sh, slots, now)
         handle = MeshRaggedTickHandle(
             self, resp, n, inv, errors, cols.limit, wt_args
@@ -1318,6 +1414,7 @@ class MeshTickEngine:
             self.max_batch,
         )
         ops = ShardedOps(mesh, tr.cap_to, layout)
+        slots = [make_slot_map(tr.cap_to) for _ in range(tr.n_to)]
         # The ragged extent spec IS the new layout's dispatch geometry:
         # post-cutover windows derive their offsets against cap_to's
         # ownership from this object — nothing width-shaped survives to
@@ -1327,7 +1424,7 @@ class MeshTickEngine:
             capacity=tr.capacity_to, layout=layout,
             ragged=RaggedExtents(tr.n_to, tr.cap_to),
             ops=ops, state=ops.init_state(),
-            slots=[make_slot_map(tr.cap_to) for _ in range(tr.n_to)],
+            slots=slots, window_pass=make_window_pass(slots, tr.cap_to),
             last_access=np.zeros(tr.capacity_to, np.int64),
             staging=StagingRing(
                 REQ32_ROWS, tr.capacity_to, self._staging_slabs,
@@ -1347,6 +1444,7 @@ class MeshTickEngine:
             self.mesh, self.n_shards, self.local_capacity, self.capacity,
             self.ragged, self.layout, self.ops, self.state,
             self.slots, self._last_access, self._staging, self._pending,
+            self._window_pass,
         )
         self.mesh = new.mesh
         self.n_shards = new.n_shards
@@ -1357,6 +1455,7 @@ class MeshTickEngine:
         self.ops = new.ops
         self.state = new.state
         self.slots = new.slots
+        self._window_pass = new.window_pass
         self._last_access = new.last_access
         self._staging = new.staging
         self._pending = set()
@@ -1371,7 +1470,7 @@ class MeshTickEngine:
                 self.mesh, self.n_shards, self.local_capacity,
                 self.capacity, self.ragged, self.layout, self.ops,
                 self.state, self.slots, self._last_access, self._staging,
-                self._pending,
+                self._pending, self._window_pass,
             ) = saved
             raise
 

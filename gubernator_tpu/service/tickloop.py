@@ -146,7 +146,7 @@ class TickLoop:
         self._synced_routed = 0
         self._synced_mesh = {
             "metric_dup_windows": 0, "metric_unique_windows": 0,
-            "metric_h2d_uploads": 0,
+            "metric_native_pack_windows": 0, "metric_h2d_uploads": 0,
         }
         self._synced_routed_overflows = 0
         self._cond = sanitize.condition("TickLoop._cond")
@@ -718,11 +718,13 @@ class TickLoop:
         if routed > self._synced_routed:
             m.mesh_routed_windows.inc(routed - self._synced_routed)
             self._synced_routed = routed
-            # The mesh's windows by the program that answered them, and
-            # its uploads (a one-chip engine routes no window).
+            # The mesh's windows by the program that answered them,
+            # those the native window pass packed, and its uploads (a
+            # one-chip engine routes no window).
             for name, counter in (
                 ("metric_dup_windows", m.mesh_dup_windows),
                 ("metric_unique_windows", m.mesh_unique_windows),
+                ("metric_native_pack_windows", m.mesh_native_pack_windows),
                 ("metric_h2d_uploads", m.mesh_h2d_uploads),
             ):
                 value = getattr(self.engine, name)

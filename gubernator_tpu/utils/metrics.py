@@ -391,6 +391,13 @@ class Metrics:
             "by the duplicate-free program.",
             registry=reg,
         )
+        self.mesh_native_pack_windows = Counter(
+            "gubernator_tpu_mesh_native_pack_windows",
+            "Sharded serving windows whose host side (keys to shard and "
+            "slot, the slot-sorted slab) was the one native window pass "
+            "(over mesh_routed_windows: the share it answered).",
+            registry=reg,
+        )
         self.mesh_h2d_uploads = Counter(
             "gubernator_tpu_mesh_h2d_uploads",
             "Host-to-device uploads the sharded engine issued for its "

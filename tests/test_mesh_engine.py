@@ -410,6 +410,33 @@ def test_mesh_store_write_and_read_through(store_engines):
     assert store.called["Get()"] - gets0 >= 1
 
 
+def test_native_pass_leaves_a_store_miss_to_the_numpy_chain(store_engines):
+    """On a Store-backed engine a window with a new key stops the native
+    window pass at the miss (the Store is asked first: the numpy chain
+    and ``_read_through``, from the slots the pass resolved); a window
+    of known keys is the pass's, write-through and all."""
+    store, (eng, eng2) = store_engines
+    if eng._window_pass is None:
+        pytest.skip("native slotmap library unavailable")
+    reqs = [req("np1", hits=1, limit=10), req("np2", hits=2, limit=10)]
+    packed0, gets0 = eng.metric_native_pack_windows, store.called["Get()"]
+    out = eng.process(reqs, now=NOW)
+    assert [r.remaining for r in out] == [9, 8]
+    assert eng.metric_native_pack_windows == packed0
+    assert store.called["Get()"] - gets0 == 2
+    out = eng.process(reqs, now=NOW + 1)
+    assert [r.remaining for r in out] == [8, 6]
+    assert eng.metric_native_pack_windows == packed0 + 1
+    assert store.called["Get()"] - gets0 == 2
+    assert store.data["mesh_np1"]["remaining"] == 8
+    assert store.data["mesh_np2"]["remaining"] == 6
+    # the engine that never saw the keys reads them through, in numpy
+    packed2 = eng2.metric_native_pack_windows
+    out = eng2.process(reqs[:1], now=NOW + 2)
+    assert out[0].remaining == 7
+    assert eng2.metric_native_pack_windows == packed2
+
+
 def test_mesh_store_via_instance_config():
     """The service layer no longer refuses Store + mesh shards."""
     import asyncio

@@ -7,8 +7,11 @@ population (``base3-mixed-10m-mesh4``'s, small) filled through
 cell's ``correct`` asks on the chip; the one-chip ``TickEngine`` gives
 the same answers; the reference one precision below (leaky arithmetic
 rounded to float32) does not.  And the fill itself: ``load_columns``
-against ``load_items``.
+against ``load_items``.  And the host side of a window: the native
+sharded window pass against the numpy chain, array for array.
 """
+
+import zlib
 
 import jax
 import numpy as np
@@ -17,11 +20,15 @@ import pytest
 from benchmarks.harness import population, traffic
 from benchmarks.harness.reference import Reference
 from benchmarks.tests.test_precision_control import Float32Leaky
-from gubernator_tpu.ops.engine import TickEngine, items_from_snapshot
+from gubernator_tpu.native import NativeSlotMap
+from gubernator_tpu.ops.engine import (
+    REQ32_INDEX, REQ32_ROWS, TickEngine, items_from_snapshot)
 from gubernator_tpu.ops.raggedtick import choose_tile
-from gubernator_tpu.ops.reqcols import ReqColumns
-from gubernator_tpu.parallel.mesh_engine import MeshTickEngine, make_mesh
-from gubernator_tpu.utils import flightrec
+from gubernator_tpu.ops.reqcols import CREATED_UNSET, ReqColumns, pack_blob
+from gubernator_tpu.parallel.mesh_engine import (
+    MeshTickEngine, make_mesh, make_window_pass)
+from gubernator_tpu.types import Behavior
+from gubernator_tpu.utils import flightrec, timeutil
 
 SHARDS = 4
 KEYS = 3000
@@ -209,11 +216,13 @@ def test_load_columns_reclaims_a_full_shard_once(layout):
 
 
 def test_route_stage_and_dispatch_counters(mesh):
-    """``route`` is in the recorder's totals after a mesh window, and the
+    """``route`` and ``pack`` are in the recorder's totals after a mesh
+    window, the native pass on, and it answered every one of them; the
     two dispatch counters add up to ``metric_h2d_windows``."""
     pop = population.Population(SPEC, 3)
     rec = flightrec.FlightRecorder(windows=8)
     flightrec.install(rec)
+    before = mesh.metric_native_pack_windows, mesh.metric_h2d_windows
     try:
         for ids in (np.arange(50), np.asarray([1, 1, 2, 3])):
             wid = rec.begin(len(ids), 0)
@@ -223,12 +232,220 @@ def test_route_stage_and_dispatch_counters(mesh):
     finally:
         flightrec.uninstall()
     stages = [w["stages_ms"] for w in rec.recent()]
-    assert len(stages) == 2 and all(s["route"] > 0 for s in stages)
+    assert len(stages) == 2
+    assert all(s["route"] > 0 and s["pack"] > 0 for s in stages)
     assert "route" in flightrec.STAGES
+    if mesh._window_pass is not None:
+        assert (mesh.metric_native_pack_windows - before[0]
+                == mesh.metric_h2d_windows - before[1] == 2)
     assert mesh.metric_dup_windows >= 1 and mesh.metric_unique_windows >= 1
     assert (mesh.metric_dup_windows + mesh.metric_unique_windows
             == mesh.metric_h2d_windows)
     assert mesh.metric_h2d_uploads == 3 * mesh.metric_h2d_windows
+
+
+# ----------------------------------------------------------------------
+# The native sharded window pass (native/slotmap.cc
+# guber_slotmap_pack_window_sharded) against the numpy chain it stands
+# in for, array for array.  No device program runs: the shared engine's
+# slot maps and host arrays are swapped for fresh ones a run.
+# ----------------------------------------------------------------------
+PASS_KINDS = ("unique", "zipf", "one_shard", "new_keys")
+
+
+def on_shard(shard, count, tag):
+    """``count`` keys that CRC-32 routes to ``shard`` of SHARDS."""
+    keys, i = [], 0
+    while len(keys) < count:
+        k = b"%s_%d" % (tag, i)
+        if zlib.crc32(k) % SHARDS == shard:
+            keys.append(k)
+        i += 1
+    return keys
+
+
+def window_case(kind, n, rng):
+    """(ReqColumns of n rows, the keys mapped before the window)."""
+    if kind == "unique":
+        ranks = rng.permutation(n)
+    else:   # a hot head: the hottest key is a tenth of the rows
+        ranks = np.minimum(rng.zipf(1.2, n) - 1, 2 * n)
+        ranks[rng.random(n) < 0.1] = 0
+    if kind == "one_shard":
+        pool = on_shard(2, int(ranks.max()) + 1, b"wp")
+        keys = [pool[r] for r in ranks.tolist()]
+    else:
+        keys = [b"wp_%d" % r for r in ranks.tolist()]
+    col = lambda v: np.asarray(v, np.int64)  # noqa: E731
+    blob, offsets = pack_blob(keys)
+    cols = ReqColumns(
+        blob, offsets,
+        hits=col(ranks % 3),
+        limit=col(np.where(ranks % 11 == 0, 1 << 33, 10 + ranks % 7)),
+        duration=col(np.full(n, 60_000)),
+        algorithm=col(ranks % 2),
+        behavior=col(np.where(ranks % 5 == 0,
+                              int(Behavior.DRAIN_OVER_LIMIT), 0)),
+        created_at=col(np.where(ranks % 3 == 0, T0 + 7, CREATED_UNSET)),
+        burst=col(ranks % 2 * 5))
+    seed = sorted(set(keys))
+    if kind == "new_keys":
+        seed = seed[::2]
+    return cols, keys, seed
+
+
+@pytest.mark.parametrize("n", [1, 50, 4000])
+@pytest.mark.parametrize("kind", PASS_KINDS)
+def test_native_window_pass_equals_numpy_chain(mesh, monkeypatch, kind, n):
+    if mesh._window_pass is None:
+        pytest.skip("native slotmap library unavailable")
+    rng = np.random.default_rng(n * 31 + PASS_KINDS.index(kind))
+    cols, keys, seed = window_case(kind, n, rng)
+    cap = mesh.local_capacity
+    b = B if n <= B else 4096
+    R = REQ32_INDEX
+
+    def run(native):
+        slots = [NativeSlotMap(cap) for _ in range(SHARDS)]
+        for d, sm in enumerate(slots):
+            sm.assign_batch([k for k in seed if zlib.crc32(k) % SHARDS == d])
+        monkeypatch.setattr(mesh, "slots", slots)
+        monkeypatch.setattr(
+            mesh, "_window_pass",
+            make_window_pass(slots, cap) if native else None)
+        monkeypatch.setattr(
+            mesh, "_last_access", np.zeros(mesh.capacity, np.int64))
+        monkeypatch.setattr(mesh, "_pending", set())
+        before = (mesh.metric_hits, mesh.metric_misses,
+                  mesh.metric_native_pack_windows)
+        # the pass cleans the slab it packs, whatever it held; the numpy
+        # chain is handed a clean one
+        m = rng.integers(-9, 9, (REQ32_ROWS, b)).astype(np.int32)
+        if not native:
+            mesh._staging.clean(m)
+        errors = {}
+        sh, sl, ix, inv, has_dups, offs, route_s = mesh._pack_window(
+            cols, T0, m, errors)
+        assert not errors and route_s > 0
+        assert ix is None or (ix == np.arange(n)).all()
+        counted = tuple(
+            now - was for now, was in zip(
+                (mesh.metric_hits, mesh.metric_misses,
+                 mesh.metric_native_pack_windows), before))
+        every = np.arange(cap)
+        return dict(
+            slab=m, sh=sh, slots=sl, inv=inv, has_dups=has_dups, offs=offs,
+            known=m[R["known"], inv], last_access=mesh._last_access,
+            pending=set(mesh._pending), counted=counted,
+            maps=[sm.keys_batch(every) for sm in slots])
+
+    nat = run(native=True)
+    # the window's keys live where the host ring says, once each
+    assert mesh.routing_parity_errors([k.decode() for k in keys]) == 0
+    ref = run(native=False)
+    assert nat.pop("counted")[2] == 1 and ref.pop("counted")[2] == 0
+    for name, want in ref.items():
+        if isinstance(want, np.ndarray):
+            assert nat[name].dtype == want.dtype, name
+            np.testing.assert_array_equal(nat[name], want, err_msg=name)
+        else:
+            assert nat[name] == want, name
+
+    # ... and both are what the window says, read without either.
+    want_sh = np.asarray([zlib.crc32(k) % SHARDS for k in keys])
+    np.testing.assert_array_equal(nat["sh"], want_sh)
+    seen, want_known = set(seed), []
+    for k in keys:
+        want_known.append(k in seen)
+        seen.add(k)
+    np.testing.assert_array_equal(nat["known"], want_known)
+    np.testing.assert_array_equal(
+        np.diff(nat["offs"]), np.bincount(want_sh, minlength=SHARDS))
+    assert nat["has_dups"] == (len(set(keys)) < n)
+    g = nat["sh"] * cap + nat["slots"]
+    assert nat["pending"] == set(g[~np.asarray(want_known)].tolist())
+    assert (nat["last_access"][g] == mesh._tick_count).all()
+    if kind == "one_shard":
+        assert (nat["sh"] == 2).all()
+    if kind == "new_keys" and n > 1:
+        assert nat["pending"]
+
+
+@pytest.mark.parametrize("kind", ["plain", "gregorian", "shard_reclaimed",
+                                  "shard_full"])
+def test_native_pass_engages_by_what_the_batch_shows(mesh, one_chip, kind):
+    """``metric_native_pack_windows`` rises by one for the window the
+    served path sees all day, and not for a Gregorian row or a key that
+    finds its shard full (reclaimed and retried; where nothing can be
+    freed, the per-item error); those take the numpy chain and answer
+    as before.  (A Store's new key: tests/test_mesh_engine.py, on its
+    store-backed engines.)"""
+    if mesh._window_pass is None:
+        pytest.skip("native slotmap library unavailable")
+    pop = population.Population(SPEC, 3)
+    cap = mesh.local_capacity
+    ids = np.arange(100, 140)
+    cols = columns(pop, ids, T0 + 5, 10)
+    n = len(cols)
+    # three more rows, their keys new and all on shard 1
+    fresh = on_shard(1, 3, b"bench10_%s" % kind.encode())
+    blob, offsets = pack_blob(
+        [cols.key_bytes(i) for i in range(n)] + fresh)
+    grow = lambda c, v: np.concatenate([c, np.full(3, v, np.int64)])  # noqa: E731
+    cols = ReqColumns(
+        blob, offsets, hits=grow(cols.hits, 1), limit=grow(cols.limit, 10),
+        duration=grow(cols.duration, 60_000),
+        algorithm=grow(cols.algorithm, 0), behavior=grow(cols.behavior, 0),
+        created_at=grow(cols.created_at, T0 + 5), burst=grow(cols.burst, 0))
+    if kind == "gregorian":
+        cols.behavior[n] = int(Behavior.DURATION_IS_GREGORIAN)
+        cols.duration[n] = timeutil.GREGORIAN_MINUTES
+
+    lo, fillers, reclaimed = cap, [], []
+    if kind.startswith("shard_"):
+        # Fill shard 1's map from the host: the device never saw these
+        # keys, so a reclaim finds them dead and frees them ...
+        sm = mesh.slots[1]
+        room = cap - len(sm)
+        fillers = sm.assign_batch(on_shard(1, room, b"filler_%s" % kind.encode()))
+        assert len(sm) == cap and (fillers >= 0).all()
+        stamps = mesh._last_access[lo:lo + cap].copy()
+        if kind == "shard_full":
+            # ... unless the shard's every slot counts as touched by the
+            # coming tick: nothing to free, expired or least recent.
+            mesh._last_access[lo:lo + cap] = mesh._tick_count + 1
+        reclaim = mesh._reclaim
+        mesh._reclaim = lambda *a: (reclaimed.append(a[0]), reclaim(*a))[1]
+    before = mesh.metric_native_pack_windows, mesh.metric_h2d_windows
+    try:
+        rm, errors = mesh.submit_columns(cols, now=T0).result()
+    finally:
+        if len(fillers):
+            del mesh._reclaim
+            # the fillers a reclaim did not free (a freed one's slot may
+            # be a fresh key's by now)
+            left = np.asarray([s for s in fillers.tolist() if (
+                sm.key_of(s) or "").startswith("filler_")], np.int64)
+            sm.release_batch(left)
+            mesh._last_access[lo:lo + cap] = stamps
+    assert mesh.metric_h2d_windows - before[1] == 1
+    assert (mesh.metric_native_pack_windows - before[0]
+            == (kind == "plain"))
+    want, want_errors = one_chip.submit_columns(cols, now=T0).result()
+    assert not want_errors
+    if kind == "shard_full":
+        assert errors == {i: "rate-limit shard full; eviction failed"
+                          for i in range(n, n + 3)}
+        served_rows = np.arange(n)
+    else:
+        assert not errors
+        served_rows = np.arange(n + 3)
+    assert reclaimed == ([1] if kind.startswith("shard_") else [])
+    np.testing.assert_array_equal(
+        np.asarray(rm)[:4, served_rows], np.asarray(want)[:4, served_rows])
+    if kind == "gregorian":
+        assert rm[3, n] == timeutil.gregorian_expiration(
+            T0, timeutil.GREGORIAN_MINUTES)
 
 
 def test_the_x64_program_is_not_the_mesh_engines():

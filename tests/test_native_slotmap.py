@@ -84,6 +84,47 @@ def test_native_tombstone_rehash_stays_correct():
         assert len(sm) == 0
 
 
+@pytest.mark.parametrize("n_shards", [1, 3, 4])
+def test_sharded_window_pass_routes_by_crc32(n_shards):
+    """The sharded window pass sends every key to ``zlib.crc32(key) %
+    n_shards`` (keys of length 0, 1 and 4,096 among them) and maps it in
+    that shard's map alone: ``routing_parity_errors`` stays 0."""
+    import threading
+    import zlib
+
+    from gubernator_tpu.native import ShardedWindowPass
+    from gubernator_tpu.ops.reqcols import ReqColumns, pack_blob
+    from gubernator_tpu.parallel.mesh_engine import MeshTickEngine
+
+    rng = np.random.default_rng(n_shards)
+    keys = [b"", b"a", b"k" * 4096, b"name_key"] + [
+        b"k%d" % int(rng.integers(0, 1 << 40)) for _ in range(60)]
+    n, cap = len(keys), 64
+    maps = [NativeSlotMap(cap) for _ in range(n_shards)]
+    blob, offsets = pack_blob(keys)
+    ones = np.ones(n, np.int64)
+    cols = ReqColumns(blob, offsets, hits=ones, limit=ones * 10,
+                      duration=ones * 60_000, algorithm=ones * 0,
+                      behavior=ones * 0, created_at=ones * 5, burst=ones * 0)
+    status, sh, slots, known, inv, n_miss, counts, route_s = ShardedWindowPass(
+        maps, cap).pack_window(
+            cols, np.empty((19, 64), np.int32), 5, False,
+            np.zeros(n_shards * cap, np.int64), 1)
+    want = [zlib.crc32(k) % n_shards for k in keys]
+    assert status == NativeSlotMap.PACK_UNIQUE and n_miss == n
+    assert sh.tolist() == want and not known.any()
+    assert counts.tolist() == np.bincount(want, minlength=n_shards).tolist()
+    for k, d, slot in zip(keys, want, slots.tolist()):
+        assert [sm.get(k.decode()) for sm in maps] == [
+            slot if i == d else None for i in range(n_shards)]
+    # the audit the reshard coordinator runs, on an engine that is only
+    # its routing state (no device program)
+    eng = MeshTickEngine.__new__(MeshTickEngine)
+    eng.n_shards, eng.local_capacity, eng.slots = n_shards, cap, maps
+    eng._lock = threading.RLock()
+    assert eng.routing_parity_errors([k.decode() for k in keys]) == 0
+
+
 # ----------------------------------------------------------------------
 # The loader: ``*.so`` is git-ignored, so the library on disk is whatever
 # an earlier checkout built — it must be rebuilt when its source is
@@ -133,8 +174,8 @@ def test_fallback_without_a_toolchain_warns(native_copy, monkeypatch, caplog):
 def test_library_without_the_newest_symbol_is_stale(
         native_copy, monkeypatch, caplog, toolchain):
     """A library an older checkout built can be newer than its source and
-    still lack ``guber_slotmap_pack_window``: it is rebuilt, or refused
-    with the WARNING, never bound half-way."""
+    still lack ``guber_slotmap_pack_window_sharded``: it is rebuilt, or
+    refused with the WARNING, never bound half-way."""
     import logging
     import os
     import shutil
@@ -153,8 +194,19 @@ def test_library_without_the_newest_symbol_is_stale(
         path = native.library_path("libguber_slotmap.so")
     if toolchain:
         assert path == str(so) and not native._stale("libguber_slotmap.so")
-        assert b"guber_slotmap_pack_window" in so.read_bytes()
+        assert b"guber_slotmap_pack_window_sharded" in so.read_bytes()
     else:
         assert path is None
-        assert "guber_slotmap_pack_window" in caplog.text
+        assert "guber_slotmap_pack_window_sharded" in caplog.text
         assert "pure-Python fallback" in caplog.text
+
+
+def test_a_library_with_the_one_chip_pass_alone_is_stale(native_copy):
+    """The library of the commit before the sharded window pass exports
+    ``guber_slotmap_pack_window`` and not its sharded sibling: stale, so
+    a sharded engine never falls to the numpy chain in silence."""
+    so = native_copy / "libguber_slotmap.so"
+    so.write_bytes(b"\x7fELF guber_slotmap_pack_window\0guber_crc32_batch\0")
+    assert native._stale("libguber_slotmap.so")
+    so.write_bytes(so.read_bytes() + b"guber_slotmap_pack_window_sharded\0")
+    assert not native._stale("libguber_slotmap.so")
