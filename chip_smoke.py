@@ -432,7 +432,9 @@ async def start_daemon(report: Report, env: dict):
 def print_counters(eng) -> None:
     names = ("_tick_count", "metric_h2d_windows", "metric_h2d_uploads",
              "metric_h2d_overlapped", "metric_native_pack_windows",
-             "metric_layered_ticks", "metric_hits", "metric_misses",
+             "metric_unique_ticks", "metric_grouped_ticks",
+             "metric_sequential_ticks", "metric_layered_ticks",
+             "metric_hits", "metric_misses",
              "metric_over_limit", "metric_unexpired_evictions")
     print("engine counters: " + " ".join(
         f"{n.lstrip('_')}={getattr(eng, n)}" for n in names if hasattr(eng, n)),

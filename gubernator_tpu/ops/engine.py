@@ -2478,6 +2478,13 @@ class TickEngine:
         # that take the float64 leaky path (ops/b64.py).
         self.metric_leaky_rows = 0
         self.metric_unexpired_evictions = 0
+        # Windows by the dispatch branch that answered them
+        # (submit_columns): the four add up to metric_h2d_windows.  In a
+        # trace the programs are jit_tick32_unique / _grouped /
+        # _sequential / _layered (ops/tick32.py).
+        self.metric_unique_ticks = 0
+        self.metric_grouped_ticks = 0
+        self.metric_sequential_ticks = 0
         self.metric_layered_ticks = 0
         # Tiering telemetry: cold lookups that hit on the miss path,
         # batched restore scatters the promote path dispatched (and the
@@ -3277,6 +3284,7 @@ class TickEngine:
                     # the elementwise expansion — a k-deep hot key costs
                     # one row of HBM traffic, not k.  The slab is not
                     # uploaded: the plan holds its head columns.
+                    self.metric_grouped_ticks += 1
                     self.state, resp = self._tick32m(
                         self.state, jnp.asarray(plan[5]), packed.shape[1]
                     )
@@ -3318,13 +3326,19 @@ class TickEngine:
                             jnp.asarray(uidx), jnp.asarray(rank),
                         )
                     else:
-                        # Adversarial shapes (over-deep/over-wide unit
-                        # structure, unprovable head liveness): the
+                        # No plan: too few followers to pay for one (a
+                        # large uniform population at BatchLimit repeats
+                        # a few dozen keys a window: base2-leaky-1m's
+                        # closed cell lands here in every window), or an
+                        # adversarial shape (over-deep/over-wide unit
+                        # structure, unprovable head liveness).  The
                         # sequential chained-unit program is always
                         # correct.
+                        self.metric_sequential_ticks += 1
                         self.state, resp = self._tick(self.state, dev_m)
                 else:
                     # The common serving shape.
+                    self.metric_unique_ticks += 1
                     dev_m = jnp.asarray(packed)
                     self.state, resp = self._tick32(self.state, dev_m)
             if fr is not None:

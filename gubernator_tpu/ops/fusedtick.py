@@ -233,6 +233,7 @@ def make_fused_tick_fn(capacity: int, chunk: int | None = None):
                 input_output_aliases={3: 0},  # table input -> table output
                 compiler_params=_VMEM,
                 interpret=_interpret(),
+                name="fused_tick32",
             )(slots, now2, m32, state.table)
         return state._replace(table=table), resp
 
@@ -441,6 +442,7 @@ def make_fused_merged_tick_fn(capacity: int, chunk: int | None = None):
                 input_output_aliases={3: 0},
                 compiler_params=_VMEM,
                 interpret=_interpret(),
+                name="fused_merged_tick32",
             )(slots, now2, m20, state.table)
         return state._replace(table=table), resp
 

@@ -197,6 +197,7 @@ def scatter_rows(table: jnp.ndarray, slots: jnp.ndarray,
             input_output_aliases={2: 0},
             compiler_params=_COMPILER_PARAMS,
             interpret=_interpret(),
+            name="scatter_rows",
         )(slots, rows, table)
 
 
@@ -218,6 +219,7 @@ def gather_rows(table: jnp.ndarray, slots: jnp.ndarray) -> jnp.ndarray:
             out_shape=jax.ShapeDtypeStruct((b, w), jnp.int32),
             compiler_params=_COMPILER_PARAMS,
             interpret=_interpret(),
+            name="gather_rows",
         )(slots, table)
 
 

@@ -187,14 +187,18 @@ def make_tick32_fn(capacity: int, layout: str = "columns",
     return tick
 
 
-def _jit_on_slab(tick):
+def _jit_on_slab(tick, name: str):
     """(state, m32, now) tick → the engine's ONE jitted program of
     ``(state, slab)``, state donated: the REQ32 rows and ``now`` both
-    come out of the window's one upload (:func:`split_slab`)."""
+    come out of the window's one upload (:func:`split_slab`).  ``name``
+    is what the program is called in a trace (module ``jit_<name>``):
+    the unique and the sequential program share this wrapper and must
+    not share a name."""
 
     def run(state, slab):
         return tick(state, *split_slab(slab))
 
+    run.__name__ = run.__qualname__ = name
     return jax.jit(run, donate_argnums=(0,))
 
 
@@ -204,7 +208,8 @@ def jitted_tick32(capacity: int, layout: str = "columns",
     """Engine entry for unique-slot windows: (state, slab (SLAB_ROWS, B))
     → (state, (6, B) compact responses), one program call on one upload
     — the fused Pallas row kernel, or the XLA rows with their stack."""
-    return _jit_on_slab(make_tick32_fn(capacity, layout, fused))
+    return _jit_on_slab(make_tick32_fn(capacity, layout, fused),
+                        "tick32_unique")
 
 
 # ----------------------------------------------------------------------
@@ -323,7 +328,7 @@ def jitted_layered_pipeline(capacity: int, layout: str, w0: int,
         tickk = make_fused_merged_tick_fn(
             capacity, chunk=min(2048, layer_width))
 
-        def run_inner(state, mh0, cnt0, mhk, cntk, slab, uidx, rank):
+        def tick32_layered(state, mh0, cnt0, mhk, cntk, slab, uidx, rank):
             _, now = split_slab(slab)
             state, r24_0 = tick0(state, mh0, cnt0, now)   # (W0, 24)
 
@@ -341,11 +346,11 @@ def jitted_layered_pipeline(capacity: int, layout: str, w0: int,
             return state, jnp.stack(
                 expand32_rowmajor(flat24, uidx, rank))
 
-        return jax.jit(run_inner, donate_argnums=(0,))
+        return jax.jit(tick32_layered, donate_argnums=(0,))
 
     core = make_merged_tick32_rows_fn(capacity, layout)
 
-    def run_inner(state, mh0, cnt0, mhk, cntk, slab, uidx, rank):
+    def tick32_layered(state, mh0, cnt0, mhk, cntk, slab, uidx, rank):
         m32, now = split_slab(slab)
         state, rows0 = core(state, mh0, cnt0, now)
 
@@ -372,7 +377,7 @@ def jitted_layered_pipeline(capacity: int, layout: str, w0: int,
         ]
         return state, jnp.stack(_expand_sorted(flat15, m32, uidx, rank))
 
-    return jax.jit(run_inner, donate_argnums=(0,))
+    return jax.jit(tick32_layered, donate_argnums=(0,))
 
 
 # ----------------------------------------------------------------------
@@ -589,7 +594,7 @@ def jitted_sorted_tick32(capacity: int, layout: str = "columns",
         state, rows = rows_fn(state, m32, now)
         return state, stack6(rows)
 
-    return _jit_on_slab(tick)
+    return _jit_on_slab(tick, "tick32_sequential")
 
 
 @functools.lru_cache(maxsize=None)
@@ -628,11 +633,12 @@ def jitted_merged_pipeline(capacity: int, layout: str = "columns",
         def expand(rows, mhead, uidx, rank):
             return stack6(expand32_rows(tuple(rows), mhead, uidx, rank))
 
-    def run(state, buf, b):
+    def tick32_grouped(state, buf, b):
         from gubernator_tpu.ops.engine import plan_views
 
         mhead, count, uidx, rank, now = plan_views(buf, b)
         state, heads = tick(state, mhead, count, p64.I64(now[0], now[1]))
         return state, expand(heads, mhead, uidx, rank)
 
-    return jax.jit(run, donate_argnums=(0,), static_argnums=(2,))
+    return jax.jit(
+        tick32_grouped, donate_argnums=(0,), static_argnums=(2,))

@@ -42,6 +42,7 @@ from gubernator_tpu.admission import (
 from gubernator_tpu.types import RateLimitRequest, RateLimitResponse, Status
 from gubernator_tpu.utils import flightrec
 from gubernator_tpu.utils.hotpath import hot_path
+from gubernator_tpu.utils.metrics import TICK_BRANCHES
 
 _EMPTY_MATRIX = np.zeros((5, 0), np.int64)
 
@@ -148,6 +149,7 @@ class TickLoop:
             "metric_dup_windows": 0, "metric_unique_windows": 0,
             "metric_native_pack_windows": 0, "metric_h2d_uploads": 0,
         }
+        self._synced_branch = dict.fromkeys(TICK_BRANCHES, 0)
         self._synced_routed_overflows = 0
         self._cond = sanitize.condition("TickLoop._cond")
         self._pending_count = 0
@@ -681,6 +683,15 @@ class TickLoop:
         if leaky > self._synced_leaky_rows:
             m.leaky_rows.inc(leaky - self._synced_leaky_rows)
             self._synced_leaky_rows = leaky
+        # The one-chip engine's windows by the dispatch branch that
+        # answered them (the sharded engine counts its own two below).
+        if hasattr(self.engine, "metric_sequential_ticks"):
+            for branch in TICK_BRANCHES:
+                value = getattr(self.engine, f"metric_{branch}_ticks")
+                if value > self._synced_branch[branch]:
+                    m.tick_windows.labels(program=branch).inc(
+                        value - self._synced_branch[branch])
+                    self._synced_branch[branch] = value
         if cold is not None:
             demos = cold.metric_demotions
             if demos > self._synced_demotions:

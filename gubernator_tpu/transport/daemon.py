@@ -40,7 +40,7 @@ from gubernator_tpu.transport.grpc_api import V1Stub, peers_handler, v1_handler
 from gubernator_tpu.transport.tlsutil import TLSBundle, setup_tls
 from gubernator_tpu.types import GlobalUpdate, PeerInfo
 from gubernator_tpu.utils import flightrec, tracing
-from gubernator_tpu.utils.metrics import Metrics
+from gubernator_tpu.utils.metrics import TICK_BRANCHES, Metrics
 
 log = logging.getLogger("gubernator.daemon")
 
@@ -846,6 +846,13 @@ class Daemon:
             # them; they add up to h2d_windows
             engine_tel["dup_windows"] = eng.metric_dup_windows
             engine_tel["unique_windows"] = eng.metric_unique_windows
+        if hasattr(eng, "metric_sequential_ticks"):
+            # the one-chip engine's windows by the dispatch branch (and
+            # so the program, jit_tick32_<branch>) that answered them;
+            # they add up to h2d_windows
+            for branch in TICK_BRANCHES:
+                engine_tel[f"{branch}_ticks"] = getattr(
+                    eng, f"metric_{branch}_ticks")
         if hasattr(eng, "metric_native_pack_windows"):
             # over h2d_windows: the share of windows the native host
             # pack answered (TickEngine._build_cols)

@@ -29,6 +29,11 @@ from prometheus_client.core import HistogramMetricFamily
 
 CONTENT_TYPE_LATEST = "text/plain; version=0.0.4; charset=utf-8"
 
+# The one-chip engine's dispatch branches (TickEngine.submit_columns):
+# its counter of each is ``metric_<branch>_ticks``, its program in a
+# device trace ``jit_tick32_<branch>``.
+TICK_BRANCHES = ("unique", "grouped", "sequential", "layered")
+
 
 def log_buckets(lo: float, hi: float, per_decade: int = 4) -> Tuple[float, ...]:
     """Fixed log-spaced bucket bounds from ``lo`` up to at least ``hi``."""
@@ -500,6 +505,15 @@ class Metrics:
             "gubernator_tpu_leaky_rows",
             "Rows dispatched to the device with algorithm LEAKY_BUCKET: "
             "the decisions that take the float64 leaky path.",
+            registry=reg,
+        )
+        self.tick_windows = Counter(
+            "gubernator_tpu_tick_windows",
+            "Serving windows of the one-chip engine by the dispatch "
+            "branch that answered them; label \"program\" is "
+            "unique/grouped/sequential/layered, the jit_tick32_<program> "
+            "of a device trace.",
+            ["program"],
             registry=reg,
         )
 

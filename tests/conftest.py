@@ -88,7 +88,7 @@ def pytest_configure(config):
 # (CHANGES.md, PR 27: 348 s of wall in this order); a file not listed
 # runs after these, in its usual place.
 LONGEST_FIRST = (
-    "test_chip_compile",    # 283
+    "test_chip_compile",    # 283 (two more sequential shapes, PR 36)
     "test_group_plan",      # 190 (39 before PR 33's one-buffer programs)
     "test_fusedtick",       # 143
     "test_mesh_engine",     # 135
@@ -105,6 +105,7 @@ LONGEST_FIRST = (
     "test_reshard",         # 50
     "test_unit_merge",      # 43
     "test_mesh_reference",  # 41: a four-shard mesh and a one-chip engine
+    "test_base2_reference", # 41-56: a 32,768-row engine, three programs
     "test_service",         # 51
     "test_store",           # 50
     "test_fastwire",        # 41

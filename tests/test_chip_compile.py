@@ -79,6 +79,14 @@ def has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def named(compiled, program, *kernels) -> bool:
+    """A trace tells the tick programs apart by these: the module is
+    ``jit_<program>``, each Pallas call carries its ``name=``."""
+    text = compiled.as_text()
+    return (f"HloModule jit_{program}" in text
+            and all(k in text for k in kernels))
+
+
 def row_state(cap, sharding):
     return rowtable.RowState(
         table=sds((cap + 1, rowtable.ROW_W), I32, sharding))
@@ -111,6 +119,7 @@ def test_fused_tick(mosaic, one_chip):
         row_state(CAP, one_chip), sds((SLAB_ROWS, B), I32, one_chip),
     ).compile()
     assert has_kernel(c) and no_int64(c)
+    assert named(c, "tick32_unique", "fused_tick32")
 
 
 # At the default GUBER_TPU_MAX_BATCH the batch widths are 1024 and 4096:
@@ -130,17 +139,22 @@ def test_fused_merged_tick(mosaic, one_chip, shape):
         b,
     ).compile()
     assert has_kernel(c) and no_int64(c)
+    assert named(c, "tick32_grouped", "fused_merged_tick32")
 
 
-def test_sorted_tick32_on_rows(mosaic, one_chip):
+# CAP, and base2-leaky-1m's table (1M keys at an 80 % fill) at both
+# batch widths: every window of its closed cell is this program's.
+@pytest.mark.parametrize("cap,b", [(CAP, B), (1_250_000, B), (1_250_000, 1024)])
+def test_sorted_tick32_on_rows(mosaic, one_chip, cap, b):
     """A sequential window: the slab in, rounds and stack one program."""
     from gubernator_tpu.ops.tick32 import jitted_sorted_tick32
 
-    fn = jitted_sorted_tick32(CAP, "row")
+    fn = jitted_sorted_tick32(cap, "row")
     c = fn.lower(
-        row_state(CAP, one_chip), sds((SLAB_ROWS, B), I32, one_chip),
+        row_state(cap, one_chip), sds((SLAB_ROWS, b), I32, one_chip),
     ).compile()
     assert has_kernel(c) and no_int64(c)
+    assert named(c, "tick32_sequential", "gather_rows", "scatter_rows")
 
 
 def test_layered_pipeline_at_warmup_shape(mosaic, one_chip):
@@ -160,6 +174,7 @@ def test_layered_pipeline_at_warmup_shape(mosaic, one_chip):
         sds((w,), I32, one_chip), sds((w,), I32, one_chip),
     ).compile()
     assert has_kernel(c)
+    assert named(c, "tick32_layered", "fused_merged_tick32")
 
 
 def test_fused_ragged_tick(mosaic, one_chip):

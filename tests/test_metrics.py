@@ -14,7 +14,7 @@ from gubernator_tpu.config import (
     DaemonConfig,
     parse_metric_flags,
 )
-from gubernator_tpu.utils.metrics import Metrics
+from gubernator_tpu.utils.metrics import TICK_BRANCHES, Metrics
 
 
 def test_parse_metric_flags_reference_names():
@@ -361,6 +361,15 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
         assert eng_tel["h2d_uploads"] == d.instance.engine.metric_h2d_uploads
         if d.instance.engine.describe()["native_pack"]:
             assert eng_tel["native_pack_windows"] == eng_tel["h2d_windows"]
+        # the windows by the dispatch branch that answered them: on the
+        # engine, in /debug/state, in Prometheus; four distinct keys a
+        # call, so every window here is the unique program's
+        by_branch = {b: eng_tel[f"{b}_ticks"] for b in TICK_BRANCHES}
+        assert by_branch == dict(dict.fromkeys(TICK_BRANCHES, 0),
+                                 unique=eng_tel["h2d_windows"])
+        assert d.instance.engine.metric_unique_ticks == by_branch["unique"]
+        assert d.metrics.sample("gubernator_tpu_tick_windows_total",
+                                {"program": "unique"}) == by_branch["unique"]
         # metric_leaky_rows: on the engine, in /debug/state, in Prometheus
         assert d.instance.engine.metric_leaky_rows == 3
         assert eng_tel["leaky_rows"] == 3
