@@ -43,11 +43,13 @@ the tick path uses, and lands the rows through the blocked restore;
 **Ragged on-device dispatch (the only tick wire format).**  Keys are
 strings, so hashing and the key→slot map stay host-side (SURVEY.md §7
 "Host/device split") — but everything else is gone from the host: the
-tick ships ONE flat slot-sorted (19, B) compact matrix carrying GLOBAL
-slots plus a ``(n_shards + 1,)`` cumulative offsets vector
+tick ships ONE buffer, the flat slot-sorted (19, B) compact matrix
+carrying GLOBAL slots and, in the slab's tail after it, ``now`` as two
+int32 words and the ``(n_shards + 1,)`` cumulative offsets
 (:class:`partition.RaggedExtents` — the host already knows the
-per-shard counts from the resolve), and each device walks only its own
-``[offsets[my], offsets[my+1])`` extent of the flat matrix
+per-shard counts from the resolve; one ``device_put`` and one program
+call a window, as the one-chip engine's one upload), and each device
+walks only its own ``[offsets[my], offsets[my+1])`` extent of the flat matrix
 (ops.raggedtick): no per-shard compaction into a padded
 ``local_width`` block, no skew fallback, one fixed-shape program per
 batch capacity.  The flat batch sorts by GLOBAL slot and ownership is
@@ -79,6 +81,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from gubernator_tpu.native import NativeSlotMap, ShardedWindowPass
+from gubernator_tpu.ops import i64pair as p64
 from gubernator_tpu.ops import rowtable
 from gubernator_tpu.ops.buckets import BucketState, slice_field
 from gubernator_tpu.ops.engine import (
@@ -88,7 +91,6 @@ from gubernator_tpu.ops.engine import (
     ITEM_INT_ROWS,
     READBACK_ROWS,
     REQ32_INDEX,
-    REQ32_ROWS,
     RESTORE_CHUNK,
     StagingRing,
     describe_engine,
@@ -106,6 +108,7 @@ from gubernator_tpu.ops.engine import (
     select_reclaim_victims,
     snapshot_from_items,
     sort_packed_by_slot,
+    stamp_now,
     unpack_resp_compact,
 )
 from gubernator_tpu.ops.raggedtick import (
@@ -155,8 +158,9 @@ class ShardedOps:
     """The per-shard device ops for one (mesh, local_capacity, layout):
     tick/evict/install/restore/readback, each a shard_map of the
     corresponding single-chip op, jitted with state donation.  Ticks use
-    ONE wire format — the ragged flat (19, B) + offsets dispatch (module
-    docstring) — in two 32-bit programs: the sorted duplicate program
+    ONE wire format — the ragged flat dispatch, one slab of (19, B)
+    rows, ``now`` and offsets (module docstring) — in two 32-bit
+    programs of ``(state, slab)``: the sorted duplicate program
     (``tick32.make_sorted_tick32_rows_fn``, the one-chip ``_tick``) for
     duplicate-bearing windows and the duplicate-free parts program
     (the fused Pallas ragged kernel on the row layout).
@@ -164,8 +168,8 @@ class ShardedOps:
     ``trace_counts`` increments once per TRACE of each program (the
     counter bump runs at trace time only): serving re-dispatch must hit
     the warmed executables, and tests pin the counts so a signature
-    drift between warmup and serving (e.g. a committed ``device_put``
-    where warmup used ``jnp.asarray``) fails loudly instead of silently
+    drift between warmup and serving (e.g. an uncommitted
+    ``jnp.asarray`` where warmup used ``put_slab``) fails loudly instead of silently
     re-tracing per tick — with the ragged wire there is exactly one
     program per batch capacity, so ANY skew- or width-driven growth of
     these counters is a regression."""
@@ -212,28 +216,34 @@ class ShardedOps:
                 donate_argnums=(0,),
             )
 
-        # ---- Ragged flat tick programs (module docstring): one
-        # replicated slot-sorted (19, B) batch plus the (n_shards + 1,)
-        # extent offsets in; each shard walks only its own
+        # ---- Ragged flat tick programs (module docstring): ONE
+        # replicated upload in, the slot-sorted (19, B) batch with
+        # ``now`` and the (n_shards + 1,) extent offsets in its tail
+        # (RaggedExtents.split); each shard walks only its own
         # [offsets[my], offsets[my+1]) extent of the flat matrix
         # (ops.raggedtick) and the responses gather with one psum.
         # Compact int32 wire formats (engine.REQ32 / pack_resp_compact):
         # requests cross host->devices at 76 B each and responses return
-        # at 24, the transfer win the single-chip engine gets.
+        # at 24, the transfer win the single-chip engine gets.  ``now``
+        # is read as its i32 pair (tick32.split_slab's reading): no
+        # 64-bit value exists in either program.
         n_shards = n
+        split = RaggedExtents(n, local_capacity).split
 
-        def _extent(offsets, my):
+        def _window(slab):
+            """(m, this shard's (start, count, lo), now) of one upload."""
+            m, now, offsets = split(slab)
+            my = lax.axis_index("shard")
             start = offsets[my]
             count = offsets[my + 1] - start
             lo = my.astype(jnp.int32) * local_capacity
-            return start, count, lo
+            return m, (start, count, lo), p64.I64(now[0], now[1])
 
-        def walk_rows(rows_fn, state_blk, m, offsets, now):
+        def walk_rows(rows_fn, state_blk, slab):
             """One shard's extent of the flat batch through a
             single-chip 32-bit rows program, tile by tile; the six
             response rows merge into zeroed flat lanes."""
-            my = lax.axis_index("shard")
-            start, count, lo = _extent(offsets, my)
+            m, extent, now = _window(slab)
             b = m.shape[1]
 
             def tile_tick(s_, blk):
@@ -241,10 +251,19 @@ class ShardedOps:
                 return s2, tuple(rows)
 
             return ragged_walk(
-                tile_tick, state_blk, m, start, count, lo,
+                tile_tick, state_blk, m, *extent,
                 local_capacity, choose_tile(b, n_shards),
                 tuple(jnp.zeros(b, jnp.int32) for _ in range(6)),
             )
+
+        # Each program is ONE jit of (state, slab): the state donated,
+        # the slab replicated (put_slab).  (The wrappers go to shard_map
+        # by name: guberlint G006 finds its traced functions that way.)
+        self.slab_sharding = lay.shardings(mesh, lay.flat2())
+        flat = dict(
+            mesh=mesh, in_specs=(state_spec, lay.flat2()),
+            out_specs=(state_spec, lay.flat2()), check_vma=False,
+        )
 
         # Duplicate-bearing windows: the one-chip engine's sorted
         # duplicate program (exact per-slot order, closed-form folds,
@@ -253,19 +272,13 @@ class ShardedOps:
         # the state carried between them (raggedtick module doc).
         sorted_rows = make_sorted_tick32_rows_fn(local_capacity, layout)
 
-        def _tick_ragged(state_blk, m, offsets, now):
+        def _tick_ragged(state_blk, slab):
             self.trace_counts["tick_ragged"] += 1
-            st, rows = walk_rows(sorted_rows, state_blk, m, offsets, now)
+            st, rows = walk_rows(sorted_rows, state_blk, slab)
             return st, lax.psum(stack6(rows), "shard")
 
-        flat_in = (state_spec, lay.flat2(), lay.offsets1(), lay.scalar())
         self.tick_ragged = jax.jit(
-            shard_map(
-                _tick_ragged, mesh=mesh, in_specs=flat_in,
-                out_specs=(state_spec, lay.flat2()), check_vma=False,
-            ),
-            donate_argnums=(0,),
-        )
+            shard_map(_tick_ragged, **flat), donate_argnums=(0,))
 
         # The parts-native program for duplicate-free windows (the
         # production common case): host-dispatched as its OWN program,
@@ -277,28 +290,21 @@ class ShardedOps:
         if self._fused32:
             fused_ragged = make_fused_ragged_tick_fn(local_capacity)
 
-            def _tick32_ragged(state_blk, m, offsets, now):
+            def _tick32_ragged(state_blk, slab):
                 self.trace_counts["tick_unique_ragged"] += 1
-                my = lax.axis_index("shard")
-                start, count, lo = _extent(offsets, my)
-                st, resp = fused_ragged(
-                    state_blk, m, start, count, lo, now)
+                m, extent, now = _window(slab)
+                st, resp = fused_ragged(state_blk, m, *extent, now)
                 return st, lax.psum(resp, "shard")
         else:
             tick32_rows = make_tick32_rows_fn(local_capacity, layout)
 
-            def _tick32_ragged(state_blk, m, offsets, now):
+            def _tick32_ragged(state_blk, slab):
                 self.trace_counts["tick_unique_ragged"] += 1
-                st, rows = walk_rows(tick32_rows, state_blk, m, offsets, now)
+                st, rows = walk_rows(tick32_rows, state_blk, slab)
                 return st, lax.psum(stack6(rows), "shard")
 
         self.tick_unique_ragged = jax.jit(
-            shard_map(
-                _tick32_ragged, mesh=mesh, in_specs=flat_in,
-                out_specs=(state_spec, lay.flat2()), check_vma=False,
-            ),
-            donate_argnums=(0,),
-        )
+            shard_map(_tick32_ragged, **flat), donate_argnums=(0,))
 
         def _evict(state_blk, slots_blk):
             return evict(state_blk, slots_blk[0])
@@ -344,6 +350,17 @@ class ShardedOps:
         device two whole tables: 12.8 GB at 12.5M rows.)"""
         return jax.jit(
             self.zeros_global, out_shardings=self.state_shardings)()
+
+    def put_slab(self, slab: np.ndarray):
+        """A window's one upload: ONE ``device_put`` of the host slab
+        onto the replicated sharding the tick programs take it in, so
+        the four copies leave in one call and the program call finds its
+        argument where it wants it.  (An uncommitted ``jnp.asarray``
+        lands on device 0 and the call replicates it from there, on
+        jit's Python path: 2.25 ms a window against 1.74, PERF.md
+        section 6, PR 37.)  Warm-up and serving both come through here:
+        the committed sharding is part of the jit signature."""
+        return jax.device_put(slab, self.slab_sharding)
 
     def put2(self, blk: np.ndarray):
         return jax.device_put(blk, self.block_sharding2)
@@ -481,13 +498,13 @@ class MeshTickEngine:
             _depth = 4
         self._staging_slabs = 2 * _depth + 1
         self._staging = StagingRing(
-            REQ32_ROWS, self.capacity, self._staging_slabs,
-            width=self.max_batch)
+            self.ragged.slab_rows(self.max_batch), self.capacity,
+            self._staging_slabs, width=self.max_batch)
         self._inflight = 0
         self.metric_h2d_windows = 0
         self.metric_h2d_overlapped = 0
-        # host->device uploads, over h2d_windows: uploads a window (the
-        # matrix, the offsets and ``now`` cross separately today)
+        # host->device uploads, over h2d_windows: uploads a window, 1.0
+        # (the matrix, ``now`` and the offsets are one slab)
         self.metric_h2d_uploads = 0
         # windows by the program that answered them; they add up to
         # metric_h2d_windows
@@ -514,32 +531,31 @@ class MeshTickEngine:
         """Compile the serving-path programs at startup (see
         TickEngine._warmup): both ragged ticks — the sorted duplicate
         program and the duplicate-free parts program — with an
-        all-sentinel batch and empty extents (offsets all zero: the
+        all-sentinel batch and empty extents (a zeroed tail: the
         walkers' dynamic trip counts are runtime values, so the empty
         window compiles the same single program serving traffic uses).
-        Warmup MUST dispatch with the exact serving signature:
-        ``jnp.asarray`` uploads (uncommitted) for the matrix AND the
-        offsets vector, never a committed ``device_put`` — a committed
-        sharding is a new jit signature that re-traces every warmed
-        program (~0.6 s each; the ShardedOps.trace_counts pin in
-        test_mesh_engine holds this)."""
+        Warmup MUST dispatch with the exact serving signature: the
+        whole slab through ``ShardedOps.put_slab`` (committed,
+        replicated), never an uncommitted ``jnp.asarray`` — the
+        sharding's commitment is part of the jit signature, and another
+        one re-traces every warmed program (~0.6 s each; the
+        ShardedOps.trace_counts pin in test_mesh_engine holds this)."""
         if jax.default_backend() == "tpu":
             # Eager tick compiles are a serving chip's live-deadline
             # concern (see TickEngine._warmup): on the CPU backend
             # (the tests) each shard_map trace costs seconds per engine
             # and most tests tick only one of the two programs — lazy
             # is the right trade.
-            m = np.zeros((REQ32_ROWS, self.max_batch), np.int32)
-            m[REQ32_INDEX["slot"]] = self.capacity
-            offs = self.ragged.offsets(np.zeros(self.n_shards, np.int64))
+            slab = np.zeros(
+                (self.ragged.slab_rows(self.max_batch), self.max_batch),
+                np.int32)
+            slab[REQ32_INDEX["slot"]] = self.capacity
             self.state, resp = self.ops.tick_ragged(
-                self.state, jnp.asarray(m), jnp.asarray(offs), jnp.int64(0)
-            )
+                self.state, self.ops.put_slab(slab))
             # guber: allow-G001(init-time warmup D2H - deliberately materializes once at engine construction to pre-compile; never inside a serving tick)
             np.asarray(resp)  # warm the response D2H path
             self.state, resp = self.ops.tick_unique_ragged(
-                self.state, jnp.asarray(m), jnp.asarray(offs), jnp.int64(0)
-            )
+                self.state, self.ops.put_slab(slab))
             # guber: allow-G001(init-time warmup D2H - same as above)
             np.asarray(resp)
         cols = np.zeros((self.n_shards, 8, 1), np.int64)  # valid=0: no-op
@@ -823,36 +839,38 @@ class MeshTickEngine:
             # TickEngine.submit_columns: "route" is keys -> shard -> slot
             # and the hit/miss accounting, the layer the sharded table
             # adds on the host; "pack" the slab rows, the slot sort and
-            # the extent offsets (the lease is broken out beside it).
+            # the slab's tail (the lease is broken out beside it).
             fr = flightrec.get()
             t0 = time.perf_counter() if fr is not None else 0.0
-            # The native window pass cleans the slab it packs.
-            m = self._staging.lease(
+            # The native window pass cleans the rows it packs.
+            slab = self._staging.lease(
                 self.max_batch, clean=self._window_pass is None)
             if fr is not None:
                 fr.note(fr.active(), "lease", time.perf_counter() - t0)
                 t0 = time.perf_counter()
-            sh, slots, ix, inv, has_dups, offs, route_s = self._pack_window(
-                cols, now, m, errors)
+            sh, slots, ix, inv, has_dups, route_s = self._pack_window(
+                cols, now, slab, errors)
             if fr is not None:
                 spent = time.perf_counter() - t0
                 fr.note(fr.active(), "route", route_s)
                 fr.note(fr.active(), "pack", spent - route_s)
             return self._dispatch_ragged(
-                cols, now, m, sh, slots, ix, inv, has_dups, offs, errors)
+                cols, now, slab, sh, slots, ix, inv, has_dups, errors)
 
     @hot_path
-    def _pack_window(self, cols, now: int, m: np.ndarray,
+    def _pack_window(self, cols, now: int, slab: np.ndarray,
                      errors: Dict[int, str]):
         """One window's host side: keys to shards and slots, and the
-        leased slab ``m`` as ONE slot-sorted (19, B) compact matrix
-        carrying GLOBAL slots.  Returns ``(sh, slots, ix, inv, has_dups,
-        offs, route_s)``: the route and the LOCAL slots in request
-        order, ``ix`` the rows packed (None: all of them), ``inv`` the
-        request -> sorted-lane permutation, ``offs`` the per-shard
-        extent offsets (partition.RaggedExtents — the slot sort groups
-        shards contiguously in ascending order) and ``route_s`` the
-        seconds of the call that went to routing and accounting.
+        leased ``slab`` as the window's ONE upload
+        (partition.RaggedExtents.split): the slot-sorted (19, B) compact
+        matrix carrying GLOBAL slots and, in the tail, ``now`` and the
+        per-shard extent offsets (the slot sort groups shards
+        contiguously in ascending order), written in place.  Returns
+        ``(sh, slots, ix, inv, has_dups, route_s)``: the route and the
+        LOCAL slots in request order, ``ix`` the rows packed (None: all
+        of them), ``inv`` the request -> sorted-lane permutation and
+        ``route_s`` the seconds of the call that went to routing and
+        accounting.
 
         The window the served path sees all day takes ONE native call
         (native/slotmap.cc guber_slotmap_pack_window_sharded, the
@@ -868,6 +886,8 @@ class MeshTickEngine:
         slot maps (no native library)."""
         wp = self._window_pass
         resolved = None
+        m, now_words, offs = self.ragged.split(slab)
+        stamp_now(now_words, now)
         if wp is not None:
             n = len(cols)
             with tracing.profile_annotation("guber.mesh.pack_window"), \
@@ -884,21 +904,23 @@ class MeshTickEngine:
                 self.metric_hits += n - n_miss
                 self.metric_misses += n_miss
                 self.metric_native_pack_windows += 1
+                self.ragged.offsets(counts, out=offs)
                 return (sh, slots, None, inv,
                         status != NativeSlotMap.PACK_UNIQUE,
-                        self.ragged.offsets(counts),
                         route_s + time.perf_counter() - t0)
             if status == NativeSlotMap.PACK_RESOLVED_ONLY:
                 resolved = sh, slots, known
-            self._staging.clean(m)   # the pass left the slab as leased
-        return self._pack_window_numpy(cols, now, m, errors, resolved)
+            self._staging.clean(m)   # the pass left the rows as leased
+        return self._pack_window_numpy(cols, now, m, offs, errors, resolved)
 
     @hot_path
     def _pack_window_numpy(self, cols, now: int, m: np.ndarray,
-                           errors: Dict[int, str], resolved=None):
+                           offs: np.ndarray, errors: Dict[int, str],
+                           resolved=None):
         """:meth:`_pack_window` in numpy, for the windows the native
-        pass leaves (and for the pure-Python slot maps), into a clean
-        slab: Gregorian rows, the resolve (``resolved``: see
+        pass leaves (and for the pure-Python slot maps), into the slab's
+        clean (19, B) rows ``m`` and its tail's offsets ``offs``:
+        Gregorian rows, the resolve (``resolved``: see
         :meth:`_resolve_columns`), hit/miss accounting and Store
         read-through, then the REQ32 rows of the rows that found a slot,
         one argsort and the extents' counts."""
@@ -917,43 +939,39 @@ class MeshTickEngine:
         pack_wide_rows(m, "greg_exp", greg_e[ix], ix)
         pack_wide_rows(m, "greg_dur", greg_d[ix], ix)
         inv, has_dups = sort_packed_by_slot(m, n, self.capacity)
-        offs = self.ragged.offsets(self.ragged.counts(sh, ok))
-        return sh, slots, ix, inv, has_dups, offs, route_s
+        self.ragged.offsets(self.ragged.counts(sh, ok), out=offs)
+        return sh, slots, ix, inv, has_dups, route_s
 
     @hot_path
     def _dispatch_ragged(
-        self, cols, now, m, sh, slots, ix, inv, has_dups, offs, errors
+        self, cols, now, slab, sh, slots, ix, inv, has_dups, errors
     ) -> "MeshRaggedTickHandle":
         """The ragged flat dispatch of a packed window
-        (:meth:`_pack_window`): the slab and the extent offsets go up
-        with async ``jnp.asarray`` copies (the transfer rides under the
-        previous window's tick; the uncommitted signatures match warmup,
-        so re-dispatch reuses the compiled program).  Each shard walks
-        only its own extent on device — no per-shard host loop, no
-        padded per-shard block, responses gathered with one psum."""
+        (:meth:`_pack_window`): the slab goes up with ONE async
+        ``device_put`` (``ShardedOps.put_slab``: the transfer rides
+        under the previous window's tick; the signature matches warmup,
+        so re-dispatch reuses the compiled program) and one program call.
+        Each shard walks only its own extent on device — no per-shard
+        host loop, no padded per-shard block, responses gathered with
+        one psum."""
         n = len(cols)
         fr = flightrec.get()
         t0 = time.perf_counter() if fr is not None else 0.0
         with tracing.profile_annotation("guber.mesh.tick"), \
                 tracing.maybe_span("guber.mesh.dispatch_ragged",
                                    {"batch": n}):
-            dev_m = jnp.asarray(m)
-            dev_offs = jnp.asarray(offs)
             if has_dups:
                 self.metric_dup_windows += 1
-                self.state, resp = self.ops.tick_ragged(
-                    self.state, dev_m, dev_offs, jnp.int64(now)
-                )
+                tick = self.ops.tick_ragged
             else:
                 self.metric_unique_windows += 1
-                self.state, resp = self.ops.tick_unique_ragged(
-                    self.state, dev_m, dev_offs, jnp.int64(now)
-                )
+                tick = self.ops.tick_unique_ragged
+            self.state, resp = tick(self.state, self.ops.put_slab(slab))
         if fr is not None:
             fr.note(fr.active(), "h2d", time.perf_counter() - t0)
         self._pending.clear()
         self.metric_routed_windows += 1
-        self.metric_h2d_uploads += 3    # dev_m, dev_offs, now
+        self.metric_h2d_uploads += 1
         wt_args = None
         if self.store is not None:
             if ix is None:
@@ -1419,16 +1437,16 @@ class MeshTickEngine:
         # post-cutover windows derive their offsets against cap_to's
         # ownership from this object — nothing width-shaped survives to
         # re-derive.
+        ragged = RaggedExtents(tr.n_to, tr.cap_to)
         return SimpleNamespace(
             mesh=mesh, n_shards=tr.n_to, local_capacity=tr.cap_to,
-            capacity=tr.capacity_to, layout=layout,
-            ragged=RaggedExtents(tr.n_to, tr.cap_to),
+            capacity=tr.capacity_to, layout=layout, ragged=ragged,
             ops=ops, state=ops.init_state(),
             slots=slots, window_pass=make_window_pass(slots, tr.cap_to),
             last_access=np.zeros(tr.capacity_to, np.int64),
             staging=StagingRing(
-                REQ32_ROWS, tr.capacity_to, self._staging_slabs,
-                width=self.max_batch),
+                ragged.slab_rows(self.max_batch), tr.capacity_to,
+                self._staging_slabs, width=self.max_batch),
         )
 
     @hot_path

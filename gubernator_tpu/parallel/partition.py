@@ -13,10 +13,11 @@ read the whole placement story in one file:
 * :class:`ShardLayout` — the partitioned serving table.  The SoA bucket
   state is split over the 1-D ``('shard',)`` mesh by contiguous slot
   range (device *d* owns global slots ``[d*local_cap, (d+1)*local_cap)``);
-  tick request/response traffic is *flat replicated* — one slot-sorted
-  (19, B) matrix plus a ragged ``offsets`` vector broadcast to every
-  shard, each shard walking only its own extent on device
-  (ops.raggedtick) — while maintenance blocks (evict/install/restore/
+  tick request/response traffic is *flat replicated* — one slab, the
+  slot-sorted (19, B) matrix with ``now`` and the ragged ``offsets``
+  in its tail (:meth:`RaggedExtents.split`), broadcast to every shard,
+  each shard walking only its own extent on device (ops.raggedtick) —
+  while maintenance blocks (evict/install/restore/
   readback) keep the leading shard axis.
 * :class:`NodeLayout` — the replicated GLOBAL table.  One replica row
   per node (``P('node', None)``), accumulator/aux matrices alongside,
@@ -43,6 +44,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gubernator_tpu.ops.buckets import BucketState
+from gubernator_tpu.ops.engine import REQ32_ROWS
 from gubernator_tpu.ops.rowtable import RowState
 
 
@@ -70,18 +72,10 @@ class ShardLayout:
         return P(self.shard_axis, None, None)
 
     def flat2(self) -> P:
-        """(ROWS, B) flat slot-sorted request matrix — replicated to
-        every shard; each device walks only its own ragged extent."""
+        """(ROWS, B) flat matrix — the window's one upload
+        (RaggedExtents.split) and its response — replicated to every
+        shard; each device walks only its own ragged extent."""
         return P(None, None)
-
-    def offsets1(self) -> P:
-        """(n_shards + 1,) ragged extent offsets (RaggedExtents.offsets)
-        — replicated; each shard reads its own ``[my, my + 1]`` pair."""
-        return P(None)
-
-    def scalar(self) -> P:
-        """Replicated scalar (``now`` stamps, flags)."""
-        return P()
 
     def shardings(self, mesh: Mesh, spec_tree):
         """NamedShardings for a spec tree (or a bare spec) on ``mesh``.
@@ -153,16 +147,35 @@ class RaggedExtents:
             return np.zeros(self.n_shards, np.int64)
         return np.bincount(sh[ok], minlength=self.n_shards)
 
-    def offsets(self, counts: np.ndarray) -> np.ndarray:
+    def offsets(self, counts: np.ndarray, out=None) -> np.ndarray:
         """Cumulative extent offsets: shard s owns sorted lanes
         ``[offsets[s], offsets[s+1])``.  Valid because the packed batch
         sorts by GLOBAL slot (engine.sort_packed_by_slot) and global
         slots of shard s are exactly ``[s*cap, (s+1)*cap)`` — shards
         ascend with the sort, error/padding lanes (sentinel slot) sort
-        past every extent."""
-        off = np.zeros(self.n_shards + 1, np.int32)
-        off[1:] = np.cumsum(counts)
+        past every extent.  Written into ``out`` where given (the
+        window's upload: :meth:`split`)."""
+        off = out if out is not None else np.empty(self.n_shards + 1, np.int32)
+        off[0] = 0
+        np.cumsum(counts, out=off[1:])
         return off
+
+    def slab_rows(self, width: int) -> int:
+        """Rows of a window's ONE upload at batch capacity ``width``:
+        the REQ32 rows and, in whole rows after them, the tail — ``now``
+        as the wide encoding's two int32 words (engine.stamp_now), then
+        the ``n_shards + 1`` offsets.  One row more at any serving width
+        (engine.SLAB_ROWS); more where ``width`` is under
+        ``n_shards + 3``."""
+        return REQ32_ROWS + -(-(self.n_shards + 3) // width)
+
+    def split(self, slab):
+        """A window's upload ``(slab_rows, B)`` cut up: the (19, B)
+        request matrix, ``now``'s two words and the offsets.  THE layout
+        of the tail, for both sides: the host packs into these (numpy
+        views of the staging slab), the traced program reads them."""
+        tail = slab[REQ32_ROWS:].reshape(-1)
+        return slab[:REQ32_ROWS], tail[:2], tail[2:self.n_shards + 3]
 
 
 # ----------------------------------------------------------------------

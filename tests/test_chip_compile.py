@@ -195,11 +195,10 @@ def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
     starts with GUBER_TPU_MESH_SHARDS=4: the sorted 32-bit duplicate
     program walked over each shard's extent and the fused ragged kernel,
     on a four-device mesh with the table split 3,125,000 rows a shard
-    (``base3-mixed-10m-mesh4``).  Neither holds a 64-bit float (on a
-    TPU that is float32-pair emulation, not IEEE), and the only 64-bit
-    integer in either is the scalar ``now`` each splits on entry."""
-    import re
-
+    (``base3-mixed-10m-mesh4``), each a program of (state, slab): the
+    window's one upload, ``now`` and the extent offsets in its last row.
+    Neither holds a 64-bit float (on a TPU that is float32-pair
+    emulation, not IEEE) nor a 64-bit integer."""
     from gubernator_tpu.parallel.mesh_engine import ShardedOps
 
     mesh = Mesh(np.array(four_chips), ("shard",))
@@ -209,10 +208,7 @@ def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
     state = rowtable.RowState(table=sds(
         (SHARDS * (LOCAL_CAP + 1), rowtable.ROW_W), I32,
         ops.state_shardings.table))
-    args = (
-        state, sds((REQ32_ROWS, B), I32, rep),
-        sds((SHARDS + 1,), I32, rep), sds((), jnp.int64, rep),
-    )
+    args = (state, sds((SLAB_ROWS, B), I32, rep))
     walker = ops.tick_ragged.lower(*args).compile()
     fused = ops.tick_unique_ragged.lower(*args).compile()
     assert has_kernel(walker) and has_kernel(fused)
@@ -220,7 +216,7 @@ def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
         text = c.as_text()
         assert "all-reduce" in text             # the one response psum
         assert "f64[" not in text
-        assert set(re.findall(r"[su]64\[[^\]]*\]", text)) <= {"s64[]"}
+        assert no_int64(c) and "u64[" not in text
         # each device holds its quarter of the table, not all of it
         per_dev = c.memory_analysis().argument_size_in_bytes
         assert per_dev < 2 * (LOCAL_CAP + 1) * rowtable.ROW_W * 4

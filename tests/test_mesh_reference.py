@@ -22,9 +22,10 @@ from benchmarks.harness.reference import Reference
 from benchmarks.tests.test_precision_control import Float32Leaky
 from gubernator_tpu.native import NativeSlotMap
 from gubernator_tpu.ops.engine import (
-    REQ32_INDEX, REQ32_ROWS, TickEngine, items_from_snapshot)
+    REQ32_INDEX, SLAB_ROWS, TickEngine, items_from_snapshot, join_i32_pair)
 from gubernator_tpu.ops.raggedtick import choose_tile
 from gubernator_tpu.ops.reqcols import CREATED_UNSET, ReqColumns, pack_blob
+from gubernator_tpu.parallel import mesh_engine
 from gubernator_tpu.parallel.mesh_engine import (
     MeshTickEngine, make_mesh, make_window_pass)
 from gubernator_tpu.types import Behavior
@@ -241,7 +242,117 @@ def test_route_stage_and_dispatch_counters(mesh):
     assert mesh.metric_dup_windows >= 1 and mesh.metric_unique_windows >= 1
     assert (mesh.metric_dup_windows + mesh.metric_unique_windows
             == mesh.metric_h2d_windows)
-    assert mesh.metric_h2d_uploads == 3 * mesh.metric_h2d_windows
+    assert mesh.metric_h2d_uploads == mesh.metric_h2d_windows
+
+
+# ----------------------------------------------------------------------
+# One upload a window: ``now`` and the extent offsets ride in the slab's
+# tail (partition.RaggedExtents.split), and both programs read them
+# there.
+# ----------------------------------------------------------------------
+def tail_columns(keys, ranks):
+    """Token and leaky buckets by turns, a third of them with a second's
+    duration; ``created_at`` unset, so the tick's ``now`` is the clock."""
+    n = len(keys)
+    col = lambda v: np.asarray(v, np.int64)  # noqa: E731
+    blob, offsets = pack_blob(keys)
+    return ReqColumns(
+        blob, offsets, hits=np.ones(n, np.int64), limit=col(10 + ranks % 7),
+        duration=col(np.where(ranks % 3 == 0, 1_000, 3_600_000)),
+        algorithm=col(ranks % 2), behavior=np.zeros(n, np.int64),
+        created_at=np.full(n, CREATED_UNSET, np.int64),
+        burst=np.zeros(n, np.int64))
+
+
+@pytest.mark.parametrize("bit31", ["lo_bit31_clear", "lo_bit31_set"])
+@pytest.mark.parametrize("program", ["unique", "dup"])
+def test_now_and_offsets_ride_in_the_slab(mesh, one_chip, program, bit31):
+    """Two ticks five seconds apart, at a ``now`` over 2^32 whose low
+    word has bit 31 clear or set: the buckets of a second's duration
+    expire between them and start anew, the others go on, which only a
+    program that reads both of ``now``'s words from the tail answers;
+    and every shard walks its own extent, which only one that reads its
+    two offsets there does.  Answers and ``_last_access`` as the
+    one-chip engine's, through both programs."""
+    now = (T0 & ~0xFFFFFFFF) | (0x9000_0000 if bit31 == "lo_bit31_set"
+                                else 0x1000_0000)
+    assert now > 1 << 32 and bool(now & 0x8000_0000) == (bit31 == "lo_bit31_set")
+    n = 120
+    ranks = np.arange(n) if program == "unique" else np.arange(n) % 40
+    keys = [b"tail_%s_%s_%d" % (program.encode(), bit31.encode(), r)
+            for r in ranks.tolist()]
+    assert len({zlib.crc32(k) % SHARDS for k in keys}) == SHARDS
+    counter = f"metric_{program}_windows"
+    count0 = getattr(mesh, counter)
+    answers = {mesh: [], one_chip: []}
+    ticks = {mesh: [], one_chip: []}
+    # the second window holds the first two thirds of the rows
+    for t, rows in ((now, n), (now + 5_000, 2 * n // 3)):
+        cols = tail_columns(keys[:rows], ranks[:rows])
+        for eng in (mesh, one_chip):
+            rm, errors = eng.submit_columns(cols, now=t).result()
+            assert not errors
+            answers[eng].append(np.asarray(rm)[:4])
+            ticks[eng].append(eng._tick_count)
+    assert mismatched(answers[mesh], answers[one_chip]) == 0
+    first, second = (a[2] for a in answers[mesh])        # remaining
+    token = ranks[:len(second)] % 2 == 0
+    expired = ranks[:len(second)] % 3 == 0
+    np.testing.assert_array_equal(
+        second[token & expired], first[:len(second)][token & expired])
+    assert (second[~expired] < first[:len(second)][~expired]).all()
+    assert getattr(mesh, counter) - count0 == 2
+
+    def stamps(eng, slot_of):
+        return [int(eng._last_access[slot_of(k.decode(), zlib.crc32(k) % SHARDS)])
+                for k in keys]
+
+    cap = mesh.local_capacity
+    for eng, slot_of in (
+            (mesh, lambda k, sh: sh * cap + mesh.slots[sh].get(k)),
+            (one_chip, lambda k, sh: one_chip.slots.get(k))):
+        again = set(keys[:2 * n // 3])
+        want = [ticks[eng][1] if k in again else ticks[eng][0] for k in keys]
+        assert stamps(eng, slot_of) == want
+
+
+@pytest.mark.parametrize("program", ["tick_unique_ragged", "tick_ragged"])
+def test_one_upload_and_one_program_call_a_window(mesh, monkeypatch, program):
+    """After k windows of a program ``metric_h2d_uploads`` and
+    ``metric_h2d_windows`` have both risen by k, and ``submit_columns``
+    crossed into the runtime with host data k times (``jax.device_put``
+    of the whole slab onto the replicated sharding; no ``jnp.asarray``,
+    no ``jnp.int64``) and called that program's entry k times, and no
+    other."""
+    k = 3
+    ranks = np.arange(30) if program == "tick_unique_ragged" else np.arange(30) % 7
+    keys = [b"one_%s_%d" % (program.encode(), r) for r in ranks.tolist()]
+    cols = tail_columns(keys, ranks)
+    mesh.submit_columns(cols, now=T0).result()   # compiles here
+    calls = []
+    for name in ("tick_unique_ragged", "tick_ragged"):
+        def counted(*a, _fn=getattr(mesh.ops, name), _name=name):
+            calls.append(_name)
+            return _fn(*a)
+        monkeypatch.setattr(mesh.ops, name, counted)
+    for mod, name in ((mesh_engine.jax, "device_put"),
+                      (mesh_engine.jnp, "asarray"),
+                      (mesh_engine.jnp, "int64")):
+        def upload(x, *a, _fn=getattr(mod, name), _name=name, **kw):
+            calls.append((_name, type(x).__name__, np.shape(x), a))
+            return _fn(x, *a, **kw)
+        monkeypatch.setattr(mod, name, upload)
+    before = mesh.metric_h2d_uploads, mesh.metric_h2d_windows
+    for i in range(k):
+        _, errors = mesh.submit_columns(cols, now=T0 + 1 + i).result()
+        assert not errors
+    assert mesh.metric_h2d_uploads - before[0] == k
+    assert mesh.metric_h2d_windows - before[1] == k
+    slab = ("device_put", "ndarray", (SLAB_ROWS, B),
+            (mesh.ops.slab_sharding,))
+    assert mesh.ragged.slab_rows(B) == SLAB_ROWS
+    assert mesh.ops.slab_sharding.is_fully_replicated
+    assert calls == [slab, program] * k
 
 
 # ----------------------------------------------------------------------
@@ -318,14 +429,17 @@ def test_native_window_pass_equals_numpy_chain(mesh, monkeypatch, kind, n):
         monkeypatch.setattr(mesh, "_pending", set())
         before = (mesh.metric_hits, mesh.metric_misses,
                   mesh.metric_native_pack_windows)
-        # the pass cleans the slab it packs, whatever it held; the numpy
-        # chain is handed a clean one
-        m = rng.integers(-9, 9, (REQ32_ROWS, b)).astype(np.int32)
+        # the pass cleans the rows it packs, whatever they held; the
+        # numpy chain is handed a clean slab; both write the whole tail
+        slab = rng.integers(
+            -9, 9, (mesh.ragged.slab_rows(b), b)).astype(np.int32)
         if not native:
-            mesh._staging.clean(m)
+            mesh._staging.clean(slab)
         errors = {}
-        sh, sl, ix, inv, has_dups, offs, route_s = mesh._pack_window(
-            cols, T0, m, errors)
+        sh, sl, ix, inv, has_dups, route_s = mesh._pack_window(
+            cols, T0, slab, errors)
+        m, now_words, offs = mesh.ragged.split(slab)
+        assert slab.shape[0] == SLAB_ROWS and offs.base is slab
         assert not errors and route_s > 0
         assert ix is None or (ix == np.arange(n)).all()
         counted = tuple(
@@ -334,7 +448,8 @@ def test_native_window_pass_equals_numpy_chain(mesh, monkeypatch, kind, n):
                  mesh.metric_native_pack_windows), before))
         every = np.arange(cap)
         return dict(
-            slab=m, sh=sh, slots=sl, inv=inv, has_dups=has_dups, offs=offs,
+            slab=m, now=now_words, sh=sh, slots=sl, inv=inv,
+            has_dups=has_dups, offs=offs,
             known=m[R["known"], inv], last_access=mesh._last_access,
             pending=set(mesh._pending), counted=counted,
             maps=[sm.keys_batch(every) for sm in slots])
@@ -361,6 +476,8 @@ def test_native_window_pass_equals_numpy_chain(mesh, monkeypatch, kind, n):
     np.testing.assert_array_equal(nat["known"], want_known)
     np.testing.assert_array_equal(
         np.diff(nat["offs"]), np.bincount(want_sh, minlength=SHARDS))
+    assert nat["offs"][0] == 0 and len(nat["offs"]) == SHARDS + 1
+    assert int(join_i32_pair(*nat["now"])) == T0
     assert nat["has_dups"] == (len(set(keys)) < n)
     g = nat["sh"] * cap + nat["slots"]
     assert nat["pending"] == set(g[~np.asarray(want_known)].tolist())
