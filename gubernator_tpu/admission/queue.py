@@ -29,9 +29,12 @@ CLASS_CLIENT = 1
 
 class QueueItem:
     """One queued submission: an object batch or a columnar batch plus
-    its completion future, admission class, and absolute deadline."""
+    its completion future, admission class, and absolute deadline.
+    ``t_enq`` is the ``perf_counter`` reading at enqueue, stamped by the
+    tick loop only while a flight recorder is installed (0.0: none)."""
 
-    __slots__ = ("kind", "payload", "n", "fut", "deadline", "klass", "seq")
+    __slots__ = ("kind", "payload", "n", "fut", "deadline", "klass", "seq",
+                 "t_enq")
 
     def __init__(self, kind, payload, n, fut, deadline=None,
                  klass=CLASS_CLIENT, seq=0):
@@ -42,6 +45,7 @@ class QueueItem:
         self.deadline = deadline
         self.klass = klass
         self.seq = seq
+        self.t_enq = 0.0
 
     def expired(self, now: float) -> bool:
         return self.deadline is not None and now >= self.deadline

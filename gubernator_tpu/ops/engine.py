@@ -58,7 +58,7 @@ from gubernator_tpu.types import (
     Status,
     has_behavior,
 )
-from gubernator_tpu.utils import flightrec, timeutil, tracing
+from gubernator_tpu.utils import flightrec, timeutil
 from gubernator_tpu.utils.hotpath import hot_path
 from gubernator_tpu.utils import sanitize
 
@@ -2169,7 +2169,7 @@ class TickHandle:
     """
 
     __slots__ = ("_engine", "_resp", "_n", "_inv", "errors", "_refs",
-                 "_slots_req", "_limit_req", "_done", "_flock")
+                 "_slots_req", "_limit_req", "_done", "_flock", "_wid")
 
     def __init__(self, engine, resp, n, inv, errors, refs, slots_req,
                  limit_req=None):
@@ -2192,6 +2192,10 @@ class TickHandle:
         )
         self._done: Optional[np.ndarray] = None
         self._flock = sanitize.lock("TickHandle._flock")
+        # The flight recorder's window in dispatch (None: no recorder,
+        # or no tick loop): where _finish notes its wait for the lock.
+        fr = flightrec.get()
+        self._wid = fr.active() if fr is not None else None
 
     def _finish(self, raw: np.ndarray) -> None:
         """Complete from an already-materialized device response matrix:
@@ -2206,7 +2210,12 @@ class TickHandle:
             if self._limit_req is not None:  # compact → public (5, n) int64
                 rm = unpack_resp_compact(rm, self._limit_req)
             eng = self._engine
+            # submit_columns holds the lock across the next window's
+            # pack and dispatch: the wait for it is the recorder's
+            # "finish_lock", an overlay inside the drain's "tick".
+            waited = flightrec.stage("finish_lock", into=self._wid).start()
             with eng._lock:
+                waited.stop()
                 # This window is resolved: it no longer holds its H2D
                 # staging slab, and later windows' uploads stop counting
                 # it as overlap (see TickEngine.metric_h2d_overlapped).
@@ -2865,12 +2874,8 @@ class TickEngine:
         there to do that (it cleans the REQ32 rows it packs; the numpy
         pack cleans those the pass hands back).  Called under the engine
         lock (ring state is unsynchronized)."""
-        fr = flightrec.get()
-        t0 = time.perf_counter() if fr is not None else 0.0
-        m = self._staging.lease(b, clean=not self._native_pack)
-        if fr is not None:
-            fr.note(fr.active(), "lease", time.perf_counter() - t0)
-        return m
+        with flightrec.stage("lease"):
+            return self._staging.lease(b, clean=not self._native_pack)
 
     @hot_path
     def _build_cols(self, cols: ReqColumns, now: int):
@@ -3126,15 +3131,12 @@ class TickEngine:
                 cold_hit[pos] = True
             rem = np.flatnonzero(~cold_hit)
             fr = flightrec.get()
-            t0 = time.perf_counter() if fr is not None else 0.0
-            spos, scols = self.ssd.take_batch(
-                [keys[int(j)] for j in rem], now
-            )
+            with flightrec.stage("ssd") as read:
+                spos, scols = self.ssd.take_batch(
+                    [keys[int(j)] for j in rem], now
+                )
             if fr is not None:
-                dt = time.perf_counter() - t0
-                wid = fr.active()
-                fr.note(wid, "ssd", dt)
-                fr.note(wid, "pack", -dt)
+                fr.note(fr.active(), "pack", -read.seconds)
             self.metric_ssd_lookups += 1
             self.metric_ssd_miss_ticks += 1
             if len(spos):
@@ -3233,24 +3235,23 @@ class TickEngine:
         write-through readback must observe exactly this tick's state, so
         no later tick may be dispatched first).
         """
+        # Flight-recorder stages (utils/flightrec.py), consecutive on
+        # this thread: "submit_lock" the wait for the lock the resolver
+        # takes in TickHandle._finish; "pack" slot resolve + matrix fill
+        # + sort + dirty marks + the grouped plan, native or numpy (the
+        # lease is broken out inside it, _lease_matrix); "h2d" the
+        # queued device dispatch; "handle" the rest, to the return.
+        waited = flightrec.stage("submit_lock").start()
         with self._lock:
+            waited.stop()
             now = now if now is not None else timeutil.now_ms()
             self._last_now = max(self._last_now, now)
             self._tick_count += 1
-            # Flight-recorder stage notes (docs/observability.md): "pack"
-            # covers slot resolve + matrix fill + sort + dirty marks +
-            # the grouped plan, native or numpy (the lease is also
-            # broken out inside _lease_matrix); "h2d" the queued device
-            # dispatch below.
-            fr = flightrec.get()
-            t_pack = time.perf_counter() if fr is not None else 0.0
-            packed, n, errors, inv, has_dups, plan = self._build_cols(
-                cols, now)
-            if fr is not None:
-                fr.note(fr.active(), "pack", time.perf_counter() - t_pack)
+            with flightrec.stage("pack"):
+                packed, n, errors, inv, has_dups, plan = self._build_cols(
+                    cols, now)
             dev_m = None
             uploads = 1
-            t_h2d = time.perf_counter() if fr is not None else 0.0
             # Structural tick-path evidence: any SSD lookup issued while
             # the tick-dispatch block below runs would land in this
             # delta.  _build_cols (the only legitimate lookup site) has
@@ -3275,9 +3276,7 @@ class TickEngine:
             # is a new jit signature and re-traces every warmed program
             # once per width (measured ~0.6 s each on the CPU suite).
             #
-            # Named range in XProf captures (utils/tracing.py): device
-            # tick vs host packing shows up separated in the profile.
-            with tracing.profile_annotation("guber.tick"):
+            with flightrec.stage("h2d"):
                 if plan is not None:
                     # Grouped tick: unique heads through the parts
                     # program (fold on device), member responses from
@@ -3341,8 +3340,7 @@ class TickEngine:
                     self.metric_unique_ticks += 1
                     dev_m = jnp.asarray(packed)
                     self.state, resp = self._tick32(self.state, dev_m)
-            if fr is not None:
-                fr.note(fr.active(), "h2d", time.perf_counter() - t_h2d)
+            rest = flightrec.stage("handle").start()
             if self.ssd is not None:
                 self.metric_ssd_tick_path_reads += (
                     self.ssd.metric_lookup_calls - ssd_reads0
@@ -3369,6 +3367,7 @@ class TickEngine:
             # never uploaded it (dev_m is None) and free it for the very
             # next lease.
             self._staging.retire(handle if dev_m is not None else None)
+            rest.stop()
             if self.store is not None:
                 handle.result()
             return handle

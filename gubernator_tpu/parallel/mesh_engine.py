@@ -380,7 +380,7 @@ class MeshRaggedTickHandle:
     D2H)."""
 
     __slots__ = ("_engine", "_resp", "_n", "_inv", "errors", "_limit_req",
-                 "_wt_args", "_done", "_flock")
+                 "_wt_args", "_done", "_flock", "_wid")
 
     def __init__(self, engine, resp, n, inv, errors, limit_req, wt_args):
         self._engine = engine
@@ -395,6 +395,9 @@ class MeshRaggedTickHandle:
         self._wt_args = wt_args
         self._done: Optional[np.ndarray] = None
         self._flock = sanitize.lock("MeshRaggedTickHandle._flock")
+        # as TickHandle: the recorder's window in dispatch, for _finish
+        fr = flightrec.get()
+        self._wid = fr.active() if fr is not None else None
 
     def _finish(self, raw: np.ndarray) -> None:
         with self._flock:
@@ -404,7 +407,9 @@ class MeshRaggedTickHandle:
                 raw[:, : self._n][:, self._inv], self._limit_req
             )
             eng = self._engine
+            waited = flightrec.stage("finish_lock", into=self._wid).start()
             with eng._lock:
+                waited.stop()
                 # This window is resolved: it no longer holds its H2D
                 # staging slab, and later windows' uploads stop counting
                 # it as overlap (metric_h2d_overlapped).
@@ -695,11 +700,8 @@ class MeshTickEngine:
         error-in-item convention).  Returns ``(sh, slots, known)`` with
         resolved rows stamped live (``_last_access``/``_pending``)."""
         n = len(cols)
-        # Named range + span like the single-chip tick path: host-side
-        # shard routing shows up separated from device work in XProf
-        # captures, and traced windows carry the resolve as a child span.
-        with tracing.profile_annotation("guber.mesh.resolve"), \
-                tracing.maybe_span("guber.mesh.resolve", {"batch": n}):
+        # Traced windows carry the resolve as a child span.
+        with tracing.maybe_span("guber.mesh.resolve", {"batch": n}):
             return self._resolve_columns_locked(cols, now, errors, n, resolved)
 
     @hot_path
@@ -831,29 +833,30 @@ class MeshTickEngine:
         if n > self.max_batch:
             raise ValueError(
                 f"batch of {n} exceeds engine max {self.max_batch}")
+        # Flight-recorder stages, the single-chip TickEngine.
+        # submit_columns's under the same names: the native pass is one
+        # call, so one "pack" range, the lease inside it; "route" (keys
+        # -> shard -> slot and the hit/miss accounting, the layer the
+        # sharded table adds on the host) is noted from the pass's own
+        # clock and taken off "pack", which keeps the slab rows, the
+        # slot sort and the slab's tail.
+        waited = flightrec.stage("submit_lock").start()
         with self._lock:
+            waited.stop()
             now = now if now is not None else timeutil.now_ms()
             self._tick_count += 1
             errors: Dict[int, str] = {}
-            # Flight-recorder stage notes, mirroring the single-chip
-            # TickEngine.submit_columns: "route" is keys -> shard -> slot
-            # and the hit/miss accounting, the layer the sharded table
-            # adds on the host; "pack" the slab rows, the slot sort and
-            # the slab's tail (the lease is broken out beside it).
             fr = flightrec.get()
-            t0 = time.perf_counter() if fr is not None else 0.0
-            # The native window pass cleans the rows it packs.
-            slab = self._staging.lease(
-                self.max_batch, clean=self._window_pass is None)
+            with flightrec.stage("pack"):
+                with flightrec.stage("lease"):
+                    # The native window pass cleans the rows it packs.
+                    slab = self._staging.lease(
+                        self.max_batch, clean=self._window_pass is None)
+                sh, slots, ix, inv, has_dups, route_s = self._pack_window(
+                    cols, now, slab, errors)
             if fr is not None:
-                fr.note(fr.active(), "lease", time.perf_counter() - t0)
-                t0 = time.perf_counter()
-            sh, slots, ix, inv, has_dups, route_s = self._pack_window(
-                cols, now, slab, errors)
-            if fr is not None:
-                spent = time.perf_counter() - t0
                 fr.note(fr.active(), "route", route_s)
-                fr.note(fr.active(), "pack", spent - route_s)
+                fr.note(fr.active(), "pack", -route_s)
             return self._dispatch_ragged(
                 cols, now, slab, sh, slots, ix, inv, has_dups, errors)
 
@@ -890,8 +893,7 @@ class MeshTickEngine:
         stamp_now(now_words, now)
         if wp is not None:
             n = len(cols)
-            with tracing.profile_annotation("guber.mesh.pack_window"), \
-                    tracing.maybe_span("guber.mesh.pack_window", {"batch": n}):
+            with tracing.maybe_span("guber.mesh.pack_window", {"batch": n}):
                 status, sh, slots, known, inv, n_miss, counts, route_s = (
                     wp.pack_window(
                         cols, m, now, self.store is not None,
@@ -955,9 +957,7 @@ class MeshTickEngine:
         host loop, no padded per-shard block, responses gathered with
         one psum."""
         n = len(cols)
-        fr = flightrec.get()
-        t0 = time.perf_counter() if fr is not None else 0.0
-        with tracing.profile_annotation("guber.mesh.tick"), \
+        with flightrec.stage("h2d"), \
                 tracing.maybe_span("guber.mesh.dispatch_ragged",
                                    {"batch": n}):
             if has_dups:
@@ -967,8 +967,7 @@ class MeshTickEngine:
                 self.metric_unique_windows += 1
                 tick = self.ops.tick_unique_ragged
             self.state, resp = tick(self.state, self.ops.put_slab(slab))
-        if fr is not None:
-            fr.note(fr.active(), "h2d", time.perf_counter() - t0)
+        rest = flightrec.stage("handle").start()
         self._pending.clear()
         self.metric_routed_windows += 1
         self.metric_h2d_uploads += 1
@@ -985,6 +984,7 @@ class MeshTickEngine:
             self.metric_h2d_overlapped += 1
         self._inflight += 1
         self._staging.retire(handle)
+        rest.stop()
         if self.store is not None:
             handle.result()
         return handle

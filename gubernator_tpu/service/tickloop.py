@@ -233,6 +233,8 @@ class TickLoop:
                 fut.set_exception(RuntimeError("tick loop is shut down"))
                 return fut
             item = QueueItem(kind, payload, n, fut, deadline, klass)
+            if flightrec.enabled():
+                item.t_enq = time.perf_counter()   # the "queue" overlay
             lvl = self._freeze_level
             if lvl and (lvl >= 2 or klass == CLASS_CLIENT):
                 frozen, shed = item, ()
@@ -263,6 +265,10 @@ class TickLoop:
 
     @hot_path
     def _run(self) -> None:
+        # The flight recorder's "wait": this thread between windows,
+        # from the end of one _flush to the pop of the next batch.  It
+        # ends before its window is begun, so _flush notes it.
+        wait = flightrec.stage("wait", into=None).start()
         while True:
             batch: List[QueueItem] = []
             stopping = False
@@ -309,17 +315,29 @@ class TickLoop:
                 # resolver handoff queue is bounded, and a full pipeline
                 # must park the dispatch thread without wedging every
                 # _cond waiter behind it (guberlint G007).
+                wait.stop()
                 # guber: allow-G001(shutdown-only drain sentinel - runs once at loop exit, never inside a serving tick)
                 self._resolve_q.put(None)
                 return
             if batch:
-                self._flush(batch)
+                wait.stop()
+                self._flush(batch, wait)
+                wait = flightrec.stage("wait", into=None).start()
 
     @hot_path
-    def _flush(self, batch: List[QueueItem]) -> None:
+    def _flush(self, batch: List[QueueItem], wait=flightrec.OFF) -> None:
         """Dispatch one window.  Object and columnar submissions each
         coalesce into (at most) one engine submission; both ride the same
-        resolver handoff and resolve together in one D2H."""
+        resolver handoff and resolve together in one D2H.
+
+        Flight-recorder stages (utils/flightrec.py), consecutive on this
+        thread: ``wait`` (the caller's, noted into the window once it is
+        begun), ``gather`` to the call into the engine, the engine's own,
+        ``handoff`` from its return; beside them the overlays ``queue``
+        (pop time less the items' enqueue stamps) and ``cpu``."""
+        fr = flightrec.get()
+        cpu0 = time.thread_time() if fr is not None else 0.0
+        gather = flightrec.stage("gather").start()
         # Deadline-aware admission (docs/overload.md): shed anything
         # already expired BEFORE packing — the device never burns a tick
         # answering an RPC whose caller has given up.  Shed items are
@@ -331,15 +349,19 @@ class TickLoop:
             for it in expired:
                 self._shed_item(it, "expired")
         if not batch:
+            gather.stop()
             self._window_done()
             return
         # Flight-recorder window open (docs/observability.md): the engine
-        # notes lease/pack/h2d into the active window while we dispatch.
-        fr = flightrec.get()
+        # notes its stages into the active window while we dispatch.
         wid = None
         if fr is not None:
             wid = fr.begin(
                 sum(it.n for it in batch), self._resolve_q.qsize())
+            fr.note(wid, "wait", wait.seconds)
+            stamps = [it.t_enq for it in batch if it.t_enq]
+            if stamps and wait.t1:
+                fr.note(wid, "queue", wait.t1 - sum(stamps) / len(stamps))
         t0 = time.perf_counter()
         obj_items: List[tuple] = []   # (n, fut)
         reqs: List[RateLimitRequest] = []
@@ -365,45 +387,57 @@ class TickLoop:
         # windows in one D2H.  There is deliberately no synchronous
         # fallback — an engine without submit/submit_cols is a bug.
         subs = []
-        if reqs:
-            try:
-                subs.append(("obj", self.engine.submit(reqs), obj_items,
-                             len(reqs)))
-            except Exception as e:
-                _fail_waiters(obj_items, e)
+        cols = None
         if col_parts:
             from gubernator_tpu.ops.reqcols import ReqColumns
 
             try:
-                subs.append((
-                    "cols",
-                    self.engine.submit_cols(ReqColumns.concat(col_parts)),
-                    col_items,
-                    sum(n for n, _ in col_items),
-                ))
+                cols = ReqColumns.concat(col_parts)
             except Exception as e:
                 _fail_waiters(col_items, e)
-            finally:
-                # Arena-backed batches (fastwire decode slabs) recycle
-                # the moment the engine has packed them — submit_cols
-                # copies every column into the device request matrix
-                # before returning, so the views are dead here.
-                for p in col_parts:
-                    p.release()
+        gather.stop()
+        try:
+            if reqs:
+                try:
+                    subs.append(("obj", self.engine.submit(reqs),
+                                 obj_items, len(reqs)))
+                except Exception as e:
+                    _fail_waiters(obj_items, e)
+            if cols is not None:
+                try:
+                    subs.append(("cols", self.engine.submit_cols(cols),
+                                 col_items, sum(n for n, _ in col_items)))
+                except Exception as e:
+                    _fail_waiters(col_items, e)
+        finally:
+            handoff = flightrec.stage("handoff", into=wid).start()
+            # Arena-backed batches (fastwire decode slabs) recycle
+            # the moment the engine has packed them — submit_cols
+            # copies every column into the device request matrix
+            # before returning, so the views are dead here.
+            for p in col_parts:
+                p.release()
+        if fr is not None and wid is not None:
+            fr.end_dispatch(wid)
         if not subs:
+            handoff.stop()
             if fr is not None and wid is not None:
-                fr.end_dispatch(wid)
+                fr.note(wid, "cpu", time.thread_time() - cpu0)
                 fr.finish(wid)
             self._window_done()
             return
-        if fr is not None and wid is not None:
-            fr.end_dispatch(wid)
         # Bounded handoff: blocks when pipeline_depth windows are already
         # in flight (device behind), which is exactly the backpressure the
         # dispatch thread should feel.  The in-flight count was taken at
         # pop time in _run; the resolver releases it after the D2H drain.
         # guber: allow-G001(deliberate bounded-pipeline backpressure - blocking here when pipeline_depth windows are in flight IS the flow control)
         self._resolve_q.put((subs, time.perf_counter() - t0, wid))
+        # Noted after the handoff, whose wait is the stage: a resolver
+        # that seals the window first leaves these two out of that
+        # window's histogram sample and slow check, never of the ring.
+        handoff.stop()
+        if fr is not None and wid is not None:
+            fr.note(wid, "cpu", time.thread_time() - cpu0)
 
     def _window_done(self) -> None:
         """Release one window's in-flight count without a resolver trip
@@ -434,38 +468,38 @@ class TickLoop:
                     break
                 items.append(nxt)
             fr = flightrec.get()
-            t_drain = time.perf_counter()
-            try:
-                from gubernator_tpu.ops.engine import resolve_ticks
+            # All drained windows share this one D2H wait; each reports
+            # it as its tick time (documented in flightrec).
+            with flightrec.stage("tick", into=None) as drain:
+                try:
+                    from gubernator_tpu.ops.engine import resolve_ticks
 
-                resolve_ticks([
-                    h
-                    for subs, _, _ in items
-                    for _, sb, _, _ in subs
-                    for h in sb.handles()
-                ])
-            except Exception:
-                pass  # per-window resolution below surfaces real errors
+                    resolve_ticks([
+                        h
+                        for subs, _, _ in items
+                        for _, sb, _, _ in subs
+                        for h in sb.handles()
+                    ])
+                except Exception:
+                    pass  # per-window resolution below surfaces real errors
             if fr is not None:
-                # All drained windows shared this one D2H wait; each
-                # reports it as its tick time (documented in flightrec).
-                drain_s = time.perf_counter() - t_drain
                 for _, _, wid in items:
                     if wid is not None:
-                        fr.note(wid, "tick", drain_s)
+                        fr.note(wid, "tick", drain.seconds)
             for subs, dispatch_s, wid in items:
                 for kind, sb, waiters, n_reqs in subs:
                     # Guarded: an exception escaping this loop would kill
                     # the resolver thread and wedge the whole pipeline
                     # (dispatch eventually blocks on the bounded queue).
                     try:
+                        # timed recorder or not: the limiter's sample
                         t1 = time.perf_counter()
-                        out = (
-                            sb.responses() if kind == "obj" else sb.matrix()
-                        )
+                        with flightrec.stage("resolve", into=wid):
+                            out = (
+                                sb.responses() if kind == "obj"
+                                else sb.matrix()
+                            )
                         resolve_s = time.perf_counter() - t1
-                        if fr is not None and wid is not None:
-                            fr.note(wid, "resolve", resolve_s)
                     except Exception as e:
                         _fail_waiters(waiters, e)
                         continue

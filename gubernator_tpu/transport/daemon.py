@@ -221,19 +221,18 @@ async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
         arena, metrics = instance.ingest_arena, instance.metrics
         # Flight-recorder transport edges: per-batch decode/encode CPU
         # (folded into window records — see utils/flightrec.py).
-        fr = flightrec.get()
-        t0 = time.perf_counter() if fr is not None else 0.0
-        try:
-            parsed = fastwire.parse_req(raw, arena)
-        except IngestOverloadError as e:
-            # Bounded ingest (docs/overload.md): arena exhaustion past
-            # the fallback budget is backpressure, not an allocation —
-            # answer retriable RESOURCE_EXHAUSTED so clients back off.
-            metrics.admission_shed.labels(reason="backpressure").inc()
-            await context.abort(grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
-        _sync_arena_metrics(arena, metrics)
-        if fr is not None:
-            fr.edge("decode", time.perf_counter() - t0)
+        with flightrec.stage("decode"):
+            try:
+                parsed = fastwire.parse_req(raw, arena)
+            except IngestOverloadError as e:
+                # Bounded ingest (docs/overload.md): arena exhaustion
+                # past the fallback budget is backpressure, not an
+                # allocation — answer retriable RESOURCE_EXHAUSTED so
+                # clients back off.
+                metrics.admission_shed.labels(reason="backpressure").inc()
+                await context.abort(
+                    grpc.StatusCode.RESOURCE_EXHAUSTED, str(e))
+            _sync_arena_metrics(arena, metrics)
         decoded = parsed is not None
         if not decoded:  # codec unavailable or malformed bytes
             msg = await _parse_pb(msg_type, raw, context)
@@ -253,10 +252,8 @@ async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
         # Native wire encoding straight from the matrix; the method's
         # pass-through serializer ships bytes as-is.  The same pass
         # counts the over-limit items, which the service left to it.
-        t1 = time.perf_counter() if fr is not None else 0.0
-        out, over = fastwire.encode_resp(mat)
-        if fr is not None:
-            fr.edge("encode", time.perf_counter() - t1)
+        with flightrec.stage("encode"):
+            out, over = fastwire.encode_resp(mat)
         if over:
             metrics.over_limit_counter.inc(over)
         native = decoded
@@ -443,6 +440,7 @@ class Daemon:
         self._slow_window_ms = env_knob(
             "GUBER_SLOW_WINDOW_MS", 0.0, parse=float)
         self._flight_recorder: Optional[flightrec.FlightRecorder] = None
+        self._synced_stalls: dict = {}   # rec.stalls() as last scraped
         self._debug_exporter: Optional[tracing.InMemoryExporter] = None
         self._watchdog_task: Optional[asyncio.Task] = None
         self._profiling = False
@@ -492,6 +490,7 @@ class Daemon:
             rec.observer = self._observe_stage
             flightrec.install(rec)
             self._flight_recorder = rec
+            self._synced_stalls = rec.stalls()
             self._watchdog_task = spawn_supervised(
                 self._watchdog_loop,
                 name="flight_watchdog",
@@ -706,6 +705,7 @@ class Daemon:
             return web.Response(
                 body=self.metrics.expose(), content_type="text/plain"
             )
+        self._sync_stalls()
         eng = self.instance.engine
         self.metrics.cache_size.set(eng.cache_size())
         if hasattr(eng, "hot_occupancy"):
@@ -722,6 +722,24 @@ class Daemon:
     def _observe_stage(self, stage: str, seconds: float) -> None:
         """Flight-recorder observer: per-stage latency histogram."""
         self.metrics.stage_duration.labels(stage=stage).observe(seconds)
+
+    def _sync_stalls(self) -> None:
+        """The recorder's stall counters into this daemon's registry, at
+        scrape time (the recorder counts; Prometheus gets the delta)."""
+        rec = self._flight_recorder
+        if rec is None:
+            return
+        m, seen, now = self.metrics, self._synced_stalls, rec.stalls()
+        for gen in range(flightrec.GC_GENERATIONS):
+            m.gc_pause_seconds.labels(generation=str(gen)).inc(
+                now["gc_pause_seconds"][gen] - seen["gc_pause_seconds"][gen])
+            m.gc_collections.labels(generation=str(gen)).inc(
+                now["gc_collections"][gen] - seen["gc_collections"][gen])
+        m.serving_compile_seconds.inc(
+            now["serving_compile_seconds"] - seen["serving_compile_seconds"])
+        m.serving_compiles.inc(
+            now["serving_compiles"] - seen["serving_compiles"])
+        self._synced_stalls = now
 
     async def _watchdog_loop(self) -> None:
         """Drain slow-window records parked by FlightRecorder.finish().
@@ -756,8 +774,13 @@ class Daemon:
             return web.json_response({"error": "bad limit"}, status=400)
         return web.json_response({
             "windows": rec.recent(max(1, limit)),
-            "stage_percentiles": rec.stage_percentiles(),
+            "stage_percentiles": rec.snapshot()["stages"],
             "slow_windows": rec.slow_total,
+            # tick-loop's cycle, disjoint and consecutive; and what lies
+            # inside another stage or on another thread: in stages_ms,
+            # not in total_ms (nor is "wait") — utils/flightrec.py
+            "cycle": list(flightrec.CYCLE),
+            "overlays": list(flightrec.OVERLAYS),
         })
 
     @staticmethod
@@ -866,6 +889,10 @@ class Daemon:
             engine_tel["staging_ring"] = staging.telemetry()
         if engine_tel:
             body["engine"] = engine_tel
+        if self._flight_recorder is not None:
+            # collections and first-met shapes since the first window
+            # (the recorder's listeners; also on /metrics)
+            body["stalls"] = self._flight_recorder.stalls()
         body["breakers"] = {
             p.info.grpc_address: p.breaker.state.name
             for p in inst.local_picker.peers()

@@ -2,33 +2,89 @@
 
 A preallocated ring of serving-window records: each window that flows
 through ``TickLoop`` → ``TickEngine``/``MeshTickEngine`` gets one row
-holding its per-stage wall time (decode, shard routing, arena lease,
-pack, H2D dispatch, tick, resolve, encode) plus queue depth and batch
-width.
+holding the wall time of every stage of its way, plus queue depth and
+batch width.
 
 Gating mirrors ``tracing.enabled()``: recording happens only while a
 recorder is installed (``install()``), so an un-instrumented daemon pays
-a single ``is None`` check per window.  The record path itself is
+a single ``is None`` check per stage.  The record path itself is
 ``@hot_path`` code — host-scalar writes into preallocated numpy arrays,
 no device syncs, no locks on the per-stage ``note`` path (each
 (window, stage) cell has exactly one writer).
 
-Stage semantics:
+One way to time a stage: ``with flightrec.stage("pack"):`` (or its
+``start()`` / ``stop()`` pair where the interval is no block).  While a
+recorder is installed it notes the wall seconds into the window in
+dispatch and holds a ``jax.profiler.TraceAnnotation("guber.pack")`` open
+for the same interval, so the stage is a named range on the host plane
+of an XProf capture, on the clock of the device's ``XLA Ops``.  With no
+recorder it is one ``is None`` check and builds nothing.  The resolver's
+three stages (``RESOLVER``) are seconds only: that thread is inside
+``tick`` for nearly the whole of the dispatch thread's cycle, so in a
+trace reduced by "the host event that overlaps a device gap most"
+(benchmarks/harness/xtrace.py) its ranges would own every gap and hide
+the stage the device was waiting for; the fetch inside ``tick`` is the
+runtime's own event there already.
+
+Stage semantics.  ``CYCLE`` is the ``tick-loop`` thread's whole cycle,
+disjoint and consecutive on that one thread, one name on both engines:
+
+- ``wait``: between windows, from the end of one ``_flush`` to the pop
+  of the next batch (the condition variable and BatchWait).  Part of
+  the thread's cycle, no part of the window's work (an idle service
+  waits for as long as nobody calls): left out of ``total_ms`` and the
+  slow-window check.
+- ``gather``: ``_flush`` up to the call into the engine: the expiry
+  partition, the item loop, ``ReqColumns.concat`` of the window's calls.
+- ``submit_lock``: ``submit_columns`` waiting to take ``engine._lock``.
+- ``route`` is the sharded engine's alone (zero on one chip): keys to
+  shards by CRC-32, the batch regrouped by shard, one native slot
+  resolve a shard, and the hit/miss accounting; noted from the native
+  pass's own clock and taken off the ``pack`` range that holds the call.
+- ``pack``: the host pack, the arena ``lease`` inside it; ``ssd`` is the
+  miss path's batched slab-store lookup, broken OUT of ``pack`` (the
+  engine subtracts it), so a pack regression can't hide SSD I/O and
+  vice versa.
+- ``h2d``: the upload and the program call, queued and not awaited.
+- ``handle``: ``submit_columns`` after ``h2d`` to its return: the
+  ``TickHandle``, the slab's retirement, the counters.
+- ``handoff``: back in ``_flush``: the parts' ``release()`` and the
+  bounded ``_resolve_q.put`` (which blocks when the pipeline is full).
+
+Beside them, on other threads:
 
 - ``decode``/``encode`` are transport edges recorded per request batch
   via ``edge()``; decode time accumulates and folds into the *next*
   window begun, encode attaches to the most recently finished window
   (a window's decode is the CPU that fed it; its encode trails it).
-- ``route`` is the sharded engine's alone (zero on one chip): keys to
-  shards by CRC-32, the batch regrouped by shard, one native slot
-  resolve a shard, and the hit/miss accounting; the mesh's ``pack``
-  starts after it.
-- ``pack`` includes the arena ``lease`` (also broken out separately);
-  ``ssd`` is the miss path's batched slab-store lookup, broken OUT of
-  ``pack`` (the engine subtracts it), so a pack regression can't hide
-  SSD I/O and vice versa.
 - ``tick`` is the shared D2H wait of the resolver drain that resolved
   the window; windows resolved in one drain report the same tick time.
+  ``resolve`` is the window's own un-permute and delivery.
+
+``OVERLAYS`` lie inside another stage or on another thread.  They are
+recorded in the ring, the totals and the histogram like any stage, and
+left out of a window's ``total_ms`` and of the slow-window check, which
+would count their seconds twice:
+
+- ``lease``: the staging slab's lease, inside ``pack``.
+- ``queue``: the mean, over the window's items, of pop time less
+  enqueue time (``QueueItem.t_enq``, stamped only while a recorder is
+  installed).
+- ``finish_lock``: the resolver's wait to take ``engine._lock`` in
+  ``TickHandle._finish``, inside ``tick``.
+- ``cpu``: ``time.thread_time()`` across ``_flush`` on ``tick-loop``:
+  what the thread executed, so the stages of ``_flush`` less ``cpu`` is
+  what it spent off the CPU (the GIL, a lock, the runtime).
+- ``compile``: seconds of trace, lower and backend compile met while
+  serving (``jax.monitoring``'s duration events, on whichever thread).
+- ``gc``: seconds of garbage collections (``gc.callbacks``), any
+  generation, which stop every thread of the process.
+
+``compile`` and ``gc`` go into the window in dispatch or else the newest
+begun; their listeners are set by ``install()`` and dropped by
+``uninstall()``, and the recorder's own counters of them
+(``gc_pause_s``, ``gc_collections``, ``compile_s``, ``compiles``) are
+what ``/metrics`` and ``/debug/state`` read.
 
 The slow-window watchdog is split so the hot path stays cheap:
 ``finish()`` only compares the row total against ``slow_threshold_s``
@@ -39,7 +95,7 @@ drains them (``drain_slow()``), dumps each record, and bumps
 
 from __future__ import annotations
 
-import threading
+import gc
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
@@ -49,13 +105,22 @@ import numpy as np
 from gubernator_tpu.utils.hotpath import hot_path
 from gubernator_tpu.utils import sanitize
 
-STAGES = (
-    "decode", "route", "lease", "pack", "ssd", "h2d", "tick", "resolve",
-    "encode",
+CYCLE = (
+    "wait", "gather", "submit_lock", "route", "pack", "ssd", "h2d",
+    "handle", "handoff",
 )
+OVERLAYS = ("lease", "queue", "finish_lock", "cpu", "compile", "gc")
+RESOLVER = ("tick", "resolve", "finish_lock")   # seconds, no range
+STAGES = ("decode",) + CYCLE + ("tick", "resolve", "encode") + OVERLAYS
 _IDX = {s: i for i, s in enumerate(STAGES)}
+_RANGE = {s: "guber." + s for s in STAGES if s not in RESOLVER}
 _DECODE = _IDX["decode"]
 _ENCODE = _IDX["encode"]
+# 1.0 for the stages a window's total (and the slow check) adds up.
+_IN_TOTAL = np.array(
+    [s not in OVERLAYS and s != "wait" for s in STAGES], np.float64)
+GC_GENERATIONS = 3
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 class FlightRecorder:
@@ -86,6 +151,13 @@ class FlightRecorder:
         self._pending_decode = 0.0
         self.slow_total = 0
         self._slow: deque = deque(maxlen=32)
+        # Stalls met while serving (after the first window was begun),
+        # written by the process's collector and jax.monitoring hooks
+        # (note_gc / note_compile) and read by /metrics and /debug/state.
+        self.gc_pause_s = [0.0] * GC_GENERATIONS
+        self.gc_collections = [0] * GC_GENERATIONS
+        self.compile_s = 0.0
+        self.compiles = 0   # backend compiles: one a first-met shape
 
     # -- record path (hot) ---------------------------------------------
     @hot_path
@@ -127,7 +199,7 @@ class FlightRecorder:
                     obs(stage, row[i])
         thresh = self.slow_threshold_s
         if thresh > 0.0:
-            total = self._stage_s[slot].sum()
+            total = self._stage_s[slot] @ _IN_TOTAL
             if total > thresh:
                 with self._lock:
                     self.slow_total += 1
@@ -161,7 +233,49 @@ class FlightRecorder:
         if obs is not None:
             obs(stage, seconds)
 
+    def _stalled(self) -> Optional[int]:
+        """The window a stall of the whole process is noted into: the
+        one in dispatch, else the newest begun; None before the first
+        (start-up's compiles and collections are not serving's).  Takes
+        no lock: the collector's hook runs wherever an allocation
+        happens, ``begin`` included."""
+        wid = self._active
+        if wid is None:
+            wid = self._seq - 1
+        return wid if wid >= 0 else None
+
+    def note_gc(self, generation: int, seconds: float) -> None:
+        wid = self._stalled()
+        if wid is None:
+            return
+        self.gc_pause_s[generation] += seconds
+        self.gc_collections[generation] += 1
+        self.note(wid, "gc", seconds)
+
+    def note_compile(self, event: str, seconds: float) -> None:
+        wid = self._stalled()
+        if wid is None:
+            return
+        self.compile_s += seconds
+        if event == _BACKEND_COMPILE:
+            self.compiles += 1
+        self.note(wid, "compile", seconds)
+
     # -- read path -----------------------------------------------------
+    @staticmethod
+    def _record(wid, row, width, depth, wall) -> dict:
+        return {
+            "window": int(wid),
+            "wall": float(wall),
+            "width": int(width),
+            "queue_depth": int(depth),
+            "stages_ms": {
+                s: round(float(row[i]) * 1e3, 4) for s, i in _IDX.items()
+            },
+            # the window's work: OVERLAYS and ``wait`` are not in it
+            "total_ms": round(float(row @ _IN_TOTAL) * 1e3, 4),
+        }
+
     def recent(self, n: int = 64) -> List[dict]:
         """Finished window records, oldest→newest, as JSON-ready dicts."""
         out: List[dict] = []
@@ -170,75 +284,48 @@ class FlightRecorder:
             lo = max(0, seq - min(n, self.windows))
             for wid in range(lo, seq):
                 slot = wid % self.windows
-                if not self._valid[slot]:
-                    continue
-                stages = {
-                    s: round(float(self._stage_s[slot, i]) * 1e3, 4)
-                    for s, i in _IDX.items()
-                }
-                out.append({
-                    "window": wid,
-                    "wall": float(self._wall[slot]),
-                    "width": int(self._width[slot]),
-                    "queue_depth": int(self._depth[slot]),
-                    "stages_ms": stages,
-                    "total_ms": round(sum(stages.values()), 4),
-                })
-        return out
-
-    def stage_percentiles(self) -> Dict[str, Dict[str, float]]:
-        """Per-stage p50/p99 (ms) over finished windows in the ring.
-        Zero cells (stage never ran in that window) are excluded."""
-        out: Dict[str, Dict[str, float]] = {}
-        with self._lock:
-            mask = self._valid.copy()
-            stage_s = self._stage_s.copy()
-        for s, i in _IDX.items():
-            col = stage_s[mask, i]
-            col = col[col > 0.0]
-            if col.size == 0:
-                out[s] = {"p50_ms": 0.0, "p99_ms": 0.0}
-            else:
-                out[s] = {
-                    "p50_ms": round(float(np.percentile(col, 50)) * 1e3, 4),
-                    "p99_ms": round(float(np.percentile(col, 99)) * 1e3, 4),
-                }
+                if self._valid[slot]:
+                    out.append(self._record(
+                        wid, self._stage_s[slot], self._width[slot],
+                        self._depth[slot], self._wall[slot]))
         return out
 
     def snapshot(self) -> dict:
-        """Per-stage and whole-window p50/p99 (ms) plus ring metadata —
-        the control plane's view (autoscaler, /debug/autoscaler).  Not
-        ``@hot_path``: one lock-copy on the controller's cadence."""
+        """Per-stage and whole-window p50/p99 (ms) over finished windows
+        in the ring, plus ring metadata: /debug/pipeline's and the
+        control plane's view (autoscaler, /debug/autoscaler).  Zero
+        cells (stage never ran in that window) are excluded.  Not
+        ``@hot_path``: one lock-copy on the reader's cadence."""
         with self._lock:
             mask = self._valid.copy()
             stage_s = self._stage_s.copy()
             slow_total = self.slow_total
-        totals = stage_s[mask].sum(axis=1)
-        totals = totals[totals > 0.0]
-        if totals.size == 0:
-            total = {"p50_ms": 0.0, "p99_ms": 0.0}
-        else:
-            total = {
-                "p50_ms": round(float(np.percentile(totals, 50)) * 1e3, 4),
-                "p99_ms": round(float(np.percentile(totals, 99)) * 1e3, 4),
-            }
-        out: Dict[str, Dict[str, float]] = {}
-        for s, i in _IDX.items():
-            col = stage_s[mask, i]
+
+        def pcts(col):
             col = col[col > 0.0]
             if col.size == 0:
-                out[s] = {"p50_ms": 0.0, "p99_ms": 0.0}
-            else:
-                out[s] = {
-                    "p50_ms": round(float(np.percentile(col, 50)) * 1e3, 4),
-                    "p99_ms": round(float(np.percentile(col, 99)) * 1e3, 4),
-                }
+                return {"p50_ms": 0.0, "p99_ms": 0.0}
+            return {
+                "p50_ms": round(float(np.percentile(col, 50)) * 1e3, 4),
+                "p99_ms": round(float(np.percentile(col, 99)) * 1e3, 4),
+            }
+
+        done = stage_s[mask]
         return {
-            "stages": out,
-            "total": total,
+            "stages": {s: pcts(done[:, i]) for s, i in _IDX.items()},
+            "total": pcts(done @ _IN_TOTAL),
             "windows": int(mask.sum()),
             "ring_size": self.windows,
             "slow_total": slow_total,
+        }
+
+    def stalls(self) -> dict:
+        """The four stall counters, as /debug/state shows them."""
+        return {
+            "gc_pause_seconds": list(self.gc_pause_s),
+            "gc_collections": list(self.gc_collections),
+            "serving_compile_seconds": self.compile_s,
+            "serving_compiles": self.compiles,
         }
 
     def drain_slow(self) -> List[dict]:
@@ -246,18 +333,7 @@ class FlightRecorder:
         out: List[dict] = []
         with self._lock:
             while self._slow:
-                wid, row, width, depth, wall = self._slow.popleft()
-                out.append({
-                    "window": int(wid),
-                    "wall": float(wall),
-                    "width": int(width),
-                    "queue_depth": int(depth),
-                    "stages_ms": {
-                        s: round(float(row[i]) * 1e3, 4)
-                        for s, i in _IDX.items()
-                    },
-                    "total_ms": round(float(row.sum()) * 1e3, 4),
-                })
+                out.append(self._record(*self._slow.popleft()))
         return out
 
 
@@ -265,16 +341,142 @@ class FlightRecorder:
 # Process-global recorder slot (mirrors tracing's global tracer: the
 # in-process test cluster shares one recorder across daemons).
 _recorder: Optional[FlightRecorder] = None
+# jax.profiler.TraceAnnotation, bound at the first install() so that
+# importing this module imports no jax.
+_annotation = None
+
+
+class _Stage:
+    """One timed stage of the installed recorder: a named range in a
+    profiler capture (but for ``RESOLVER``'s) and, at its end, the
+    seconds noted.  ``into`` says where: ``ACTIVE`` the window in
+    dispatch when the stage ends, a window id that window, ``None``
+    nowhere (the caller notes ``seconds`` itself: a stage that ends
+    before its window is begun, or belongs to several).  ``decode`` and
+    ``encode`` go through ``edge()``."""
+
+    __slots__ = ("_fr", "_name", "_into", "_range", "_t0", "t1", "seconds")
+
+    def __init__(self, fr: FlightRecorder, name: str, into):
+        self._fr = fr
+        self._name = name
+        self._into = into
+        self.t1 = self.seconds = 0.0
+
+    @hot_path
+    def start(self) -> "_Stage":
+        # The clock is read first here and last in stop(): the range's
+        # own cost falls inside the stage, not between two stages, so
+        # consecutive stages tile the thread's time.
+        self._t0 = time.perf_counter()
+        name = _RANGE.get(self._name)
+        self._range = rng = None if name is None else _annotation(name)
+        if rng is not None:
+            rng.__enter__()
+        return self
+
+    @hot_path
+    def stop(self) -> None:
+        if self._range is not None:
+            self._range.__exit__(None, None, None)
+        self.t1 = t1 = time.perf_counter()
+        self.seconds = dt = t1 - self._t0
+        fr, name, into = self._fr, self._name, self._into
+        if name == "decode" or name == "encode":
+            fr.edge(name, dt)
+        elif into is ACTIVE:
+            fr.note(fr.active(), name, dt)
+        elif into is not None:
+            fr.note(into, name, dt)
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> bool:
+        self.stop()
+        return False
+
+
+class _NoStage:
+    """What ``stage()`` hands out with no recorder installed."""
+
+    __slots__ = ()
+    t1 = seconds = 0.0
+
+    def start(self) -> "_NoStage":
+        return self
+
+    def stop(self) -> None:
+        pass
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+ACTIVE = object()
+OFF = _NoStage()
+
+
+@hot_path
+def stage(name: str, into=ACTIVE):
+    """Time one stage: ``with stage("pack"):`` or ``s = stage(...).start()``
+    ... ``s.stop()``.  See :class:`_Stage`; with no recorder installed,
+    one check and a shared no-op."""
+    fr = _recorder
+    if fr is None:
+        return OFF
+    return _Stage(fr, name, into)
+
+
+# A collection stops every thread, so one start/stop pair is in flight
+# at a time and module state holds it.
+_gc_open = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    fr = _recorder
+    if fr is None:
+        return
+    if phase == "start":
+        _gc_open = _Stage(fr, "gc", None).start()
+    elif _gc_open is not None:
+        st, _gc_open = _gc_open, None
+        st.stop()
+        fr.note_gc(info["generation"], st.seconds)
+
+
+def _on_compile(event: str, seconds: float, **_kw) -> None:
+    fr = _recorder
+    if fr is not None and event.startswith("/jax/core/compile/"):
+        fr.note_compile(event, seconds)
 
 
 def install(recorder: FlightRecorder) -> None:
-    global _recorder
+    """Install ``recorder`` and the two listeners that feed its stall
+    overlays (the collector's callbacks, jax.monitoring's durations)."""
+    global _recorder, _annotation
+    import gubernator_tpu.jaxinit  # noqa: F401  (x64 + cache before jax use)
+    import jax.monitoring
+    import jax.profiler
+
+    _annotation = jax.profiler.TraceAnnotation
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
     _recorder = recorder
 
 
 def uninstall() -> None:
-    global _recorder
+    global _recorder, _gc_open
     _recorder = None
+    _gc_open = None
+    if _on_gc in gc.callbacks:
+        import jax.monitoring
+
+        gc.callbacks.remove(_on_gc)
+        jax.monitoring.unregister_event_duration_listener(_on_compile)
 
 
 def get() -> Optional[FlightRecorder]:

@@ -746,8 +746,9 @@ class Metrics:
         )
         self.stage_duration = Histogram(
             "gubernator_tpu_stage_duration_seconds",
-            "Per-stage serving-window latency histogram (stages: decode, "
-            "lease, pack, h2d, tick, resolve, encode), fed by the flight "
+            "Per-stage serving-window latency histogram (label stage: "
+            "every name in utils/flightrec.py STAGES, the tick-loop "
+            "cycle's stages and the overlays), fed by the flight "
             "recorder when one is installed.",
             ["stage"],
             registry=reg,
@@ -757,6 +758,39 @@ class Metrics:
             "Serving windows whose summed stage time exceeded "
             "GUBER_SLOW_WINDOW_MS; each one's flight record is dumped to "
             "the log by the watchdog.",
+            registry=reg,
+        )
+        # Stalls of the whole serving process, from the flight
+        # recorder's own counters (its gc.callbacks and jax.monitoring
+        # listeners; synced at scrape time, Daemon._sync_stalls).
+        self.gc_pause_seconds = Counter(
+            "gubernator_tpu_gc_pause_seconds",
+            "Seconds the serving process spent in garbage collections "
+            "since its first window, by generation; every thread stops "
+            "for them. Counted while a flight recorder is installed.",
+            ["generation"],
+            registry=reg,
+        )
+        self.gc_collections = Counter(
+            "gubernator_tpu_gc_collections",
+            "Garbage collections of the serving process since its first "
+            "window, by generation. Counted while a flight recorder is "
+            "installed.",
+            ["generation"],
+            registry=reg,
+        )
+        self.serving_compile_seconds = Counter(
+            "gubernator_tpu_serving_compile_seconds",
+            "Seconds of jax trace, lowering and backend compile met "
+            "after the first window: a shape the warm-up did not cover. "
+            "Counted while a flight recorder is installed.",
+            registry=reg,
+        )
+        self.serving_compiles = Counter(
+            "gubernator_tpu_serving_compiles",
+            "Backend compiles (one a first-met shape, cache hit or not) "
+            "after the first window. Counted while a flight recorder is "
+            "installed.",
             registry=reg,
         )
 

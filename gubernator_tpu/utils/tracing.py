@@ -16,9 +16,9 @@ tasks), exporters are pluggable, and the wire format is the standard W3C
 reference peer.  When the OpenTelemetry SDK *is* importable, installing
 :class:`OtelBridgeExporter` re-emits finished spans through it.
 
-TPU twist: :func:`profile_annotation` wraps device work in
-``jax.profiler.TraceAnnotation`` so engine ticks show up as named ranges
-in TensorBoard/XProf captures alongside the service-level spans.
+The serving stages' named ranges in TensorBoard/XProf captures
+(``jax.profiler.TraceAnnotation``) are the flight recorder's:
+``utils/flightrec.py`` ``stage()``.
 """
 
 from __future__ import annotations
@@ -88,9 +88,6 @@ class Span:
     def duration_ms(self) -> float:
         return (self.end_ns - self.start_ns) / 1e6
 
-    def set_attribute(self, key: str, value: object) -> None:
-        self.attributes[key] = value
-
     def add_event(self, name: str, attributes: Optional[Dict] = None) -> None:
         """Annotate a point in time (the reference's span.AddEvent calls on
         algorithm branches, algorithms.go:57-66,163-174)."""
@@ -131,10 +128,6 @@ class InMemoryExporter(SpanExporter):
     def by_name(self, name: str) -> List[Span]:
         with self._lock:
             return [s for s in self.spans if s.name == name]
-
-    def clear(self) -> None:
-        with self._lock:
-            self.spans.clear()
 
 
 class OtelBridgeExporter(SpanExporter):
@@ -387,14 +380,3 @@ def maybe_span(name, attributes=None, parent=None, root=False):
         return contextlib.nullcontext()
     return _tracer.span(name, attributes, parent, root)
 
-
-def profile_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` naming device work in XProf
-    captures; degrades to a no-op when the profiler is unavailable."""
-    try:
-        import gubernator_tpu.jaxinit  # noqa: F401  (x64 + cache before jax use)
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler always present with jax
-        return contextlib.nullcontext()

@@ -220,7 +220,7 @@ def test_flightrec_edge_decode_folds_into_next_window():
     wid = fr.begin(width=32, depth=1)
     fr.note(wid, "tick", 0.001)
     fr.finish(wid)
-    pct = fr.stage_percentiles()
+    pct = fr.snapshot()["stages"]
     assert pct["decode"]["p50_ms"] == pytest.approx(6.0)
     assert ("decode", 0.004) in seen and ("decode", 0.002) in seen
     # The next window starts clean: pending decode was consumed.

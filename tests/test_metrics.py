@@ -305,11 +305,14 @@ def test_histogram_exemplars_link_trace_ids():
     assert "trace_id" not in h2.openmetrics()
 
 
-async def test_debug_endpoints_serve_populated_json(monkeypatch):
+async def test_debug_endpoints_serve_populated_json(monkeypatch, caplog):
     """GUBER_DEBUG_ENDPOINTS=1: /debug/pipeline, /debug/state and
     /debug/traces all answer populated JSON on a live daemon after a
     few requests (the issue's acceptance criterion), and the per-method
     gRPC latency histogram saw every call."""
+    import asyncio
+    import gc
+
     import aiohttp
 
     from gubernator_tpu.config import BehaviorConfig, Config
@@ -336,6 +339,25 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
             await client.get_rate_limits(reqs)
         await client.close()
 
+        # A window slowed by a forced collection: the watchdog dumps it
+        # with "gc" named (the collector's hook notes the pause into the
+        # window in dispatch, beside the stage it fell in).
+        rec = flightrec.get()
+        rec.slow_threshold_s = 1e-4
+        wid = rec.begin(width=1, depth=0)
+        with flightrec.stage("gather"):
+            gc.collect()
+        rec.end_dispatch(wid)
+        rec.finish(wid)
+        rec.slow_threshold_s = 0.0
+        with caplog.at_level("WARNING", logger="gubernator.daemon"):
+            for _ in range(100):
+                if d.metrics.sample("gubernator_tpu_slow_windows_total"):
+                    break
+                await asyncio.sleep(0.05)
+        dumps = [m for m in caplog.messages if m.startswith("slow window")]
+        assert dumps and "'gc':" in dumps[-1] and "'gather':" in dumps[-1]
+
         base = f"http://{d.conf.http_listen_address}"
         async with aiohttp.ClientSession() as s:
             async with s.get(f"{base}/debug/pipeline") as r:
@@ -344,13 +366,49 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch):
             async with s.get(f"{base}/debug/state") as r:
                 assert r.status == 200
                 state = await r.json()
+            async with s.get(f"{base}/metrics") as r:
+                assert r.status == 200
+                exposed = await r.text()
             async with s.get(f"{base}/debug/traces") as r:
                 assert r.status == 200
                 traces = await r.json()
 
         assert pipe["windows"], pipe
         assert set(pipe["windows"][0]["stages_ms"]) == set(flightrec.STAGES)
-        assert "pack" in pipe["stage_percentiles"]
+        assert set(pipe["stage_percentiles"]) == set(flightrec.STAGES)
+        # the served windows show the whole cycle and the overlays, and
+        # /debug/pipeline says which names a total leaves out
+        assert pipe["cycle"] == list(flightrec.CYCLE)
+        assert pipe["overlays"] == list(flightrec.OVERLAYS)
+        served = [w["stages_ms"] for w in pipe["windows"][:3]]
+        for name in ("gather", "submit_lock", "pack", "h2d", "handle",
+                     "handoff", "tick", "resolve", "queue", "finish_lock",
+                     "cpu", "lease"):
+            assert all(ms[name] > 0 for ms in served), (name, served)
+        for w in pipe["windows"]:
+            assert w["total_ms"] == pytest.approx(sum(
+                v for k, v in w["stages_ms"].items()
+                if k not in flightrec.OVERLAYS and k != "wait"), abs=1e-2)
+        # the four stall counters: on the recorder, in /debug/state, in
+        # Prometheus (synced at the scrape), and the new stage labels
+        stalls = state["stalls"]
+        assert stalls["gc_collections"][2] >= 1
+        assert stalls["gc_pause_seconds"][2] > 0
+        assert stalls["serving_compiles"] >= 0
+        assert d.metrics.sample(
+            "gubernator_tpu_gc_collections_total", {"generation": "2"}
+        ) >= stalls["gc_collections"][2]
+        assert d.metrics.sample(
+            "gubernator_tpu_gc_pause_seconds_total", {"generation": "2"}) > 0
+        for family in ("gubernator_tpu_gc_pause_seconds_total{",
+                       "gubernator_tpu_gc_collections_total{",
+                       "gubernator_tpu_serving_compile_seconds_total ",
+                       "gubernator_tpu_serving_compiles_total ",
+                       'stage_duration_seconds_count{stage="gather"}',
+                       'stage_duration_seconds_count{stage="handle"}',
+                       'stage_duration_seconds_count{stage="finish_lock"}',
+                       'stage_duration_seconds_count{stage="gc"}'):
+            assert family in exposed, family
         assert state["ready"] is True
         assert state["occupancy"]
         assert "breakers" in state and "redelivery" in state

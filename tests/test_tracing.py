@@ -240,14 +240,18 @@ def test_flight_recorder_stage_accounting():
     assert r["stages_ms"]["decode"] == 1.0   # folded-forward edge
     assert r["stages_ms"]["encode"] == 1.5   # attached-backward edge
     assert r["stages_ms"]["pack"] == 2.0
-    assert r["total_ms"] == pytest.approx(13.0)
+    # the lease lies inside pack: an overlay, recorded and not summed
+    assert r["stages_ms"]["lease"] == 0.5
+    assert r["total_ms"] == pytest.approx(12.5)
     # finish() pushed every nonzero stage through the observer, and the
     # encode edge reported directly.
     assert ("pack", 0.002) in seen and ("encode", 0.0015) in seen
 
-    pcts = rec.stage_percentiles()
+    snap = rec.snapshot()
+    pcts = snap["stages"]
     assert pcts["h2d"] == {"p50_ms": 3.0, "p99_ms": 3.0}
     assert pcts["decode"]["p50_ms"] == 1.0
+    assert snap["total"] == {"p50_ms": 12.5, "p99_ms": 12.5}
 
 
 def test_flight_recorder_ring_wrap_and_staleness():
@@ -303,3 +307,269 @@ def test_flight_recorder_global_slot():
     finally:
         flightrec.uninstall()
     assert flightrec.get() is None
+
+
+# ---------------------------------------------------------------------
+# The whole tick-loop cycle as stages, the overlays beside them, and the
+# one helper that times a stage (utils/flightrec.py)
+# ---------------------------------------------------------------------
+def test_stage_sets_partition_the_names():
+    from benchmarks.harness.recorder import TotalsRecorder
+    from gubernator_tpu.utils import flightrec
+
+    assert len(set(flightrec.STAGES)) == len(flightrec.STAGES)
+    assert set(flightrec.CYCLE) | set(flightrec.OVERLAYS) < set(flightrec.STAGES)
+    assert not set(flightrec.CYCLE) & set(flightrec.OVERLAYS)
+    # the benchmark's recorder seeds its totals from STAGES: every name
+    # is a key, so a reader of a new stage finds it
+    assert set(TotalsRecorder().totals()["stage_s"]) == set(flightrec.STAGES)
+
+
+@pytest.mark.parametrize(
+    "name", ["lease", "queue", "finish_lock", "cpu", "compile", "gc", "wait"])
+def test_overlays_and_wait_are_recorded_and_not_summed(name):
+    """An overlay lies inside another stage or on another thread, and
+    ``wait`` is the thread idle between windows: each is in ``recent()``
+    and the histogram's feed, none in ``total_ms`` or the slow check."""
+    from gubernator_tpu.utils import flightrec
+
+    assert name in flightrec.OVERLAYS or name == "wait"
+    rec = flightrec.FlightRecorder(windows=4, slow_threshold_s=0.005)
+    seen = []
+    rec.observer = lambda stage, s: seen.append(stage)
+    wid = rec.begin(width=1, depth=0)
+    rec.note(wid, "gather", 0.001)
+    rec.note(wid, name, 0.050)
+    rec.finish(wid)
+    r = rec.recent()[-1]
+    assert r["stages_ms"][name] == 50.0
+    assert r["total_ms"] == pytest.approx(1.0)
+    assert name in seen
+    assert rec.slow_total == 0 and rec.drain_slow() == []
+    assert rec.snapshot()["total"]["p99_ms"] == pytest.approx(1.0)
+    # the same seconds in a stage of the window's own work are slow
+    wid = rec.begin(width=1, depth=0)
+    rec.note(wid, "handle", 0.050)
+    rec.finish(wid)
+    assert [d["total_ms"] for d in rec.drain_slow()] == [50.0]
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts what is built."""
+
+    built: list = []
+
+    def __init__(self, name):
+        type(self).built.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    import jax.profiler
+
+    monkeypatch.setattr(_CountingAnnotation, "built", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    return _CountingAnnotation.built
+
+
+def test_stage_helper_notes_and_annotates_only_while_installed(annotations):
+    from gubernator_tpu.utils import flightrec
+
+    assert flightrec.stage("pack") is flightrec.OFF
+    with flightrec.stage("pack") as off:
+        pass
+    assert off.seconds == 0.0 and annotations == []
+
+    rec = flightrec.FlightRecorder(windows=4)
+    flightrec.install(rec)
+    try:
+        wid = rec.begin(width=1, depth=0)
+        with flightrec.stage("pack") as st:       # the window in dispatch
+            with flightrec.stage("lease"):
+                pass
+        waited = flightrec.stage("wait", into=None).start()
+        waited.stop()                             # noted by the caller
+        rec.end_dispatch(wid)
+        flightrec.stage("handoff", into=wid).start().stop()
+        with flightrec.stage("encode"):           # an edge: fr.edge()
+            pass
+        rec.finish(wid)
+    finally:
+        flightrec.uninstall()
+    assert annotations == ["guber.pack", "guber.lease", "guber.wait",
+                           "guber.handoff", "guber.encode"]
+    ms = rec.recent()[-1]["stages_ms"]
+    assert st.seconds > 0
+    assert ms["pack"] == pytest.approx(st.seconds * 1e3, abs=1e-3)
+    assert 0 < ms["lease"] <= ms["pack"]
+    assert ms["handoff"] > 0 and ms["encode"] > 0
+    assert ms["wait"] == 0.0 and waited.seconds > 0 and waited.t1 > 0
+    assert flightrec.stage("pack") is flightrec.OFF
+
+
+def test_install_sets_and_uninstall_drops_both_listeners():
+    """The collector's and jax.monitoring's listeners live exactly while
+    a recorder is installed; a forced collection and a first-met shape
+    each land in the window in dispatch and in the counters."""
+    import gc
+
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    from gubernator_tpu.utils import flightrec
+
+    def listeners():
+        return (flightrec._on_gc in gc.callbacks,
+                flightrec._on_compile
+                in monitoring.get_event_duration_listeners())
+
+    assert listeners() == (False, False)
+    rec = flightrec.FlightRecorder(windows=4)
+    flightrec.install(rec)
+    try:
+        assert listeners() == (True, True)
+        flightrec.install(rec)                    # once, however often
+        assert gc.callbacks.count(flightrec._on_gc) == 1
+        # before the first window: start-up's, not serving's
+        gc.collect()
+        assert rec.stalls()["gc_collections"] == [0, 0, 0]
+        wid = rec.begin(width=1, depth=0)
+        gc.collect()
+        (jnp.zeros((3, 1237), jnp.float32) + 1).block_until_ready()
+        rec.end_dispatch(wid)
+        gc.collect(0)                             # no window in dispatch:
+        rec.finish(wid)                           # the newest begun
+    finally:
+        flightrec.uninstall()
+    assert listeners() == (False, False)
+    gc.collect()                                  # nobody listens
+    stalls = rec.stalls()
+    assert stalls["gc_collections"][2] == 1 and stalls["gc_collections"][0] >= 1
+    assert stalls["gc_pause_seconds"][2] > 0
+    assert stalls["serving_compiles"] >= 1
+    assert stalls["serving_compile_seconds"] > 0
+    ms = rec.recent()[-1]["stages_ms"]
+    assert ms["gc"] == pytest.approx(
+        sum(stalls["gc_pause_seconds"]) * 1e3, abs=1e-2)
+    assert ms["compile"] == pytest.approx(
+        stalls["serving_compile_seconds"] * 1e3, abs=1e-2)
+
+
+def _drive_windows(engine, n_windows, wrap=None):
+    """``n_windows`` one-call windows through a real TickLoop on a
+    module-scoped cluster engine; ``wrap(loop)`` may wrap its methods
+    before the first call."""
+    from gubernator_tpu.ops.reqcols import ReqColumns
+    from gubernator_tpu.service.tickloop import TickLoop
+
+    loop = TickLoop(engine, batch_wait=0.0)
+    try:
+        if wrap is not None:
+            wrap(loop)
+        for w in range(n_windows):
+            cols = ReqColumns.from_requests([
+                RateLimitRequest(name="stages", unique_key=f"w{w}k{i}",
+                                 hits=1, limit=100, duration=60_000)
+                for i in range(8)])
+            mat, errs = loop.submit_columns(cols).result(timeout=120)
+            assert mat.shape == (5, 8) and not errs
+    finally:
+        loop.close()
+    return loop
+
+
+def test_disjoint_stages_add_up_to_the_flush(cluster):
+    """A window's stages from ``gather`` to ``handoff`` tile ``_flush``
+    on the tick-loop thread: their sum is its wall time (the first
+    window, which may compile, left out); ``wait`` and the overlays
+    ride beside them."""
+    import time
+
+    from gubernator_tpu.utils import flightrec
+
+    flush_s = []
+
+    def wrap(loop):
+        inner = loop._flush
+
+        def timed(batch, wait=flightrec.OFF):
+            t0 = time.perf_counter()
+            inner(batch, wait)
+            flush_s.append(time.perf_counter() - t0)
+
+        loop._flush = timed
+
+    rec = flightrec.FlightRecorder(windows=16)
+    flightrec.install(rec)
+    try:
+        _drive_windows(cluster.daemons[0].instance.engine, 6, wrap)
+    finally:
+        flightrec.uninstall()
+    recs = rec.recent()
+    assert len(recs) == len(flush_s) == 6
+    flush = flightrec.CYCLE[1:]
+    assert flush[0] == "gather" and flush[-1] == "handoff"
+    staged = sum(r["stages_ms"][s] for r in recs[1:] for s in flush) / 1e3
+    wall = sum(flush_s[1:])
+    assert staged <= wall
+    assert wall - staged <= 0.15 * wall + 5e-4, (staged, wall)
+    for r in recs:
+        ms = r["stages_ms"]
+        for s in ("gather", "submit_lock", "pack", "h2d", "handle",
+                  "handoff", "lease", "cpu", "queue", "tick", "resolve"):
+            assert ms[s] > 0, (s, ms)
+        assert ms["lease"] <= ms["pack"]
+        assert ms["finish_lock"] > 0 and ms["finish_lock"] <= ms["tick"]
+        # what the thread executed is no more than the wall it spanned
+        assert ms["cpu"] <= sum(ms[s] for s in flush) * 1.05 + 0.05
+    # every window after the first waited for its call
+    assert all(r["stages_ms"]["wait"] > 0 for r in recs[1:])
+
+
+def test_without_a_recorder_no_annotation_and_no_stamp(cluster, annotations):
+    import gc
+
+    from jax._src import monitoring
+
+    from gubernator_tpu.utils import flightrec
+
+    stamps = []
+
+    def wrap(loop):
+        inner = loop._flush
+
+        def spy(batch, wait=flightrec.OFF):
+            stamps.extend(it.t_enq for it in batch)
+            assert wait is flightrec.OFF
+            inner(batch, wait)
+
+        loop._flush = spy
+
+    assert flightrec.get() is None
+    _drive_windows(cluster.daemons[0].instance.engine, 2, wrap)
+    assert stamps == [0.0, 0.0]
+    assert annotations == []
+    assert flightrec._on_gc not in gc.callbacks
+    assert (flightrec._on_compile
+            not in monitoring.get_event_duration_listeners())
+    # and with one installed the stamp is taken and the ranges are built
+    rec = flightrec.FlightRecorder(windows=4)
+    flightrec.install(rec)
+    try:
+        del stamps[:]
+        _drive_windows(cluster.daemons[0].instance.engine, 1)
+    finally:
+        flightrec.uninstall()
+    # one range a stage of the dispatch thread's cycle; the resolver's
+    # three stages are seconds only, so the old guber.tick is gone
+    assert set(annotations) - {"guber.gc"} == {
+        "guber." + s for s in flightrec.CYCLE + ("lease",)
+        if s not in ("route", "ssd")}
+    ms = rec.recent()[-1]["stages_ms"]
+    assert all(ms[s] > 0 for s in flightrec.RESOLVER)
