@@ -3136,7 +3136,7 @@ class TickEngine:
                     [keys[int(j)] for j in rem], now
                 )
             if fr is not None:
-                fr.note(fr.active(), "pack", -read.seconds)
+                read.out_of("pack")
             self.metric_ssd_lookups += 1
             self.metric_ssd_miss_ticks += 1
             if len(spos):

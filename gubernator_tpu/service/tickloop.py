@@ -320,6 +320,8 @@ class TickLoop:
                 self._resolve_q.put(None)
                 return
             if batch:
+                # the recorder's thread clocks, in no stage of the window
+                flightrec.read_clocks()
                 wait.stop()
                 self._flush(batch, wait)
                 wait = flightrec.stage("wait", into=None).start()
@@ -468,6 +470,7 @@ class TickLoop:
                     break
                 items.append(nxt)
             fr = flightrec.get()
+            flightrec.register_thread("resolver")
             # All drained windows share this one D2H wait; each reports
             # it as its tick time (documented in flightrec).
             with flightrec.stage("tick", into=None) as drain:
@@ -486,6 +489,7 @@ class TickLoop:
                 for _, _, wid in items:
                     if wid is not None:
                         fr.note(wid, "tick", drain.seconds)
+                        fr.note(wid, "tick_cpu", drain.cpu)
             for subs, dispatch_s, wid in items:
                 for kind, sb, waiters, n_reqs in subs:
                     # Guarded: an exception escaping this loop would kill

@@ -220,7 +220,9 @@ async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
             return None, msg
         arena, metrics = instance.ingest_arena, instance.metrics
         # Flight-recorder transport edges: per-batch decode/encode CPU
-        # (folded into window records — see utils/flightrec.py).
+        # (folded into window records — see utils/flightrec.py), and
+        # this thread's whole CPU, read by tick-loop once a window.
+        flightrec.register_thread("edge")
         with flightrec.stage("decode"):
             try:
                 parsed = fastwire.parse_req(raw, arena)

@@ -79,6 +79,25 @@ would count their seconds twice:
   serving (``jax.monitoring``'s duration events, on whichever thread).
 - ``gc``: seconds of garbage collections (``gc.callbacks``), any
   generation, which stop every thread of the process.
+- ``<stage>_cpu`` (``_CPU``: ``gather``, ``pack``, ``ssd``, ``h2d``,
+  ``handle`` on ``tick-loop``, ``tick`` on the resolver):
+  ``time.thread_time()`` across the stage, read inside its wall clock,
+  noted where its wall goes.  A stage's wall less its CPU is its thread
+  off the CPU inside it; in a stage that makes no blocking call
+  (``gather``, ``handle``) that is the wait for the GIL.  On the
+  sharded engine ``pack_cpu`` is the CPU of ``pack``'s whole range,
+  which holds the native pass whose ``route`` share of the wall is
+  noted apart: compare it with ``pack`` + ``route``.
+- ``CLOCKS``, noted by ``read_clocks`` as what each moved since its
+  previous call, so their totals over a run span the same interval:
+  ``tickloop_thread_cpu``, ``edge_thread_cpu``, ``resolver_cpu`` (each
+  registered thread's whole CPU: ``register_thread``, read with
+  ``time.clock_gettime`` on its ``pthread_getcpuclockid``) and
+  ``process_cpu`` (``time.process_time()``; less the three, the
+  runtime's native threads).  ``tick-loop`` reads them at the end of
+  its ``wait`` for each window, so that their cost falls in no stage
+  of the window's work.  They are clocks, not latencies: the observer
+  does not see them.
 
 ``compile`` and ``gc`` go into the window in dispatch or else the newest
 begun; their listeners are set by ``install()`` and dropped by
@@ -96,6 +115,7 @@ drains them (``drain_slow()``), dumps each record, and bumps
 from __future__ import annotations
 
 import gc
+import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
@@ -109,11 +129,24 @@ CYCLE = (
     "wait", "gather", "submit_lock", "route", "pack", "ssd", "h2d",
     "handle", "handoff",
 )
-OVERLAYS = ("lease", "queue", "finish_lock", "cpu", "compile", "gc")
+# The stages that note their thread's CPU beside their wall, as the
+# overlay "<stage>_cpu".
+_CPU = {s: s + "_cpu" for s in (
+    "gather", "pack", "ssd", "h2d", "handle", "tick")}
+# The Python threads whose CPU clocks ``read_clocks`` reads, by role,
+# and the overlay each delta is noted as.
+THREADS = {"tickloop": "tickloop_thread_cpu", "edge": "edge_thread_cpu",
+           "resolver": "resolver_cpu"}
+# Noted by ``read_clocks`` (the deltas since its previous call): clocks,
+# not latencies, so they bypass the observer.
+CLOCKS = tuple(THREADS.values()) + ("process_cpu",)
+OVERLAYS = ("lease", "queue", "finish_lock", "cpu", "compile", "gc") \
+    + tuple(_CPU.values()) + CLOCKS
 RESOLVER = ("tick", "resolve", "finish_lock")   # seconds, no range
 STAGES = ("decode",) + CYCLE + ("tick", "resolve", "encode") + OVERLAYS
 _IDX = {s: i for i, s in enumerate(STAGES)}
 _RANGE = {s: "guber." + s for s in STAGES if s not in RESOLVER}
+_OBSERVED = [(s, i) for s, i in _IDX.items() if s not in CLOCKS]
 _DECODE = _IDX["decode"]
 _ENCODE = _IDX["encode"]
 # 1.0 for the stages a window's total (and the slow check) adds up.
@@ -149,6 +182,10 @@ class FlightRecorder:
         self._seq = 0
         self._active: Optional[int] = None
         self._pending_decode = 0.0
+        # role -> [CPU clock id, the clock at the last read]; see
+        # register_thread().
+        self._threads: Dict[str, list] = {}
+        self._process_cpu: Optional[float] = None
         self.slow_total = 0
         self._slow: deque = deque(maxlen=32)
         # Stalls met while serving (after the first window was begun),
@@ -177,6 +214,37 @@ class FlightRecorder:
             self._active = wid
         return wid
 
+    def register_thread(self, role: str) -> None:
+        """Name the calling thread as ``role`` (a key of ``THREADS``) so
+        that ``read_clocks`` reads its CPU clock.  The first thread to
+        register a role keeps it until its clock can no longer be read
+        (the thread ended): one serving process, one ``TickLoop``."""
+        if role not in self._threads:
+            self._threads[role] = [
+                time.pthread_getcpuclockid(threading.get_ident()), None]
+
+    @hot_path
+    def read_clocks(self) -> None:
+        """Note, into the newest window begun, what each of ``CLOCKS``
+        moved since the previous call: the registered threads' CPU and
+        the process's.  Called by the thread that begins windows, which
+        is ``tickloop``, before it begins the next."""
+        self.register_thread("tickloop")
+        wid = self._seq - 1
+        for role, t in list(self._threads.items()):
+            try:
+                cpu = time.clock_gettime(t[0])
+            except OSError:               # the thread is gone
+                self._threads.pop(role, None)
+                continue
+            if t[1] is not None and cpu >= t[1]:
+                self.note(wid, THREADS[role], cpu - t[1])
+            t[1] = cpu
+        process = time.process_time()
+        if self._process_cpu is not None:
+            self.note(wid, "process_cpu", process - self._process_cpu)
+        self._process_cpu = process
+
     @hot_path
     def note(self, wid: Optional[int], stage: str, seconds: float) -> None:
         """Accumulate ``seconds`` into one stage cell of window ``wid``."""
@@ -194,7 +262,7 @@ class FlightRecorder:
         obs = self.observer
         if obs is not None:
             row = self._stage_s[slot]
-            for stage, i in _IDX.items():
+            for stage, i in _OBSERVED:
                 if row[i] > 0.0:
                     obs(stage, row[i])
         thresh = self.slow_threshold_s
@@ -355,20 +423,25 @@ class _Stage:
     before its window is begun, or belongs to several).  ``decode`` and
     ``encode`` go through ``edge()``."""
 
-    __slots__ = ("_fr", "_name", "_into", "_range", "_t0", "t1", "seconds")
+    __slots__ = ("_fr", "_name", "_into", "_range", "_t0", "t1", "seconds",
+                 "_cpu", "_c0", "cpu")
 
     def __init__(self, fr: FlightRecorder, name: str, into):
         self._fr = fr
         self._name = name
         self._into = into
-        self.t1 = self.seconds = 0.0
+        self._cpu = _CPU.get(name)
+        self.t1 = self.seconds = self.cpu = 0.0
 
     @hot_path
     def start(self) -> "_Stage":
-        # The clock is read first here and last in stop(): the range's
-        # own cost falls inside the stage, not between two stages, so
-        # consecutive stages tile the thread's time.
+        # The wall clock is read first here and last in stop(), the
+        # thread's CPU clock inside it: the range's own cost falls inside
+        # the stage, not between two stages, so consecutive stages tile
+        # the thread's time, and a stage's CPU is never more than its wall.
         self._t0 = time.perf_counter()
+        if self._cpu is not None:
+            self._c0 = time.thread_time()
         name = _RANGE.get(self._name)
         self._range = rng = None if name is None else _annotation(name)
         if rng is not None:
@@ -379,15 +452,30 @@ class _Stage:
     def stop(self) -> None:
         if self._range is not None:
             self._range.__exit__(None, None, None)
+        cname = self._cpu
+        if cname is not None:
+            self.cpu = time.thread_time() - self._c0
         self.t1 = t1 = time.perf_counter()
         self.seconds = dt = t1 - self._t0
         fr, name, into = self._fr, self._name, self._into
         if name == "decode" or name == "encode":
             fr.edge(name, dt)
-        elif into is ACTIVE:
-            fr.note(fr.active(), name, dt)
-        elif into is not None:
-            fr.note(into, name, dt)
+            return
+        wid = fr.active() if into is ACTIVE else into
+        if wid is not None:
+            fr.note(wid, name, dt)
+            if cname is not None:
+                fr.note(wid, cname, self.cpu)
+
+    @hot_path
+    def out_of(self, outer: str) -> None:
+        """This stage, ended, lay inside the range of ``outer`` in the
+        window in dispatch: take its wall and its CPU off ``outer``'s
+        (``ssd`` out of ``pack``)."""
+        fr = self._fr
+        wid = fr.active()
+        fr.note(wid, outer, -self.seconds)
+        fr.note(wid, _CPU[outer], -self.cpu)
 
     __enter__ = start
 
@@ -400,7 +488,7 @@ class _NoStage:
     """What ``stage()`` hands out with no recorder installed."""
 
     __slots__ = ()
-    t1 = seconds = 0.0
+    t1 = seconds = cpu = 0.0
 
     def start(self) -> "_NoStage":
         return self
@@ -427,6 +515,22 @@ def stage(name: str, into=ACTIVE):
     if fr is None:
         return OFF
     return _Stage(fr, name, into)
+
+
+def register_thread(role: str) -> None:
+    """Name the calling thread ``role`` to the installed recorder (see
+    :meth:`FlightRecorder.register_thread`); with none, one check."""
+    fr = _recorder
+    if fr is not None:
+        fr.register_thread(role)
+
+
+def read_clocks() -> None:
+    """The installed recorder's :meth:`FlightRecorder.read_clocks`; with
+    none, one check."""
+    fr = _recorder
+    if fr is not None:
+        fr.read_clocks()
 
 
 # A collection stops every thread, so one start/stop pair is in flight

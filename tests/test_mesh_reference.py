@@ -235,6 +235,9 @@ def test_route_stage_and_dispatch_counters(mesh):
     stages = [w["stages_ms"] for w in rec.recent()]
     assert len(stages) == 2
     assert all(s["route"] > 0 and s["pack"] > 0 for s in stages)
+    # pack's CPU is its whole range's, the route pass's share included
+    for s in stages:
+        assert 0 < s["pack_cpu"] <= s["pack"] + s["route"] + 1e-3, s
     assert "route" in flightrec.STAGES
     if mesh._window_pass is not None:
         assert (mesh.metric_native_pack_windows - before[0]

@@ -326,7 +326,8 @@ def test_stage_sets_partition_the_names():
 
 
 @pytest.mark.parametrize(
-    "name", ["lease", "queue", "finish_lock", "cpu", "compile", "gc", "wait"])
+    "name", ["lease", "queue", "finish_lock", "cpu", "compile", "gc", "wait",
+             "gather_cpu", "pack_cpu", "tick_cpu"])
 def test_overlays_and_wait_are_recorded_and_not_summed(name):
     """An overlay lies inside another stage or on another thread, and
     ``wait`` is the thread idle between windows: each is in ``recent()``
@@ -461,6 +462,173 @@ def test_install_sets_and_uninstall_drops_both_listeners():
         stalls["serving_compile_seconds"] * 1e3, abs=1e-2)
 
 
+# ---------------------------------------------------------------------
+# CPU seconds by stage and by thread (PR 39)
+# ---------------------------------------------------------------------
+def _burn(seconds):
+    """Run on the CPU until this thread has executed ``seconds``."""
+    import time
+
+    c0 = time.thread_time()
+    while time.thread_time() - c0 < seconds:
+        pass
+
+
+@pytest.mark.parametrize("busy", [True, False])
+def test_a_stage_notes_its_threads_cpu_beside_its_wall(busy):
+    """Round a busy loop ``<stage>_cpu`` is about the stage's wall; round
+    a sleep about nothing; never more than the wall."""
+    import time
+
+    from gubernator_tpu.utils import flightrec
+
+    rec = flightrec.FlightRecorder(windows=4)
+    flightrec.install(rec)
+    try:
+        wid = rec.begin(width=1, depth=0)
+        for name in ("gather", "handle"):
+            with flightrec.stage(name):
+                _burn(0.03) if busy else time.sleep(0.03)
+        rec.finish(wid)
+    finally:
+        flightrec.uninstall()
+    ms = rec.recent()[-1]["stages_ms"]
+    for name in ("gather", "handle"):
+        assert ms[name + "_cpu"] <= ms[name]
+        if busy:
+            assert ms[name + "_cpu"] >= 30.0
+        else:
+            assert ms[name + "_cpu"] < 5.0 and ms[name] >= 30.0
+
+
+def test_the_cpu_of_a_stage_taken_out_goes_with_its_wall():
+    """``ssd`` out of ``pack`` takes its CPU off ``pack_cpu``; the
+    mesh's ``route``, a share of ``pack``'s wall by the native pass's
+    clock, leaves the range's CPU in ``pack_cpu``."""
+    from gubernator_tpu.utils import flightrec
+
+    rec = flightrec.FlightRecorder(windows=4)
+    flightrec.install(rec)
+    try:
+        one_chip = rec.begin(width=1, depth=0)
+        with flightrec.stage("pack") as pk1:
+            with flightrec.stage("ssd") as read:
+                _burn(0.01)
+            read.out_of("pack")
+            _burn(0.02)
+        rec.finish(one_chip)
+        mesh = rec.begin(width=1, depth=0)
+        with flightrec.stage("pack") as pk2:
+            _burn(0.02)
+        rec.note(mesh, "route", pk2.seconds / 2)    # as MeshTickEngine
+        rec.note(mesh, "pack", -pk2.seconds / 2)
+        rec.finish(mesh)
+    finally:
+        flightrec.uninstall()
+    one_chip, mesh = (r["stages_ms"] for r in rec.recent())
+    for name in ("pack", "ssd"):
+        assert 0 < one_chip[name + "_cpu"] <= one_chip[name] + 1e-3
+    assert one_chip["pack_cpu"] == pytest.approx(
+        (pk1.cpu - read.cpu) * 1e3, abs=1e-3)
+    assert one_chip["pack"] == pytest.approx(
+        (pk1.seconds - read.seconds) * 1e3, abs=1e-3)
+    assert mesh["pack_cpu"] == pytest.approx(pk2.cpu * 1e3, abs=1e-3)
+    assert 0 < mesh["pack_cpu"] <= mesh["pack"] + mesh["route"] + 1e-3
+    assert "route_cpu" not in mesh
+
+
+def test_read_clocks_reads_the_registered_threads_cpu():
+    """A registered thread that burns 50 ms of CPU between two readings
+    shows at least 40 ms under its overlay, an idle one under 5; the
+    process's CPU is at least the three threads'."""
+    import threading
+
+    from gubernator_tpu.utils import flightrec
+
+    rec = flightrec.FlightRecorder(windows=4)
+    ready = threading.Barrier(3, timeout=60)
+    go, done, leave = threading.Event(), threading.Event(), threading.Event()
+
+    def edge():
+        rec.register_thread("edge")
+        ready.wait()
+        assert go.wait(60)
+        _burn(0.05)
+        done.set()
+        assert leave.wait(60)     # alive, so its clock is read
+
+    def resolver():
+        rec.register_thread("resolver")
+        ready.wait()
+        assert leave.wait(60)
+
+    threads = [threading.Thread(target=f) for f in (edge, resolver)]
+    for t in threads:
+        t.start()
+    try:
+        ready.wait()
+        wid = rec.begin(width=1, depth=0)
+        rec.read_clocks()                  # the clocks' first reading
+        go.set()
+        assert done.wait(60)
+        rec.read_clocks()                  # into the newest window begun
+        rec.finish(wid)
+    finally:
+        leave.set()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+    ms = rec.recent()[-1]["stages_ms"]
+    assert ms["edge_thread_cpu"] >= 40.0
+    assert ms["resolver_cpu"] < 5.0
+    # every thread is in the process's CPU (the clocks are read one after
+    # another, so a window's two intervals differ by microseconds)
+    python = sum(ms[k] for k in flightrec.THREADS.values())
+    assert ms["process_cpu"] >= python - 0.5
+    assert set(rec._threads) == {"tickloop", "edge", "resolver"}
+    # clocks bypass the observer and a window's total
+    assert rec.recent()[-1]["total_ms"] == 0.0
+
+
+def test_clocks_read_before_the_first_window_note_nothing():
+    """Readings before any window was begun set the clocks' baseline
+    and are noted nowhere; the next reading goes to the window begun."""
+    from gubernator_tpu.utils import flightrec
+
+    rec = flightrec.FlightRecorder(windows=4)
+    rec.read_clocks()
+    _burn(0.01)
+    rec.read_clocks()
+    assert not rec._stage_s.any()
+    wid = rec.begin(width=1, depth=0)
+    _burn(0.01)
+    rec.read_clocks()
+    rec.finish(wid)
+    ms = rec.recent()[-1]["stages_ms"]
+    assert ms["tickloop_thread_cpu"] >= 9.0
+    assert ms["process_cpu"] >= ms["tickloop_thread_cpu"] - 0.5
+
+
+def test_without_a_recorder_no_clock_is_read(monkeypatch):
+    """Off, a stage, a thread's registration and the clocks' reading
+    read no clock: one check each."""
+    from gubernator_tpu.utils import flightrec
+
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read with no recorder")
+
+    assert flightrec.get() is None
+    monkeypatch.setattr(flightrec, "time", NoClock())
+    for name in ("gather", "decode", "encode", "tick", "pack"):
+        with flightrec.stage(name) as st:
+            pass
+        assert st is flightrec.OFF and st.cpu == 0.0
+    for role in flightrec.THREADS:
+        flightrec.register_thread(role)
+    flightrec.read_clocks()
+
+
 def _drive_windows(engine, n_windows, wrap=None):
     """``n_windows`` one-call windows through a real TickLoop on a
     module-scoped cluster engine; ``wrap(loop)`` may wrap its methods
@@ -527,7 +695,18 @@ def test_disjoint_stages_add_up_to_the_flush(cluster):
         assert ms["lease"] <= ms["pack"]
         assert ms["finish_lock"] > 0 and ms["finish_lock"] <= ms["tick"]
         # what the thread executed is no more than the wall it spanned
-        assert ms["cpu"] <= sum(ms[s] for s in flush) * 1.05 + 0.05
+        # (the first window's first calls run between its stages)
+        if r is not recs[0]:
+            assert ms["cpu"] <= sum(ms[s] for s in flush) * 1.05 + 0.05
+        for s in ("gather", "pack", "h2d", "handle", "tick"):
+            assert 0 < ms[s + "_cpu"] <= ms[s] + 1e-3, (s, ms)
+        python = sum(ms[k] for k in flightrec.THREADS.values())
+        assert ms["process_cpu"] >= python - 0.5
+    # tick-loop reads the clocks at the end of each window's wait, into
+    # the window before it: every window but the last has its reading;
+    # the resolver registered at its first drain, and is read from then
+    assert sum(r["stages_ms"]["resolver_cpu"] for r in recs) > 0
+    assert all(r["stages_ms"]["tickloop_thread_cpu"] > 0 for r in recs[:-1])
     # every window after the first waited for its call
     assert all(r["stages_ms"]["wait"] > 0 for r in recs[1:])
 
