@@ -43,7 +43,6 @@ from gubernator_tpu.ops.buckets import (
     bucket_transition,
     gather_state,
     np_logical,
-    to_logical,
     scatter_state,
 )
 from gubernator_tpu.ops import rowtable
@@ -2037,14 +2036,26 @@ class SlotMap:
 
 @functools.lru_cache(maxsize=None)
 def _jitted_dead_scan():
-    """Device-side TTL sweep: ``~in_use | expired`` packed to a bitmask so
-    the per-reclaim D2H is capacity/8 bytes, not the 9 bytes/slot the old
-    host sweep copied (90 MB per sweep at 10M slots)."""
+    """Device-side TTL sweep of the column layout: ``~in_use | expired``
+    packed to a bitmask so the per-reclaim D2H is capacity/8 bytes, not
+    the 9 bytes/slot the old host sweep copied (90 MB per sweep at 10M
+    slots).  Packed by stride, not by neighbour: bit j of byte i is slot
+    ``j * m + i`` (``m = ceil(capacity / 8)``; :func:`unpack_dead_bits`).
+    XLA:TPU compiles ``packbits``' reduction over a minor axis of eight
+    into code that grows with the table (311 s and 165 MB of code for a
+    31.25M-slot shard, on a described v5e); over the major axis it is
+    one loop.  ``expire_at`` is compared as its int32 pair, with no
+    64-bit column."""
 
     def scan(in_use, exp_lo, exp_hi, now):
-        exp = to_logical((exp_lo, exp_hi), "expire_at")
-        dead = (~in_use) | (exp < now)
-        return jnp.packbits(dead, bitorder="little")
+        now_hi = (now >> 32).astype(jnp.int32)
+        now_lo = (now & 0xFFFFFFFF).astype(jnp.uint32)
+        lo = lax.bitcast_convert_type(exp_lo, jnp.uint32)
+        dead = (~in_use) | (exp_hi < now_hi) | ((exp_hi == now_hi) & (lo < now_lo))
+        m = -(-dead.shape[0] // 8)
+        dead = jnp.pad(dead, (0, 8 * m - dead.shape[0])).reshape(8, m)
+        shift = jnp.arange(8, dtype=jnp.uint8)[:, None]
+        return jnp.sum(dead.astype(jnp.uint8) << shift, axis=0, dtype=jnp.uint8)
 
     return jax.jit(scan)
 
@@ -2060,10 +2071,11 @@ def device_dead_bits(in_use, expire_field, now: int):
 
 
 def unpack_dead_bits(bits, capacity: int) -> np.ndarray:
+    """The host mask of :func:`device_dead_bits`' stride-packed bits."""
     return np.unpackbits(
         # guber: allow-G001(the deliberate reclaim D2H - materializing the packed dead bitmask is this helper's whole job; callers pay it off-lock, at most once per reclaim round, never per tick)
-        np.asarray(bits), count=capacity, bitorder="little"
-    ).astype(bool)
+        np.asarray(bits)[None, :], axis=0, count=8, bitorder="little"
+    ).reshape(-1)[:capacity].astype(bool)
 
 
 def device_dead_mask(in_use, expire_field, now: int, capacity: int) -> np.ndarray:
@@ -2141,12 +2153,16 @@ def make_slot_map(capacity: int):
 
 
 def describe_engine(device, devices: int, layout: str, fused: bool,
-                    warmup_seconds: float, native_pack: bool = False) -> dict:
+                    warmup_seconds: float, native_pack: bool = False,
+                    load_seconds: float = 0.0, load_rows: int = 0) -> dict:
     """What an engine resolved to at construction — the daemon logs it
     once at start, so the backend that answers is never a guess (layout,
     fused and warm-up follow jax's default backend; see
     make_layout_choice, tick32._resolve_fused, _warmup; native_pack:
-    the host pack is the native window pass, TickEngine._build_cols)."""
+    the host pack is the native window pass, TickEngine._build_cols) —
+    and the seconds its fills took and the rows they landed
+    (``load_columns``; the start-up Loader's fill runs before the daemon
+    serves)."""
     return {
         "platform": device.platform,
         "device_kind": device.device_kind,
@@ -2155,6 +2171,8 @@ def describe_engine(device, devices: int, layout: str, fused: bool,
         "fused": fused,
         "native_pack": native_pack,
         "warmup_seconds": round(warmup_seconds, 3),
+        "load_seconds": round(load_seconds, 3),
+        "load_rows": load_rows,
         "compile_cache_dir": jax.config.jax_compilation_cache_dir,
     }
 
@@ -2533,6 +2551,9 @@ class TickEngine:
         self.metric_lease_dispatches = 0
         self.metric_lease_windows = 0
         self.metric_lease_ops = 0
+        # the fills' seconds and the rows they landed (load_columns)
+        self.load_seconds = 0.0
+        self.load_rows = 0
         t0 = time.perf_counter()
         self._warmup()
         self.warmup_seconds = time.perf_counter() - t0
@@ -2545,6 +2566,7 @@ class TickEngine:
             self.layout == "row" and _resolve_fused(None),
             self.warmup_seconds,
             native_pack=self._native_pack,
+            load_seconds=self.load_seconds, load_rows=self.load_rows,
         )
         d["leaky_rows"] = self.metric_leaky_rows
         return d
@@ -2638,7 +2660,14 @@ class TickEngine:
         return device_dead_bits(self.state.in_use, self.state.expire_at, now)
 
     def _dead_mask(self, now: int) -> np.ndarray:
-        return unpack_dead_bits(self._dead_bits(now), self.capacity)
+        return self._unpack_dead(self._dead_bits(now))
+
+    def _unpack_dead(self, bits) -> np.ndarray:
+        """The host mask of :meth:`_dead_bits` (each layout's scan packs
+        its own way)."""
+        if self.layout == "row":
+            return rowtable.unpack_row_dead_bits(bits, self.capacity)
+        return unpack_dead_bits(bits, self.capacity)
 
     # ------------------------------------------------------------------
     # Host-side request preparation
@@ -2824,7 +2853,7 @@ class TickEngine:
             # mid-wait must not be freed on the strength of the old scan.
             snap = self._tick_count
             bits = self._dead_bits(self._last_now)
-        dead = unpack_dead_bits(bits, self.capacity)
+        dead = self._unpack_dead(bits)
         with self._lock:
             mapped = self.slots.mapped_mask()
             if self._pending:
@@ -3772,7 +3801,17 @@ class TickEngine:
         native blob-assign maps every key; duplicate keys dedup to their
         LAST occurrence (install order — the row layout's one-DMA-per-slot
         contract); the data lands in RESTORE_CHUNK-wide jitted scatters.
+        The fill's seconds and the rows it landed add up in
+        ``load_seconds`` / ``load_rows`` (``describe()``).
         """
+        t0 = time.perf_counter()
+        try:
+            self.load_rows += self._fill(snap, now)
+        finally:
+            self.load_seconds += time.perf_counter() - t0
+
+    def _fill(self, snap: dict, now: Optional[int]) -> int:
+        """:meth:`load_columns`'s work; the rows it landed."""
         with self._lock:
             now = now if now is not None else timeutil.now_ms()
             self._last_now = max(self._last_now, now)
@@ -3780,7 +3819,7 @@ class TickEngine:
             offsets = np.asarray(snap["key_offsets"], np.int64)
             n = len(offsets) - 1
             if n == 0:
-                return
+                return 0
             cols = {f: np.asarray(snap[f]) for f in SNAP_FIELDS}
             # Pre-zoo snapshots lack the zoo state columns: restore them
             # as zeros — a fresh window/TAT, the safe reading (see
@@ -3803,7 +3842,7 @@ class TickEngine:
                 cols = {f: c[keep] for f, c in cols.items()}
                 n = int(keep.sum())
                 if n == 0:
-                    return
+                    return 0
             shortfall = len(self.slots) + n - self.capacity
             if shortfall > 0:
                 self._reclaim(now, want=shortfall)
@@ -3822,7 +3861,7 @@ class TickEngine:
                 )
             sel = np.flatnonzero(slots >= 0)  # full table: drop the tail
             if len(sel) == 0:
-                return
+                return 0
             # Last-wins dedup by slot (same key → same slot): reverse +
             # first-unique keeps each slot's final occurrence.
             s = slots[sel]
@@ -3863,6 +3902,7 @@ class TickEngine:
                 self._lease_budget = jnp.asarray(lb)
                 self._lease_expire = jnp.asarray(le)
                 self._lease_gen = jnp.asarray(lg)
+            return len(sel)
 
     def load_items(self, items: Sequence[dict], now: Optional[int] = None) -> None:
         """Install snapshot items into the table (the dict-shaped Loader

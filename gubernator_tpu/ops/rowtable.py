@@ -310,10 +310,15 @@ def row_device_dead_bits(state: RowState, now: int):
     return _jitted_row_dead_scan()(state.table, jnp.int64(now))
 
 
-def row_device_dead_mask(state: RowState, now: int, capacity: int) -> np.ndarray:
+def unpack_row_dead_bits(bits, capacity: int) -> np.ndarray:
+    """The host mask of :func:`row_device_dead_bits`' packed bits."""
     # guber: allow-G001(the deliberate reclaim D2H, row-layout twin of unpack_dead_bits - at most once per reclaim round, never per tick)
-    bits = np.asarray(row_device_dead_bits(state, now))
+    bits = np.asarray(bits)
     return np.unpackbits(bits, count=capacity, bitorder="little").astype(bool)
+
+
+def row_device_dead_mask(state: RowState, now: int, capacity: int) -> np.ndarray:
+    return unpack_row_dead_bits(row_device_dead_bits(state, now), capacity)
 
 
 @functools.lru_cache(maxsize=None)

@@ -83,7 +83,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from gubernator_tpu.native import NativeSlotMap, ShardedWindowPass
 from gubernator_tpu.ops import i64pair as p64
 from gubernator_tpu.ops import rowtable
-from gubernator_tpu.ops.buckets import BucketState, slice_field
+from gubernator_tpu.ops.buckets import BucketState
 from gubernator_tpu.ops.engine import (
     SNAP_FIELDS,
     ZOO_SNAP_FIELDS,
@@ -277,6 +277,15 @@ class ShardedOps:
             st, rows = walk_rows(sorted_rows, state_blk, slab)
             return st, lax.psum(stack6(rows), "shard")
 
+        def named(fn, name):
+            """The column layout's programs carry it in their names
+            (``jit_mesh_tick_<program>_columns`` in a trace); the row
+            layout's keep the module names of their cache entries.  (The
+            functions still reach shard_map by their own names: G006.)"""
+            if layout != "row":
+                fn.__name__ = fn.__qualname__ = f"mesh_tick_{name}_columns"
+
+        named(_tick_ragged, "sorted")
         self.tick_ragged = jax.jit(
             shard_map(_tick_ragged, **flat), donate_argnums=(0,))
 
@@ -303,6 +312,7 @@ class ShardedOps:
                 st, rows = walk_rows(tick32_rows, state_blk, slab)
                 return st, lax.psum(stack6(rows), "shard")
 
+        named(_tick32_ragged, "unique")
         self.tick_unique_ragged = jax.jit(
             shard_map(_tick32_ragged, **flat), donate_argnums=(0,))
 
@@ -521,6 +531,9 @@ class MeshTickEngine:
         self.metric_misses = 0
         self.metric_over_limit = 0
         self.metric_unexpired_evictions = 0
+        # the fills' seconds and the rows they landed (load_columns)
+        self.load_seconds = 0.0
+        self.load_rows = 0
         t0 = time.perf_counter()
         self._warmup()
         self.warmup_seconds = time.perf_counter() - t0
@@ -530,6 +543,7 @@ class MeshTickEngine:
             self.mesh.devices.flat[0], self.n_shards, self.layout,
             self.ops._fused32, self.warmup_seconds,
             native_pack=self._window_pass is not None,
+            load_seconds=self.load_seconds, load_rows=self.load_rows,
         )
 
     def _warmup(self) -> None:
@@ -584,21 +598,25 @@ class MeshTickEngine:
         return zlib.crc32(key.encode()) % self.n_shards
 
     def _shard_dead_mask(self, shard: int, now: int) -> np.ndarray:
-        """Device-dead mask for one shard's slice of the table."""
+        """Device-dead mask for one shard's slice of the table, scanned
+        in the shard's own buffers on its own chip.  A slice of the
+        sharded arrays would gather them onto one device first: the
+        whole row table (12.8 GB at 12.5M rows, which a chip holding its
+        1.6 GB shard refuses), or 1.1 GB of ``in_use`` and ``expire_at``
+        columns at 125M slots."""
+
+        def own(arr, start):
+            return next(sh.data for sh in arr.addressable_shards
+                        if (sh.index[0].start or 0) == start)
+
         if self.layout == "row":
-            # The shard's own buffer, scanned on its own chip.  A slice
-            # of the sharded array would gather the whole table onto one
-            # device first (12.8 GB at 12.5M rows, which a chip holding
-            # its 1.6 GB shard refuses).
-            lo = shard * (self.local_capacity + 1)
-            blk = next(
-                sh.data for sh in self.state.table.addressable_shards
-                if (sh.index[0].start or 0) == lo)
+            blk = own(self.state.table, shard * (self.local_capacity + 1))
             return rowtable.row_device_dead_mask(
                 RowState(table=blk), now, self.local_capacity)
-        sl = slice(shard * self.local_capacity, (shard + 1) * self.local_capacity)
+        lo = shard * self.local_capacity
         return device_dead_mask(
-            self.state.in_use[sl], slice_field(self.state.expire_at, sl),
+            own(self.state.in_use, lo),
+            tuple(own(part, lo) for part in self.state.expire_at),
             now, self.local_capacity,
         )
 
@@ -716,22 +734,30 @@ class MeshTickEngine:
         the Loader's fill route through here alike."""
         from gubernator_tpu.native import crc32_batch
 
-        sh = (
-            crc32_batch(blob, offsets) % np.uint32(self.n_shards)
-        ).astype(np.int64)
-        order = np.argsort(sh, kind="stable")
+        shard_ids = crc32_batch(blob, offsets) % np.uint32(self.n_shards)
+        sh = shard_ids.astype(np.int64)
+        # A stable sort of 16-bit ids is a radix sort: at a 100M-key fill
+        # the int64 one is most of the route's seconds.
+        order = np.argsort(
+            shard_ids.astype(np.uint16) if self.n_shards <= 1 << 16 else sh,
+            kind="stable")
         # guber: allow-G001(key offsets are host numpy, never device)
         offs = np.asarray(offsets, np.int64)
         lens = np.diff(offs)
         lo = lens[order]
-        so = offs[:-1][order]
         cum = np.cumsum(lo)
         blob_arr = np.frombuffer(blob, np.uint8)
-        if len(blob_arr):
+        width = int(lens[0]) if len(lens) else 0
+        if width and (lens == width).all():
+            # Keys of one length (a Loader's fill): a gather of rows,
+            # not of bytes.
+            grouped_blob = blob_arr[offs[0]:offs[-1]].reshape(
+                -1, width)[order].tobytes()
+        elif len(blob_arr):
             gather = (
                 np.arange(int(cum[-1]), dtype=np.int64)
                 - np.repeat(cum - lo, lo)
-                + np.repeat(so, lo)
+                + np.repeat(offs[:-1][order], lo)
             )
             grouped_blob = blob_arr[gather].tobytes()
         else:
@@ -1246,7 +1272,16 @@ class MeshTickEngine:
         dedup to their LAST occurrence (the row layout's
         one-DMA-per-slot contract); the data lands through the blocked
         restore in RESTORE_CHUNK-wide chunks.  No Python loop over
-        keys."""
+        keys.  The fill's seconds and the rows it landed add up in
+        ``load_seconds`` / ``load_rows`` (``describe()``)."""
+        t0 = time.perf_counter()
+        try:
+            self.load_rows += self._fill(snap, now)
+        finally:
+            self.load_seconds += time.perf_counter() - t0
+
+    def _fill(self, snap: dict, now: Optional[int]) -> int:
+        """:meth:`load_columns`'s work; the rows it landed."""
         with self._lock:
             now = now if now is not None else timeutil.now_ms()
             self._tick_count += 1  # unblock LRU reclaim (see install_globals)
@@ -1254,7 +1289,7 @@ class MeshTickEngine:
             offsets = np.asarray(snap["key_offsets"], np.int64)
             n = len(offsets) - 1
             if n == 0:
-                return
+                return 0
             # guber: allow-G001(snapshot columns are host numpy, as above)
             cols = {f: np.asarray(snap[f]) for f in SNAP_FIELDS}
             # Pre-zoo snapshots lack the zoo columns: zeros, a fresh
@@ -1270,7 +1305,7 @@ class MeshTickEngine:
                 cols = {f: c[keep] for f, c in cols.items()}
                 n = int(keep.sum())
                 if n == 0:
-                    return
+                    return 0
             sh, order, grouped_blob, g_offsets, starts = (
                 self._group_by_shard(blob, offsets))
             lslots = np.full(n, -1, np.int64)
@@ -1296,15 +1331,20 @@ class MeshTickEngine:
                     ls[full] = self.slots[d].assign_blob(
                         *compact_blob(blob_d, off_d, full))
                 lslots[order[a:z]] = ls
-            sel = np.flatnonzero(lslots >= 0)  # a shard still full: drop
+            # The rows shard by shard in the order they were assigned (a
+            # shard still full: dropped).
+            sel = order[lslots[order] >= 0]
             if len(sel) == 0:
-                return
-            # Last-wins dedup by global slot (same key -> same slot):
-            # reverse + first-unique keeps each slot's final occurrence,
-            # ascending by slot, so each shard's rows are contiguous.
-            g = sh[sel] * self.local_capacity + lslots[sel]
-            g_uniq, ridx = np.unique(g[::-1], return_index=True)
-            sel = sel[len(g) - 1 - ridx]
+                return 0
+            g_uniq = sh[sel] * self.local_capacity + lslots[sel]
+            if not (g_uniq[1:] > g_uniq[:-1]).all():
+                # Last-wins dedup by global slot (same key -> same
+                # slot): reverse + first-unique keeps each slot's final
+                # occurrence, ascending by slot, so each shard's rows are
+                # contiguous.  (A fresh table's slots come out ascending
+                # and unique already: nothing to sort.)
+                g_uniq, ridx = np.unique(g_uniq[::-1], return_index=True)
+                sel = sel[len(sel) - 1 - ridx]
             self._last_access[g_uniq] = self._tick_count
             bounds = np.searchsorted(
                 g_uniq, np.arange(self.n_shards + 1) * self.local_capacity)
@@ -1333,6 +1373,7 @@ class MeshTickEngine:
             # them by it.
             for got in pended:
                 self._pending.difference_update(got.tolist())
+            return len(sel)
 
     def load_items(self, items: Sequence[dict], now: Optional[int] = None) -> None:
         """Install snapshot items into the sharded table (the

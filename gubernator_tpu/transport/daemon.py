@@ -886,6 +886,11 @@ class Daemon:
             # over cache hits + misses: the share of rows on the
             # float64 leaky path
             engine_tel["leaky_rows"] = eng.metric_leaky_rows
+        if hasattr(eng, "load_rows"):
+            # the fills' seconds and the rows they landed (the start-up
+            # Loader's, and any later load_columns)
+            engine_tel["load_seconds"] = round(eng.load_seconds, 3)
+            engine_tel["load_rows"] = eng.load_rows
         staging = getattr(eng, "_staging", None)
         if staging is not None and hasattr(staging, "telemetry"):
             engine_tel["staging_ring"] = staging.telemetry()

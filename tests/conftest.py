@@ -88,7 +88,9 @@ def pytest_configure(config):
 # (CHANGES.md, PR 27: 348 s of wall in this order); a file not listed
 # runs after these, in its usual place.
 LONGEST_FIRST = (
-    "test_chip_compile",    # 283 (two more sequential shapes, PR 36)
+    "test_chip_compile",    # 283 (two more sequential shapes, PR 36);
+                            # +31 s alone for the column layout at
+                            # 31.25M slots a shard (PR 40)
     "test_group_plan",      # 190 (39 before PR 33's one-buffer programs)
     "test_fusedtick",       # 143
     "test_mesh_engine",     # 135

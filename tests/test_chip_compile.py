@@ -222,6 +222,56 @@ def test_sharded_ragged_ticks_on_four_chips(mosaic, four_chips):
         assert per_dev < 2 * (LOCAL_CAP + 1) * rowtable.ROW_W * 4
 
 
+# base5-100m-mesh4: 125M slots (100M keys at an 80 % fill) over the four
+# chips, 31,250,000 a shard: 16 GB of rows a chip, so the column layout
+# (engine.make_layout_choice)
+LOCAL_CAP_100M = 125_000_000 // SHARDS
+
+
+def column_state(cap, shardings):
+    """A stored column table of ``cap`` slots, each column on
+    ``shardings`` (a tree like the state's, or one for every column)."""
+    from gubernator_tpu.ops.buckets import BucketState
+
+    shape = jax.eval_shape(lambda: BucketState.zeros(cap))
+    if not isinstance(shardings, BucketState):
+        shardings = jax.tree.map(lambda _: shardings, shape)
+    return jax.tree.map(lambda a, s: sds(a.shape, a.dtype, s), shape, shardings)
+
+
+def test_sharded_column_ticks_and_dead_scan_on_four_chips(mosaic, four_chips):
+    """The column layout's two tick programs at 31,250,000 slots a
+    shard, each named for its layout, neither with a 64-bit value, each
+    device holding about its own shard (93 B a slot: 2.91 GB) and not
+    the table; and the reclaimer's dead scan over one shard's own
+    columns on its own chip (MeshTickEngine._shard_dead_mask)."""
+    from gubernator_tpu.ops.engine import _jitted_dead_scan, make_layout_choice
+    from gubernator_tpu.parallel.mesh_engine import ShardedOps
+
+    mesh = Mesh(np.array(four_chips), ("shard",))
+    assert make_layout_choice("auto", LOCAL_CAP_100M, four_chips[0], B) == "columns"
+    ops = ShardedOps(mesh, LOCAL_CAP_100M, "columns")
+    assert not ops._fused32
+    state = column_state(SHARDS * LOCAL_CAP_100M, ops.state_shardings)
+    shard_bytes = sum(a.dtype.itemsize for a in jax.tree.leaves(state)) * LOCAL_CAP_100M
+    assert shard_bytes == 93 * LOCAL_CAP_100M
+    args = (state, sds((SLAB_ROWS, B), I32, NamedSharding(mesh, P())))
+    for program, name in ((ops.tick_ragged, "sorted"), (ops.tick_unique_ragged, "unique")):
+        c = program.lower(*args).compile()
+        text = c.as_text()
+        assert f"HloModule jit_mesh_tick_{name}_columns" in text
+        assert "all-reduce" in text
+        assert "f64[" not in text and no_int64(c) and "u64[" not in text
+        mem = c.memory_analysis()
+        assert mem.argument_size_in_bytes < 2 * shard_bytes
+        assert mem.temp_size_in_bytes < shard_bytes
+    one = SingleDeviceSharding(four_chips[0])
+    own = column_state(LOCAL_CAP_100M, one)
+    scan = _jitted_dead_scan().lower(
+        own.in_use, *own.expire_at, sds((), jnp.int64, one)).compile()
+    assert scan.memory_analysis().argument_size_in_bytes < shard_bytes
+
+
 def test_global_sparse_reconcile_on_four_chips(mosaic, four_chips):
     """The GLOBAL plane's fused sparse reconcile (x64 XLA, psum
     collectives only) at the engine's sparse defaults: 1M replicated

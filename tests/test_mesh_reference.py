@@ -1,7 +1,8 @@
 """The sharded engine held to the benchmark's plain reference (upstream
 ``algorithms.go`` in Python int and float; it imports nothing of the
-program), on four of the eight virtual devices: a seeded mixed
-population (``base3-mixed-10m-mesh4``'s, small) filled through
+program), on four of the eight virtual devices in the column layout: a
+seeded mixed population (``base3-mixed-10m-mesh4``'s, small, and
+``base5-100m-mesh4``'s, ids over its whole 100M) filled through
 ``load_columns``, then duplicate-bearing zipf windows through
 ``submit_columns``.  Every answer equal, limit 0, which is what the
 cell's ``correct`` asks on the chip; the one-chip ``TickEngine`` gives
@@ -14,6 +15,7 @@ sharded window pass against the numpy chain, array for array.
 import zlib
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -21,8 +23,10 @@ from benchmarks.harness import population, traffic
 from benchmarks.harness.reference import Reference
 from benchmarks.tests.test_precision_control import Float32Leaky
 from gubernator_tpu.native import NativeSlotMap
+from gubernator_tpu.ops.buckets import np_logical
 from gubernator_tpu.ops.engine import (
-    REQ32_INDEX, SLAB_ROWS, TickEngine, items_from_snapshot, join_i32_pair)
+    REQ32_INDEX, SLAB_ROWS, TickEngine, device_dead_mask, items_from_snapshot,
+    join_i32_pair)
 from gubernator_tpu.ops.raggedtick import choose_tile
 from gubernator_tpu.ops.reqcols import CREATED_UNSET, ReqColumns, pack_blob
 from gubernator_tpu.parallel import mesh_engine
@@ -44,15 +48,20 @@ HOT = int(traffic.hot_ids(MIX, KEYS, 1)[0])
 # leaky one of burst 50 (over its limit inside the wide group), a token
 # bucket of limit 2^33 (test_the_hot_key_is_of_both_kinds holds that)
 SEEDS = (11, 2147483777, 2147489003)
+# base5-100m-mesh4's population: the same block but for its 100M keys
+SPEC5 = dict(SPEC, keys=100_000_000)
+SEED5 = 2147495011
 
 
 # One engine of each kind for the module (a MeshTickEngine is seconds of
 # compile a program); a history's keys carry its seed, so the histories
-# do not meet in the table.
+# do not meet in the table.  The column layout, which base5-100m-mesh4's
+# 31,250,000 slots a shard take on the chip, is named, not left to auto.
 @pytest.fixture(scope="module")
 def mesh():
     return MeshTickEngine(mesh=make_mesh(jax.devices()[:SHARDS]),
-                          local_capacity=4096, max_batch=B)
+                          local_capacity=4096, max_batch=B,
+                          table_layout="columns")
 
 
 @pytest.fixture(scope="module")
@@ -73,20 +82,25 @@ def snapshot(pop, ids, tag):
     return snap
 
 
-def history(seed, windows=7):
-    """(population, [(clock ms, key ids)]): zipf 0.99 windows of varied
-    width with the clock stepping between them; the fourth holds the
-    hottest key 150 times, a group wider than the extent walk's tile."""
-    pop = population.Population(SPEC, seed)
+def history(seed, spec=SPEC, windows=7):
+    """(population, the KEYS ids filled, [(clock ms, key ids)]): zipf 0.99
+    windows of varied width over the filled ids, with the clock stepping
+    between them; the fourth holds the hottest key 150 times, a group
+    wider than the extent walk's tile.  A population of more than KEYS
+    keys has KEYS of its ids drawn over all of them, in order."""
+    pop = population.Population(spec, seed)
     rng = np.random.default_rng(seed)
+    space = np.arange(KEYS)
+    if pop.n > KEYS:
+        space = np.sort(rng.choice(pop.n, KEYS, replace=False))
     out, t = [], T0
     for w in range(windows):
         t += int(rng.choice([1, 700, 40_000]))
         ids = traffic.key_ids(MIX, KEYS, rng, B - 23 * (w % 3))
         if w == 3:
             ids[rng.permutation(len(ids))[:150]] = HOT
-        out.append((t, ids))
-    return pop, out
+        out.append((t, space[ids]))
+    return pop, space, out
 
 
 def columns(pop, ids, t, tag):
@@ -100,10 +114,12 @@ def columns(pop, ids, t, tag):
         burst=burst)
 
 
-def served(eng, pop, wins, tag):
-    """Fill ``eng`` with the whole population, then serve the windows:
-    [(4, n) status, limit, remaining, reset_time]."""
-    eng.load_columns(snapshot(pop, np.arange(KEYS), tag), now=T0)
+def served(eng, pop, wins, tag, space=None):
+    """Fill ``eng`` with the ids ``space`` (the first KEYS where None),
+    then serve the windows: [(4, n) status, limit, remaining,
+    reset_time]."""
+    space = np.arange(KEYS) if space is None else space
+    eng.load_columns(snapshot(pop, space, tag), now=T0)
     out = []
     for t, ids in wins:
         rm, errors = eng.submit_columns(columns(pop, ids, t, tag), now=T0).result()
@@ -112,18 +128,21 @@ def served(eng, pop, wins, tag):
     return out
 
 
-def replayed(ref, pop, wins):
-    state = pop.state(np.arange(KEYS), T0)
-    buckets = {k: {f: (float if f == "remaining_f" else int)(v[k])
-                   for f, v in state.items()} for k in range(KEYS)}
-    alg, limit, duration, burst = pop.params(np.arange(KEYS))
+def replayed(ref, pop, wins, space=None):
+    space = np.arange(KEYS) if space is None else space
+    state = pop.state(space, T0)
+    at = {k: i for i, k in enumerate(space.tolist())}
+    buckets = {k: {f: (float if f == "remaining_f" else int)(v[i])
+                   for f, v in state.items()} for k, i in at.items()}
+    alg, limit, duration, burst = pop.params(space)
     out = []
     for t, ids in wins:
         got = np.zeros((4, len(ids)), np.int64)
         for j, k in enumerate(ids.tolist()):
+            i = at[k]
             buckets[k], ans = ref.apply(buckets[k], (
-                1, int(limit[k]), int(duration[k]), int(burst[k]),
-                int(alg[k]), 0, t))
+                1, int(limit[i]), int(duration[i]), int(burst[i]),
+                int(alg[i]), 0, t))
             got[:, j] = ans
         out.append(got)
     return out
@@ -139,23 +158,29 @@ def test_the_hot_key_is_of_both_kinds():
     assert kinds == [population.LEAKY, population.LEAKY, 0]
 
 
-@pytest.mark.parametrize("seed", SEEDS)
-def test_duplicate_windows_equal_the_reference_and_one_chip(mesh, one_chip, seed):
-    pop, wins = history(seed)
-    tag = SEEDS.index(seed)
+@pytest.mark.parametrize("seed,spec,tag", [
+    *(pytest.param(s, SPEC, i, id=str(s)) for i, s in enumerate(SEEDS)),
+    pytest.param(SEED5, SPEC5, 5, id=f"base5-{SEED5}"),
+])
+def test_duplicate_windows_equal_the_reference_and_one_chip(
+        mesh, one_chip, seed, spec, tag):
+    pop, space, wins = history(seed, spec)
+    assert mesh.layout == "columns"
+    if pop.n > KEYS:     # ids from the whole space, its top quarter too
+        assert space.max() > 3 * pop.n // 4 and len(np.unique(space)) == KEYS
     # every window has duplicates, and the fourth's hot group is wider
     # than a tile of the extent walk, so it straddles two
     assert all(len(np.unique(ids)) < len(ids) for _, ids in wins)
-    assert (wins[3][1] == HOT).sum() > choose_tile(B, SHARDS)
+    assert (wins[3][1] == space[HOT]).sum() > choose_tile(B, SHARDS)
     dup0, uniq0 = mesh.metric_dup_windows, mesh.metric_unique_windows
-    want = replayed(Reference(), pop, wins)
-    got = served(mesh, pop, wins, tag)
+    want = replayed(Reference(), pop, wins, space)
+    got = served(mesh, pop, wins, tag, space)
     assert mismatched(got, want) == 0
     assert mesh.metric_dup_windows - dup0 == len(wins)
     assert mesh.metric_unique_windows == uniq0
-    assert mismatched(served(one_chip, pop, wins, tag), want) == 0
+    assert mismatched(served(one_chip, pop, wins, tag, space), want) == 0
     # one precision below the one the configuration states: not correct
-    lower = mismatched(replayed(Float32Leaky(), pop, wins), want)
+    lower = mismatched(replayed(Float32Leaky(), pop, wins, space), want)
     print(f"float32 control, seed {seed}: {lower} of"
           f" {sum(len(i) for _, i in wins)} answers differ")
     assert lower > 0
@@ -192,6 +217,24 @@ def test_load_columns_equals_load_items(mesh):
     assert a.routing_parity_errors([it["key"] for it in got]) == 0
 
 
+def test_a_fill_is_counted_in_describe(mesh, one_chip):
+    """``load_seconds`` / ``load_rows`` in ``describe()`` (the daemon's
+    ``engine:`` line, ``benchmarks/run.py``'s too) on both engines: a
+    fill adds its seconds and the rows it landed, an expired row not
+    among them."""
+    pop = population.Population(SPEC, 13)
+    snap = snapshot(pop, np.arange(500), 13)
+    snap["expire_at"] = snap["expire_at"].copy()
+    snap["expire_at"][:10] = T0 - 1
+    for eng in (mesh, one_chip):
+        before = eng.describe()
+        eng.load_columns(snap, now=T0)
+        d = eng.describe()
+        assert d["load_rows"] - before["load_rows"] == 490
+        assert d["load_seconds"] > before["load_seconds"]
+        assert d["load_rows"] == eng.load_rows
+
+
 @pytest.mark.parametrize("layout", ["columns", "row"])
 def test_load_columns_reclaims_a_full_shard_once(layout):
     """More live keys than a shard holds: the expired rows already there
@@ -214,6 +257,49 @@ def test_load_columns_reclaims_a_full_shard_once(layout):
     keys = {it["key"] for it in eng.export_items()}
     assert {"bench8_k%08d" % k for k in range(1000, 1100)} <= keys
     assert eng.metric_unexpired_evictions == 0
+
+
+@pytest.mark.parametrize("capacity", [1003, 4096])
+def test_the_column_dead_scan_equals_the_host(capacity):
+    """The column layout's dead scan (``engine._jitted_dead_scan``: the
+    int32-pair compare, packed by stride) against the host's reading of
+    the same columns, at a width that is no multiple of eight, with
+    expiries either side of ``now`` in its high word and in its low one
+    (bit 31 of it set)."""
+    rng = np.random.default_rng(capacity)
+    now = (5 << 32) | 0x9000_0000
+    exp = rng.integers(now - (3 << 32), now + (3 << 32), capacity)
+    exp[:8] = [now - 1, now, now + 1, now - (1 << 32), now + (1 << 32),
+               5 << 32, (5 << 32) | 0xFFFF_FFFF, 0]
+    in_use = rng.random(capacity) < 0.8
+    lo = (exp & 0xFFFF_FFFF).astype(np.uint32).view(np.int32)
+    hi = (exp >> 32).astype(np.int32)
+    got = device_dead_mask(
+        jnp.asarray(in_use), (jnp.asarray(lo), jnp.asarray(hi)), now, capacity)
+    np.testing.assert_array_equal(got, ~in_use | (exp < now))
+
+
+def test_the_dead_scan_reads_each_shards_own_columns(mesh, monkeypatch):
+    """``MeshTickEngine._shard_dead_mask`` on the column layout scans a
+    shard's own buffers on the shard's own device (a slice of the
+    sharded arrays would gather them onto one first: 1.1 GB at
+    base5-100m-mesh4's size), and finds what the host reads there: the
+    filled keys of an hour's duration dead two hours on, the rest live."""
+    devices = []
+    scan = mesh_engine.device_dead_mask
+
+    def spy(in_use, expire_at, now, capacity):
+        devices.append({d for a in (in_use, *expire_at) for d in a.devices()})
+        return scan(in_use, expire_at, now, capacity)
+
+    monkeypatch.setattr(mesh_engine, "device_dead_mask", spy)
+    cap, now = mesh.local_capacity, T0 + 7_200_001
+    host = jax.tree.map(np.asarray, mesh.state)
+    want = ~host.in_use | (np_logical(host.expire_at, "expire_at") < now)
+    for s in range(SHARDS):
+        np.testing.assert_array_equal(
+            mesh._shard_dead_mask(s, now), want[s * cap:(s + 1) * cap])
+    assert devices == [{d} for d in mesh.mesh.devices.flat]
 
 
 def test_route_stage_and_dispatch_counters(mesh):

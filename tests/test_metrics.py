@@ -433,6 +433,10 @@ async def test_debug_endpoints_serve_populated_json(monkeypatch, caplog):
         assert eng_tel["leaky_rows"] == 3
         assert d.instance.engine.describe()["leaky_rows"] == 3
         assert d.metrics.sample("gubernator_tpu_leaky_rows_total") == 3
+        # the fill counters, in /debug/state as in describe(): this
+        # daemon has no Loader, so nothing was filled
+        assert (eng_tel["load_rows"], eng_tel["load_seconds"]) == (0, 0.0)
+        assert d.instance.engine.describe()["load_rows"] == 0
         assert traces["tracing_enabled"] is True
         assert traces["count"] > 0 and traces["spans"][0]["trace_id"]
         # Satellite: _StatsInterceptor feeds the RPC latency histogram.
