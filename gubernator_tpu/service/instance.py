@@ -690,24 +690,28 @@ class V1Instance:
         )
 
     async def get_rate_limits_columns(self, cols, deadline: float = None,
-                                      over_from_encode: bool = False):
+                                      over_from_encode: bool = False,
+                                      edge_call=None):
         """Columnar GetRateLimits (the fast path; see
         columns_fast_path_ok).  Returns ``((5, n) matrix, errors)`` in
         request order; the transport writes wire responses straight from
         the matrix.  ``deadline`` is the batch's absolute admission
         deadline stamped at the serving edge (docs/overload.md);
-        ``over_from_encode`` as in :meth:`_columns_tick`."""
+        ``over_from_encode`` and ``edge_call`` as in
+        :meth:`_columns_tick`."""
         if len(cols) > MAX_BATCH_SIZE:
             self.metrics.check_error_counter.labels(error="Request too large").inc()
             raise BatchTooLargeError(
                 f"Requests.RateLimits list too large; max size is '{MAX_BATCH_SIZE}'"
             )
         return await self._columns_tick(
-            cols, deadline=deadline, over_from_encode=over_from_encode)
+            cols, deadline=deadline, over_from_encode=over_from_encode,
+            edge_call=edge_call)
 
     async def _columns_tick(self, cols, public: bool = True,
                             deadline: float = None,
-                            over_from_encode: bool = False):
+                            over_from_encode: bool = False,
+                            edge_call=None):
         """One tick-loop submission for a columnar batch + metrics.
 
         ``over_from_encode``: the caller encodes an answer without
@@ -721,17 +725,26 @@ class V1Instance:
         duration family (reference gubernator.go:188-199); the peer
         relay edge records only the local-handling metrics its object
         path does (_submit_local).  It also picks the admission class:
-        relayed peer batches outrank client traffic under overload."""
+        relayed peer batches outrank client traffic under overload.
+
+        ``edge_call`` (``flightrec.edge_call()``, or None) is paused for
+        the wait on the tick: the handler's time is what it runs on the
+        event loop."""
         if public:
             self.metrics.concurrent_checks.inc()
         t0 = time.perf_counter()
         try:
-            mat, errors = await asyncio.wrap_future(
+            ticked = asyncio.wrap_future(
                 self.tick_loop.submit_columns(
                     cols, deadline=deadline,
                     klass=CLASS_CLIENT if public else CLASS_PEER,
                 )
             )
+            if edge_call is not None:
+                edge_call.pause()
+            mat, errors = await ticked
+            if edge_call is not None:
+                edge_call.resume()
             self.metrics.getratelimit_counter.labels(calltype="local").inc(
                 len(cols) - len(errors)
             )
@@ -1023,7 +1036,8 @@ class V1Instance:
         )
 
     async def get_peer_rate_limits_columns(self, cols, deadline: float = None,
-                                           over_from_encode: bool = False):
+                                           over_from_encode: bool = False,
+                                           edge_call=None):
         """Columnar owner-side handling of a relayed batch (the peer-edge
         twin of get_rate_limits_columns; eligibility per
         peer_columns_fast_path_ok).  Peer admission class: relayed
@@ -1036,7 +1050,7 @@ class V1Instance:
             )
         return await self._columns_tick(
             cols, public=False, deadline=deadline,
-            over_from_encode=over_from_encode)
+            over_from_encode=over_from_encode, edge_call=edge_call)
 
     async def get_peer_rate_limits(
         self, requests: Sequence[RateLimitRequest]

@@ -193,7 +193,7 @@ def _sync_arena_metrics(arena, metrics) -> None:
 
 
 async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
-                            msg_type, deadline=None):
+                            msg_type, deadline=None, edge_call=None):
     """The shared raw-bytes fast path of both rate-limit edges: native
     wire parse → columns → device tick → native wire encode, with no
     protobuf objects.  Returns ``(result, msg)``: ``result`` is the
@@ -212,7 +212,8 @@ async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
     The instance's ingest ColumnArena makes the decode land in a
     preallocated slab — zero per-batch allocation.  The tick loop
     releases the slab after packing; batches that bail to the object
-    path release it here."""
+    path release it here.  ``edge_call`` (``flightrec.edge_call()``)
+    goes to ``tick``, which pauses it across its wait."""
     msg = None
     native = False
     try:
@@ -245,7 +246,8 @@ async def _raw_columns_edge(raw, context, instance, gate_ok, tick,
             return None, msg
         try:
             mat, errs = await tick(cols, deadline=deadline,
-                                   over_from_encode=True)
+                                   over_from_encode=True,
+                                   edge_call=edge_call)
         except BatchTooLargeError as e:
             cols.release()  # rejected before the tick loop saw it
             await context.abort(grpc.StatusCode.OUT_OF_RANGE, str(e))
@@ -282,6 +284,9 @@ class V1Servicer:
         return self.instance.tick_loop.admission.request_timeout
 
     async def GetRateLimits(self, raw: bytes, context):
+        # This handler's own time on the event loop, a fast-path call's
+        # (flightrec's edge_handler overlays); None with no recorder.
+        call = flightrec.edge_call()
         deadline = _edge_deadline(context, self._default_budget())
         fast, msg = await _raw_columns_edge(
             raw, context, self.instance,
@@ -289,11 +294,14 @@ class V1Servicer:
             self.instance.get_rate_limits_columns,
             pb.GetRateLimitsReq,
             deadline=deadline,
+            edge_call=call,
         )
         if fast is not None:
-            if isinstance(fast, bytes):
-                return fast
-            return pb.GetRateLimitsResp(responses=fast)
+            if not isinstance(fast, bytes):
+                fast = pb.GetRateLimitsResp(responses=fast)
+            if call is not None:
+                call.end()
+            return fast
         if msg is None:
             msg = await _parse_pb(pb.GetRateLimitsReq, raw, context)
         reqs = convert.reqs_from_pb(msg.requests)

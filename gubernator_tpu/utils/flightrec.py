@@ -92,12 +92,24 @@ would count their seconds twice:
   previous call, so their totals over a run span the same interval:
   ``tickloop_thread_cpu``, ``edge_thread_cpu``, ``resolver_cpu`` (each
   registered thread's whole CPU: ``register_thread``, read with
-  ``time.clock_gettime`` on its ``pthread_getcpuclockid``) and
+  ``time.clock_gettime`` on its ``pthread_getcpuclockid``),
   ``process_cpu`` (``time.process_time()``; less the three, the
-  runtime's native threads).  ``tick-loop`` reads them at the end of
-  its ``wait`` for each window, so that their cost falls in no stage
-  of the window's work.  They are clocks, not latencies: the observer
-  does not see them.
+  runtime's native threads), ``edge_idle`` (the event loop's wall inside
+  its selector's ``select()``, where it waits for events: a running
+  total kept by the wrapper that ``register_thread("edge")`` puts on the
+  running loop's selector and ``uninstall()`` takes off) and
+  ``clock_wall`` (``time.perf_counter()``: the wall the others span).
+  ``tick-loop`` reads them at the end of its ``wait`` for each window,
+  so that their cost falls in no stage of the window's work.  They are
+  clocks, not latencies: the observer does not see them.  Less
+  ``edge_thread_cpu`` and ``edge_idle``, ``clock_wall`` is the loop
+  neither on the CPU nor idle: waiting for the GIL, a lock or a core.
+- ``edge_handler`` / ``edge_handler_cpu`` (``EDGE``): one fast-path
+  call's time in ``V1Servicer.GetRateLimits`` on the event loop, wall
+  (``perf_counter``) and ``time.thread_time()``, from its entry to its
+  suspension on the tick's future and from its resumption to its return
+  (``edge_call()``), noted once a call when it returns, into the window
+  in dispatch or else the newest begun.
 
 ``compile`` and ``gc`` go into the window in dispatch or else the newest
 begun; their listeners are set by ``install()`` and dropped by
@@ -139,9 +151,11 @@ THREADS = {"tickloop": "tickloop_thread_cpu", "edge": "edge_thread_cpu",
            "resolver": "resolver_cpu"}
 # Noted by ``read_clocks`` (the deltas since its previous call): clocks,
 # not latencies, so they bypass the observer.
-CLOCKS = tuple(THREADS.values()) + ("process_cpu",)
+CLOCKS = tuple(THREADS.values()) + ("process_cpu", "edge_idle", "clock_wall")
+# The edge handler's own time a call: edge_call().
+EDGE = ("edge_handler", "edge_handler_cpu")
 OVERLAYS = ("lease", "queue", "finish_lock", "cpu", "compile", "gc") \
-    + tuple(_CPU.values()) + CLOCKS
+    + tuple(_CPU.values()) + EDGE + CLOCKS
 RESOLVER = ("tick", "resolve", "finish_lock")   # seconds, no range
 STAGES = ("decode",) + CYCLE + ("tick", "resolve", "encode") + OVERLAYS
 _IDX = {s: i for i, s in enumerate(STAGES)}
@@ -186,6 +200,13 @@ class FlightRecorder:
         # register_thread().
         self._threads: Dict[str, list] = {}
         self._process_cpu: Optional[float] = None
+        self._clock_wall: Optional[float] = None
+        # The event loop's seconds in select() so far (its selector's
+        # wrapper adds to it) and at the last read_clocks.
+        self.edge_idle_s = 0.0
+        self._edge_idle: Optional[float] = None
+        # (loop, its _TimedSelector) while installed; see _time_select().
+        self._timed: list = []
         self.slow_total = 0
         self._slow: deque = deque(maxlen=32)
         # Stalls met while serving (after the first window was begun),
@@ -218,17 +239,22 @@ class FlightRecorder:
         """Name the calling thread as ``role`` (a key of ``THREADS``) so
         that ``read_clocks`` reads its CPU clock.  The first thread to
         register a role keeps it until its clock can no longer be read
-        (the thread ended): one serving process, one ``TickLoop``."""
+        (the thread ended): one serving process, one ``TickLoop``.  The
+        event loop's first registration also times its selector's
+        ``select()`` (``edge_idle``)."""
         if role not in self._threads:
             self._threads[role] = [
                 time.pthread_getcpuclockid(threading.get_ident()), None]
+            if role == "edge":
+                _time_select(self)
 
     @hot_path
     def read_clocks(self) -> None:
         """Note, into the newest window begun, what each of ``CLOCKS``
-        moved since the previous call: the registered threads' CPU and
-        the process's.  Called by the thread that begins windows, which
-        is ``tickloop``, before it begins the next."""
+        moved since the previous call: the registered threads' CPU, the
+        process's, the event loop's idle and the wall.  Called by the
+        thread that begins windows, which is ``tickloop``, before it
+        begins the next."""
         self.register_thread("tickloop")
         wid = self._seq - 1
         for role, t in list(self._threads.items()):
@@ -244,6 +270,14 @@ class FlightRecorder:
         if self._process_cpu is not None:
             self.note(wid, "process_cpu", process - self._process_cpu)
         self._process_cpu = process
+        idle = self.edge_idle_s
+        if self._edge_idle is not None:
+            self.note(wid, "edge_idle", idle - self._edge_idle)
+        self._edge_idle = idle
+        wall = time.perf_counter()
+        if self._clock_wall is not None:
+            self.note(wid, "clock_wall", wall - self._clock_wall)
+        self._clock_wall = wall
 
     @hot_path
     def note(self, wid: Optional[int], stage: str, seconds: float) -> None:
@@ -301,19 +335,19 @@ class FlightRecorder:
         if obs is not None:
             obs(stage, seconds)
 
-    def _stalled(self) -> Optional[int]:
-        """The window a stall of the whole process is noted into: the
-        one in dispatch, else the newest begun; None before the first
-        (start-up's compiles and collections are not serving's).  Takes
-        no lock: the collector's hook runs wherever an allocation
-        happens, ``begin`` included."""
+    def _current(self) -> Optional[int]:
+        """The window a stall of the whole process, or an edge call, is
+        noted into: the one in dispatch, else the newest begun;
+        None before the first (start-up's compiles and collections are
+        not serving's).  Takes no lock: the collector's hook runs
+        wherever an allocation happens, ``begin`` included."""
         wid = self._active
         if wid is None:
             wid = self._seq - 1
         return wid if wid >= 0 else None
 
     def note_gc(self, generation: int, seconds: float) -> None:
-        wid = self._stalled()
+        wid = self._current()
         if wid is None:
             return
         self.gc_pause_s[generation] += seconds
@@ -321,7 +355,7 @@ class FlightRecorder:
         self.note(wid, "gc", seconds)
 
     def note_compile(self, event: str, seconds: float) -> None:
-        wid = self._stalled()
+        wid = self._current()
         if wid is None:
             return
         self.compile_s += seconds
@@ -517,6 +551,90 @@ def stage(name: str, into=ACTIVE):
     return _Stage(fr, name, into)
 
 
+class _EdgeCall:
+    """One fast-path call's time in the edge handler on the event loop:
+    two segments, from ``V1Servicer.GetRateLimits``'s entry to
+    ``pause()`` (its suspension on the tick's future) and from
+    ``resume()`` to ``end()`` (its return).  ``end()`` notes their wall
+    as ``edge_handler`` and their ``time.thread_time()`` as
+    ``edge_handler_cpu``.  The wall clock is read outside the CPU clock
+    at both ends of a segment, as in :class:`_Stage`."""
+
+    __slots__ = ("_fr", "_t0", "_c0", "wall", "cpu")
+
+    def __init__(self, fr: FlightRecorder):
+        self._fr = fr
+        self.wall = self.cpu = 0.0
+        self.resume()
+
+    @hot_path
+    def resume(self) -> None:
+        self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
+
+    @hot_path
+    def pause(self) -> None:
+        self.cpu += time.thread_time() - self._c0
+        self.wall += time.perf_counter() - self._t0
+
+    @hot_path
+    def end(self) -> None:
+        self.pause()
+        fr = self._fr
+        wid = fr._current()
+        fr.note(wid, "edge_handler", self.wall)
+        fr.note(wid, "edge_handler_cpu", self.cpu)
+
+
+@hot_path
+def edge_call() -> Optional[_EdgeCall]:
+    """Start timing one edge call (see :class:`_EdgeCall`); with no
+    recorder installed, one check and ``None``."""
+    fr = _recorder
+    if fr is None:
+        return None
+    return _EdgeCall(fr)
+
+
+class _TimedSelector:
+    """An event loop's selector whose ``select()`` adds its wall to the
+    recorder's ``edge_idle_s`` (``read_clocks`` notes it as
+    ``edge_idle``); every other attribute is the selector's own."""
+
+    def __init__(self, inner, fr: FlightRecorder):
+        self.inner = inner
+        self._fr = fr
+
+    def select(self, timeout=None):
+        t0 = time.perf_counter()
+        try:
+            return self.inner.select(timeout)
+        finally:
+            self._fr.edge_idle_s += time.perf_counter() - t0
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def _time_select(fr: FlightRecorder) -> None:
+    """Put a :class:`_TimedSelector` on the calling thread's running
+    loop while ``fr`` is the installed recorder, where the loop has a
+    selector (asyncio's default loop does) that is not timed already;
+    ``uninstall()`` puts the original back."""
+    import asyncio
+
+    try:
+        loop = asyncio.get_running_loop()
+    except RuntimeError:
+        return
+    sel = getattr(loop, "_selector", None)
+    if fr is not _recorder or sel is None or isinstance(sel, _TimedSelector):
+        return
+    timed = _TimedSelector(sel, fr)
+    loop._selector = timed
+    fr._timed.append((loop, timed))
+
+
 def register_thread(role: str) -> None:
     """Name the calling thread ``role`` to the installed recorder (see
     :meth:`FlightRecorder.register_thread`); with none, one check."""
@@ -574,8 +692,12 @@ def install(recorder: FlightRecorder) -> None:
 
 def uninstall() -> None:
     global _recorder, _gc_open
-    _recorder = None
+    fr, _recorder = _recorder, None
     _gc_open = None
+    while fr is not None and fr._timed:
+        loop, timed = fr._timed.pop()
+        if getattr(loop, "_selector", None) is timed:
+            loop._selector = timed.inner
     if _on_gc in gc.callbacks:
         import jax.monitoring
 
